@@ -108,6 +108,10 @@ def main(argv=None) -> int:
     p.add_argument("--upmap-deviation", type=float, default=1.0)
     p.add_argument("--upmap-max", type=int, default=100)
     args = p.parse_args(argv)
+    if args.bulk:
+        # the device path: keep its compiled programs between runs
+        from ..utils.jaxenv import enable_compile_cache
+        enable_compile_cache()
 
     if args.createsimple:
         if not args.mapfile:

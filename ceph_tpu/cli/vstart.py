@@ -141,6 +141,8 @@ def main(argv=None) -> int:
     p.add_argument("--exporter-port", type=int, default=0,
                    help="serve Prometheus metrics on this port")
     args = p.parse_args(argv)
+    from ..utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     return asyncio.run(run(args))
 
 

@@ -19,7 +19,7 @@ Chip count resolution, in priority order:
 2. ``len(jax.local_devices())`` — the real mesh (a v5e host sees its
    local chips; CPU CI sees the forced count when launched under
    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
-3. 1 — jax unavailable or uninitializable (host-only builds).
+3. 1 — jax not installed (host-only builds).
 """
 
 from __future__ import annotations
@@ -31,14 +31,16 @@ FORCE_HOST_FLAG = "--xla_force_host_platform_device_count"
 
 
 def local_devices() -> list:
-    """The process's jax devices ([] when jax is unusable).  Imported
-    lazily: mesh construction must not force jax init on host-only
-    paths that never dispatch."""
+    """The process's jax devices ([] when jax is not installed).
+    Imported lazily: mesh construction must not force jax init on
+    host-only paths that never dispatch.  A jax that is installed but
+    cannot initialise its backend raises: on a machine with a chip
+    that is a fault, not a host-only build."""
     try:
         import jax
-        return list(jax.local_devices())
-    except Exception:       # pragma: no cover - jax baked into image
+    except ImportError:     # pragma: no cover - jax baked into image
         return []
+    return list(jax.local_devices())
 
 
 def chip_count() -> int:
@@ -64,14 +66,15 @@ def device_for(chip_index: int):
 
 
 def backend() -> str:
-    """The jax backend serving this mesh ("cpu" when jax is unusable).
-    The dispatch-stream bench gate keys its published comparisons on
-    this: CPU-CI figures never gate a real-TPU run and vice versa."""
+    """The jax backend serving this mesh ("cpu" when jax is not
+    installed).  The dispatch-stream bench gate keys its published
+    comparisons on this: CPU-CI figures never gate a real-TPU run and
+    vice versa."""
     try:
         import jax
-        return str(jax.default_backend())
-    except Exception:       # pragma: no cover - jax baked into image
+    except ImportError:     # pragma: no cover - jax baked into image
         return "cpu"
+    return str(jax.default_backend())
 
 
 def affinity(osd_id: int, n_chips: int) -> int:

@@ -67,6 +67,7 @@ import numpy as np
 
 from . import mesh
 from ..trace import recorder as flight
+from ..utils.log import global_logger
 
 # service classes (the device-side analog of the mClock op classes)
 K_CLIENT_EC = "client-ec"
@@ -386,12 +387,25 @@ class ChipRuntime:
         """Commit an array to this chip's device (computation follows
         data placement — the 2112.09017 dispatch discipline).  Returns
         the input unchanged when the mesh shares one physical
-        device."""
+        device.  For callers that hand a jitted program jnp arrays
+        they built themselves (digest, lz, chunker, balancer); a new
+        caller uses `scope`, which also covers what the callee
+        stages."""
         dev = self.jax_device
         if dev is None:
             return arr
         import jax
         return jax.device_put(arr, dev)
+
+    def scope(self):
+        """Context in which host arrays handed to a compiled program,
+        and the program itself, land on this chip's device — for
+        callees that stage their own inputs (FusedEncoder views bytes
+        as uint32 on the host; DeviceMapper uploads its own tables),
+        where `place` on the caller's side would be undone.  A no-op
+        when the mesh shares one physical device."""
+        import jax
+        return jax.default_device(self.jax_device)
 
     # -- shape buckets / compile cache ------------------------------------
 
@@ -534,6 +548,12 @@ class ChipRuntime:
         self.fallback = True
         self.fallback_reason = repr(reason)
         self.fallback_count += 1
+        # a deterministic failure (a kernel the chip's compiler
+        # refuses, a program that does not fit HBM) looks like a
+        # healthy cluster from outside: say why, once per transition
+        global_logger().error(
+            "device", "chip %d poisoned -> host fallback: %s"
+            % (self.index, self.fallback_reason))
         self._notify()
         try:
             loop = asyncio.get_event_loop()
@@ -959,7 +979,8 @@ class DeviceRuntime:
                 enc = DeviceBatcher._encoder(matrix_key, int(w))
                 buf = target.pool.lease((k, int(b)), dtype)
                 try:
-                    np.asarray(enc(target.place(buf)))
+                    with target.scope():
+                        np.asarray(enc(buf))
                 finally:
                     target.pool.release(buf)
                 target.note_program("ec",

@@ -86,9 +86,9 @@ def device_offload_enabled() -> bool:
         return v not in ("0", "false", "no")
     try:
         import jax
-        return jax.default_backend() == "tpu"
-    except Exception:       # pragma: no cover - jax always present
+    except ImportError:     # pragma: no cover - jax always present
         return False
+    return jax.default_backend() == "tpu"
 
 
 def host_encode(matrix, w: int, data: np.ndarray) -> np.ndarray:
@@ -437,12 +437,12 @@ class DeviceBatcher:
             enc = self._encoder(matrix_key, int(w))
             outs = []
             used = n
-            for (_lo, seg), buf in zip(plan, bufs):
-                chip.note_program("ec", (matrix_key, int(w), seg))
-                u = min(seg, used)
-                outs.append(np.asarray(
-                    enc(chip.place(buf)))[:, :u])
-                used -= u
+            with chip.scope():
+                for (_lo, seg), buf in zip(plan, bufs):
+                    chip.note_program("ec", (matrix_key, int(w), seg))
+                    u = min(seg, used)
+                    outs.append(np.asarray(enc(buf))[:, :u])
+                    used -= u
             out = (outs[0] if len(outs) == 1
                    else np.concatenate(outs, axis=1))
             chip.finish(ticket, ok=True)

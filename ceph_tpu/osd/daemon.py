@@ -310,17 +310,24 @@ class OSD:
         now, and tell the cluster log (the daemon-origin side of the
         per-chip DEVICE_FALLBACK story; the mon clogs the health
         edge, naming the chip)."""
-        if self.stopping or not self.booted:
+        if self.stopping:
             return
         chip = (self.device_chip.index
                 if self.device_chip is not None else 0)
-        self.ctx.log.info(
-            "osd", "osd.%d device chip %d %s"
-            % (self.whoami, chip,
-               "LOST -> host fallback" if fallback else "healed"))
+        why = (self.device_chip.fallback_reason
+               if self.device_chip is not None else None)
+        if fallback:
+            self.ctx.log.error(
+                "osd", "osd.%d device chip %d LOST -> host fallback: "
+                "%s" % (self.whoami, chip, why))
+        else:
+            self.ctx.log.info("osd", "osd.%d device chip %d healed"
+                              % (self.whoami, chip))
+        if not self.booted:
+            return          # no mon session yet: the log line stands
         if fallback:
             self.clog.warn("osd.%d device chip %d lost, serving from "
-                           "host paths" % (self.whoami, chip))
+                           "host paths: %s" % (self.whoami, chip, why))
         else:
             self.clog.info("osd.%d device chip %d healed"
                            % (self.whoami, chip))
@@ -856,7 +863,14 @@ class OSD:
                     m, chip=(self.device_chip.index
                              if self.device_chip is not None
                              else None))
-            except Exception:
+            except Exception as e:
+                # per-PG host mapping below is always correct, but a
+                # bulk mapper that cannot even build must not go
+                # unnoticed
+                self.ctx.log.error(
+                    "osd", "osd.%d bulk PG mapper failed at epoch %d, "
+                    "mapping per PG on the host: %r"
+                    % (self.whoami, m.epoch, e))
                 mapping = None
         for pool_id, pool in m.pools.items():
             for ps in range(pool.pg_num):

@@ -18,6 +18,7 @@ OSDMap::Incremental so monitors can publish deltas.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 from ..models.crushmap import ITEM_NONE, CrushMap
@@ -183,6 +184,21 @@ class PGPool:
         return cls(**d)
 
 
+@functools.lru_cache(maxsize=8)
+def _device_mapper_for(crush_encoded: bytes):
+    """One DeviceMapper per distinct crush map per process: every
+    daemon of a process decodes its own OSDMap each epoch, and a mapper
+    carries the compiled CRUSH programs (seconds each to trace and
+    compile) — so the daemons share them, keyed by the crush map's
+    encoded content.  Built from its own decode of that content: the
+    mon appends to its pending crush map in place, and a shared mapper
+    must keep matching what it is keyed by."""
+    from ..ops.crush.device import DeviceMapper
+    from ..utils import denc
+
+    return DeviceMapper(CrushMap.from_dict(denc.decode(crush_encoded)))
+
+
 class OSDMap:
     """The cluster map. All mutation goes through apply_incremental so
     every node's copy stays identical per epoch."""
@@ -261,9 +277,10 @@ class OSDMap:
         """Shared vectorized mapper, flattened once per crush epoch
         (raises ValueError when the map is outside device scope)."""
         if self._dmapper is None:
-            from ..ops.crush.device import DeviceMapper
+            from ..utils import denc
 
-            self._dmapper = DeviceMapper(self.crush)
+            self._dmapper = _device_mapper_for(
+                denc.encode(self.crush.to_dict()))
         return self._dmapper
 
     # -- object -> pg ------------------------------------------------------

@@ -33,6 +33,7 @@ from ..device.runtime import DeviceBusy, DeviceRuntime, K_MAPPING
 from ..models.crushmap import ITEM_NONE
 from ..ops.crush.hashes import hash32_2_v
 from ..osd.osdmap import OSD_EXISTS, OSD_UP, OSDMap, PGPool, pg_t
+from ..utils.log import global_logger
 
 class PoolMapping:
     """Dense up/acting arrays for one pool ([pg_num, size] int32 with
@@ -91,10 +92,14 @@ class OSDMapMapping:
                     dm = osdmap.device_mapper()
                 up, prim = self._map_pool_ticketed(
                     osdmap, pool, dm, target, exists, isup, aff)
-            except (ValueError, DeviceBusy):
+            except (ValueError, DeviceBusy) as e:
                 # outside device scope, admission pushback, or
                 # device-loss fallback: the scalar pipeline is the
-                # always-correct degradation
+                # always-correct degradation — and a Python loop per
+                # PG, so it never starts unannounced
+                global_logger().error(
+                    "mapping", "pool %d (%d PGs) mapped by the scalar "
+                    "host pipeline: %r" % (pool.id, pool.pg_num, e))
                 up, prim = self._map_pool_scalar(osdmap, pool)
                 self.scalar_pools += 1
             else:
@@ -116,8 +121,9 @@ class OSDMapMapping:
         chip.try_admit(ticket)
         try:
             chip.launch(ticket)     # injected-fault hook
-            up, prim = self._map_pool_device(osdmap, pool, dm,
-                                             exists, isup, aff)
+            with chip.scope():
+                up, prim = self._map_pool_device(osdmap, pool, dm,
+                                                 exists, isup, aff)
         except ValueError:
             # map outside device scope: a scalar-fallback condition,
             # not a device loss

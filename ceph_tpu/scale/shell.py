@@ -62,13 +62,6 @@ class MapCache:
         self.maps: dict[int, OSDMap] = {}
         self._incs: dict[bytes, Incremental] = {}
         self._primaries: tuple[int, dict] | None = None
-        # map epoch -> epoch of the last crush change it reflects:
-        # snapshots with the same crush epoch share ONE DeviceMapper
-        # (re-flattening + re-JITting the bulk-mapping program per
-        # weight-only churn epoch is a 20s+ synchronous stall at 1k)
-        self._crush_epochs: dict[int, int] = {}
-        self._shared_dmapper = None
-        self._shared_dm_crush = -1
         self._build_fut = None      # single-flight rebuild handle
         self.full_decodes = 0
         self.inc_decodes = 0
@@ -115,17 +108,9 @@ class MapCache:
             base.apply_incremental(inc)
             if inc.new_crush is None:
                 base._mapper = m._mapper
-                self._crush_epochs[base.epoch] = \
-                    self._crush_epochs.get(m.epoch, m.epoch)
-            else:
-                self._crush_epochs[base.epoch] = base.epoch
+                base._dmapper = m._dmapper
             m = self._remember(base)
         return m
-
-    def _crush_epoch(self, m: OSDMap) -> int:
-        # unknown lineage (full-map jump) reads as its own epoch —
-        # i.e. conservatively "crush changed here"
-        return self._crush_epochs.get(m.epoch, m.epoch)
 
     async def primaries_async(self, m: OSDMap) -> dict[int, list]:
         """The shells' entry point: the freshest available grouping,
@@ -163,16 +148,9 @@ class MapCache:
 
         from ..parallel.mapping import OSDMapMapping
 
-        # same-crush snapshots share one DeviceMapper: the flattened
-        # tables and the jitted pool-mapping programs are a function
-        # of the crush map only (weights/states are call inputs)
-        ce = self._crush_epoch(m)
-        if m._dmapper is None and self._shared_dm_crush == ce:
-            m._dmapper = self._shared_dmapper
+        # same-crush snapshots share one DeviceMapper and its jitted
+        # programs (OSDMap.device_mapper memoizes by crush content)
         mapping = OSDMapMapping(m)
-        if m._dmapper is not None:
-            self._shared_dmapper = m._dmapper
-            self._shared_dm_crush = ce
         out: dict[int, list] = {}
         from ..models.crushmap import ITEM_NONE
         for pool_id, pm in mapping.pools.items():

@@ -246,3 +246,17 @@ class TestBulkMapping:
         inc.new_primary_affinity[2] = 0x4000
         m.apply_incremental(inc)
         self._assert_parity(m)
+
+
+def test_device_mapper_shared_per_crush_content():
+    """Every daemon of a process decodes its own OSDMap; maps with the
+    same crush content share one DeviceMapper (and its compiled
+    programs), a different crush map gets its own."""
+    from ceph_tpu.cli.osdmaptool import create_simple
+    blob = create_simple(6, 8, 3).encode()
+    a, b = OSDMap.decode(blob), OSDMap.decode(blob)
+    assert a.crush is not b.crush
+    assert a.device_mapper() is b.device_mapper()
+    assert a.device_mapper().map is not a.crush     # a private copy
+    other = create_simple(7, 8, 3)
+    assert other.device_mapper() is not a.device_mapper()

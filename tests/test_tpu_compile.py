@@ -1,0 +1,124 @@
+"""The main path's Pallas kernels, compiled for a described v5e chip.
+
+No chip is attached here: the TPU compiler that is installed compiles
+for a topology that is only described, and refuses what the chip's
+compiler would refuse (misaligned slices, too much VMEM, a program
+that does not fit HBM) — which interpret mode cannot show.  Nothing
+runs, so these say nothing about results or time.
+
+The topology is described inside a fixture (one process at a time may
+load libtpu, and every xdist worker imports this file), the builders
+are steered onto their Mosaic branch inside the test, and every object
+is built fresh: nothing Mosaic-built may reach a process-wide cache
+that the CPU tests of the same worker read next.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # such a compile can be written to the persistent cache but not
+    # read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Kernel builders decide interpret-vs-Mosaic from the backend at
+    build time; here the backend is the CPU, so say "tpu" while a test
+    builds and lowers."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _fused(k, m):
+    from ceph_tpu.ec.kernels import FusedEncoder
+    from ceph_tpu.ec.matrices import isa_rs_vandermonde_matrix
+    # the tile ec/batcher.py picks for the profile
+    tile = 262144 if k + m <= 11 else 131072
+    return FusedEncoder(isa_rs_vandermonde_matrix(k, m), tile_bytes=tile)
+
+
+@pytest.mark.parametrize("chunk_bytes", [4 << 10, 4 << 20])
+@pytest.mark.parametrize("k,m", [(8, 3), (4, 2), (10, 4)])
+def test_fused_encode_compiles(one_chip, mosaic, k, m, chunk_bytes):
+    enc = _fused(k, m)
+    lanes = chunk_bytes // 4
+    _compiled_text(enc._fn_for(lanes), one_chip,
+                   ((k, lanes), jnp.uint32))
+
+
+def test_fused_one_shard_decoder_compiles(one_chip, mosaic):
+    dec = _fused(8, 3).decoder_for(
+        (2,), tuple(i for i in range(11) if i != 2))
+    lanes = (64 << 10) // 4
+    _compiled_text(dec._fn_for(lanes), one_chip,
+                   ((8, lanes), jnp.uint32))
+
+
+def _flat_map():
+    """A fresh FlatMap of the 1000-OSD map chip_smoke.py maps: 50
+    straw2 hosts of 20 under one straw2 root."""
+    from chip_smoke import build_osdmap
+    from ceph_tpu.ops.crush.device import FlatMap
+    return FlatMap(build_osdmap(1000, 4096).crush)
+
+
+@pytest.mark.parametrize("depth_sizes,want_type",
+                         [((50,), 1), ((50, 20), 0)],
+                         ids=["outer", "two-level"])
+def test_crush_descend_compiles(one_chip, mosaic, depth_sizes,
+                                want_type):
+    from ceph_tpu.ops.crush import pallas_draw
+    fn = pallas_draw.make_descend_kernel(_flat_map(), depth_sizes,
+                                         want_type)
+    assert fn is not None, "map outside the kernel's table budget"
+    lanes = ((1 << 20,), jnp.int32)     # DeviceMapper.CHUNK
+    _compiled_text(fn, one_chip, lanes, lanes, lanes, lanes)
+
+
+# the 10M-PG pool of the smoke: ten DeviceMapper.CHUNK-sized chunks
+NPG = 10 << 20
+
+
+@pytest.mark.parametrize("kernel", ["post", "hitscan", "rowcompact"])
+def test_crush_lane_kernels_compile(one_chip, mosaic, kernel):
+    from ceph_tpu.ops.crush import pallas_draw
+    from ceph_tpu.ops.crush.device import DeviceMapper
+    raw = ((NPG, 3), jnp.int32)
+    osds = ((1000,), np.bool_)
+    if kernel == "post":
+        fn = pallas_draw.make_post_kernel(1000, 3, True)
+        _compiled_text(fn, one_chip, raw, osds)
+    elif kernel == "hitscan":
+        fn = pallas_draw.make_hitscan_kernel(1000, 3)
+        _compiled_text(fn, one_chip, raw, osds)
+    else:
+        fn = pallas_draw.make_rowcompact_kernel(
+            NPG, DeviceMapper.RC_ROW, DeviceMapper.RC_KT, 10_000_000)
+        _compiled_text(fn, one_chip, ((NPG,), np.bool_))
