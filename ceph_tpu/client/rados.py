@@ -23,6 +23,7 @@ from ..msg.messages import (MConfig, MMonCommand, MMonCommandAck,
                             MOSDBackoff, MOSDMapMsg, MOSDOp, MOSDOpReply,
                             MWatchNotify)
 from ..osd.osdmap import OSDMap, consume_map_payload, pg_t
+from ..trace.span import mark, span
 from ..utils.backoff import ExpBackoff
 from ..utils.context import Context
 
@@ -361,29 +362,31 @@ class RadosClient:
     # -- op submission -----------------------------------------------------
 
     def _calc_target(self, pool_id: int, oid: str):
-        pool = self.osdmap.pools[pool_id]
-        raw = self.osdmap.object_locator_to_pg(oid, pool_id)
-        pgid = pool.raw_pg_to_pg(raw)  # Objecter.cc:2830
-        up, upp, acting, actingp = \
-            self.osdmap.pg_to_up_acting_osds(pgid)
-        return actingp, pgid, acting
+        with span("client.calc_target"):
+            pool = self.osdmap.pools[pool_id]
+            raw = self.osdmap.object_locator_to_pg(oid, pool_id)
+            pgid = pool.raw_pg_to_pg(raw)  # Objecter.cc:2830
+            up, upp, acting, actingp = \
+                self.osdmap.pg_to_up_acting_osds(pgid)
+            return actingp, pgid, acting
 
     def submit_op(self, pool_id: int, oid: str, ops: list[dict],
                   snapc=None, snapid=None,
                   tenant: str | None = None) -> asyncio.Future:
-        self._tid += 1
-        fut = asyncio.get_running_loop().create_future()
-        op = _InFlight(self._tid, pool_id, oid, ops, fut,
-                       snapc=snapc, snapid=snapid, tenant=tenant)
-        op.trace = "%s:%d" % (self.msgr.entity, self._tid)
-        op.top = self.optracker.create(
-            "client_op(tid=%d pool=%d %s [%s])"
-            % (self._tid, pool_id, oid,
-               ",".join(o.get("op", "?") for o in ops)),
-            trace=op.trace, tenant=tenant)
-        self._inflight[self._tid] = op
-        self._send_op(op)
-        return fut
+        with span("client.submit"):
+            self._tid += 1
+            fut = asyncio.get_running_loop().create_future()
+            op = _InFlight(self._tid, pool_id, oid, ops, fut,
+                           snapc=snapc, snapid=snapid, tenant=tenant)
+            op.trace = "%s:%d" % (self.msgr.entity, self._tid)
+            op.top = self.optracker.create(
+                "client_op(tid=%d pool=%d %s [%s])"
+                % (self._tid, pool_id, oid,
+                   ",".join(o.get("op", "?") for o in ops)),
+                trace=op.trace, tenant=tenant)
+            self._inflight[self._tid] = op
+            self._send_op(op)
+            return fut
 
     async def list_objects(self, pool_id: int) -> list[str]:
         """Enumerate every object in the pool by walking its PGs with
@@ -420,33 +423,34 @@ class RadosClient:
         return sorted(set(names))
 
     def _send_op(self, op: _InFlight) -> None:
-        loop = asyncio.get_running_loop()
-        if op.backoff is None:
-            op.backoff = ExpBackoff(base=self.OP_RESEND_BASE,
-                                    cap=self.OP_RESEND_CAP,
-                                    rng=self.rng)
-            op.first_sent = loop.time()
-        op.next_resend = loop.time() + op.backoff.next_delay()
-        primary, pgid, acting = self._calc_target(op.pool, op.oid)
-        op.target = primary
-        op.pgid = pgid
-        op.acting = acting
-        if primary < 0:
+        with span("client.send_op"):
+            loop = asyncio.get_running_loop()
+            if op.backoff is None:
+                op.backoff = ExpBackoff(base=self.OP_RESEND_BASE,
+                                        cap=self.OP_RESEND_CAP,
+                                        rng=self.rng)
+                op.first_sent = loop.time()
+            op.next_resend = loop.time() + op.backoff.next_delay()
+            primary, pgid, acting = self._calc_target(op.pool, op.oid)
+            op.target = primary
+            op.pgid = pgid
+            op.acting = acting
+            if primary < 0:
+                if op.top is not None:
+                    op.top.mark_event("no_primary")
+                return  # no acting primary yet: wait for the next map
+            addr = self.osdmap.osd_addrs.get(primary)
+            if not addr:
+                return
+            m = MOSDOp(
+                tid=op.tid, pool=op.pool, ps=pgid.ps, oid=op.oid,
+                snapc=op.snapc, snapid=op.snapid, ops=op.ops,
+                epoch=self.osdmap.epoch, flags=0)
+            m.trace = op.trace
+            m.tenant = op.tenant    # rides the envelope into every layer
             if op.top is not None:
-                op.top.mark_event("no_primary")
-            return  # no acting primary yet: wait for the next map
-        addr = self.osdmap.osd_addrs.get(primary)
-        if not addr:
-            return
-        m = MOSDOp(
-            tid=op.tid, pool=op.pool, ps=pgid.ps, oid=op.oid,
-            snapc=op.snapc, snapid=op.snapid, ops=op.ops,
-            epoch=self.osdmap.epoch, flags=0)
-        m.trace = op.trace
-        m.tenant = op.tenant    # rides the envelope into every layer
-        if op.top is not None:
-            op.top.mark_event("sent_osd.%d" % primary)
-        self.msgr.send_to(addr, m, entity_hint="osd.%d" % primary)
+                op.top.mark_event("sent_osd.%d" % primary)
+            self.msgr.send_to(addr, m, entity_hint="osd.%d" % primary)
 
     async def _resend_loop(self) -> None:
         """Objecter op-retry ticker: any op still in flight past its
@@ -484,20 +488,23 @@ class RadosClient:
                     continue    # pg-targeted (pgls) ops are fire-once
                 if op.next_resend > now or self._backed_off(op):
                     continue
+                mark("client.resend",
+                     age_us=int((now - op.first_sent) * 1e6))
                 self._send_op(op)
 
     def _handle_reply(self, msg: MOSDOpReply) -> None:
-        op = self._inflight.pop(msg.tid, None)
-        if op is None or op.future.done():
-            return
-        if op.top is not None:
-            op.top.finish("reply_r%d" % (msg.result or 0))
-        if msg.result == 0:
-            op.future.set_result(msg.outs)
-        elif msg.result == -2:
-            op.future.set_exception(ObjectNotFound(op.oid))
-        else:
-            op.future.set_exception(RadosError(msg.result, msg.outs))
+        with span("client.handle_reply"):
+            op = self._inflight.pop(msg.tid, None)
+            if op is None or op.future.done():
+                return
+            if op.top is not None:
+                op.top.finish("reply_r%d" % (msg.result or 0))
+            if msg.result == 0:
+                op.future.set_result(msg.outs)
+            elif msg.result == -2:
+                op.future.set_exception(ObjectNotFound(op.oid))
+            else:
+                op.future.set_exception(RadosError(msg.result, msg.outs))
 
     # -- mon commands ------------------------------------------------------
 

@@ -138,7 +138,10 @@ class DispatchTicket:
 
     @property
     def device_s(self) -> float:
-        """Wall seconds of the device call itself (launch -> done)."""
+        """Host-blocked dispatch time, launch -> done: the upload, the
+        kernel and the readback as the calling thread waited for them.
+        Not device-busy time (the kernel is a small part of it); that
+        comes from a profiler trace alone."""
         if not self.t_done or not self.t_launch:
             return 0.0
         return max(0.0, self.t_done - self.t_launch)
@@ -627,9 +630,10 @@ class ChipRuntime:
         per-chip busy/idle accounting arXiv:2112.09017 treats as the
         primary scaling signal:
 
-        * ``busy_frac``  — chip-seconds of device time per wall
-          second in the window (can exceed 1.0 while multiple
-          dispatches are in flight);
+        * ``busy_frac``  — seconds of ``DispatchTicket.device_s`` per
+          wall second in the window: host-blocked dispatch time
+          (upload + kernel + readback), not device-busy time (can
+          exceed 1.0 while multiple dispatches are in flight);
         * ``queue_wait_frac`` — admission-wait seconds per wall
           second (the saturation leading indicator: latency is
           queueing, not compute);

@@ -53,6 +53,8 @@ import asyncio
 import heapq
 import time
 
+from ..trace.span import span
+
 
 class StreamOp:
     """One admitted matmul request: [k, n] words awaiting parity."""
@@ -240,24 +242,25 @@ class DispatchStream:
         finally:
             self._slots_inflight -= 1
             self._wake.set()
-        now = time.monotonic()
-        granted = (ticket.t_admit if ticket is not None
-                   and ticket.t_admit else now)
-        self.slot_dispatches += 1
-        self.slot_payload_words += n
-        self.slot_capacity_words += (ticket.bucket
-                                     if ticket is not None else n)
-        off = 0
-        for op in group:
-            if not op.fut.cancelled():
-                op.fut.set_result(out[:, off:off + op.n])
-            off += op.n
-            self.retired += 1
-            self.admission_waits += 1
-            self.admission_wait_sum += max(0.0,
-                                           granted - op.t_arrive)
-            if op.on_ticket is not None and ticket is not None:
-                try:
-                    op.on_ticket(ticket)
-                except Exception:
-                    pass    # attribution must never sink the slot
+        with span("ec.deliver", items=len(group)):
+            now = time.monotonic()
+            granted = (ticket.t_admit if ticket is not None
+                       and ticket.t_admit else now)
+            self.slot_dispatches += 1
+            self.slot_payload_words += n
+            self.slot_capacity_words += (ticket.bucket
+                                         if ticket is not None else n)
+            off = 0
+            for op in group:
+                if not op.fut.cancelled():
+                    op.fut.set_result(out[:, off:off + op.n])
+                off += op.n
+                self.retired += 1
+                self.admission_waits += 1
+                self.admission_wait_sum += max(0.0,
+                                               granted - op.t_arrive)
+                if op.on_ticket is not None and ticket is not None:
+                    try:
+                        op.on_ticket(ticket)
+                    except Exception:
+                        pass    # attribution must never sink the slot

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Mapping, Sequence
 
+from ..trace.span import span
 from .interface import ErasureCodeInterface, ErasureCodeProfile
 
 
@@ -198,19 +199,22 @@ class ErasureCode(ErasureCodeInterface):
             return self.encode(want_to_encode, data)
         import numpy as np
         matrix, w = dm
-        prepared = self.encode_prepare(data)
-        arr = np.stack([
-            np.frombuffer(prepared[self.chunk_index(i)],
-                          dtype=self._word_dtype(w))
-            for i in range(self.get_data_chunk_count())])
+        with span("ec.prepare"):
+            prepared = self.encode_prepare(data)
+            arr = np.stack([
+                np.frombuffer(prepared[self.chunk_index(i)],
+                              dtype=self._word_dtype(w))
+                for i in range(self.get_data_chunk_count())])
         parity = await DeviceBatcher.get().encode(
             matrix, w, arr, klass=klass or K_CLIENT_EC,
             on_ticket=on_ticket, chip=chip, tenant=tenant)
-        out = dict(prepared)
-        for i in range(len(matrix)):
-            out[self.chunk_index(
-                self.get_data_chunk_count() + i)] = parity[i].tobytes()
-        return {i: out[i] for i in want_to_encode}
+        with span("ec.collect"):
+            out = dict(prepared)
+            for i in range(len(matrix)):
+                out[self.chunk_index(
+                    self.get_data_chunk_count() + i)] = \
+                    parity[i].tobytes()
+            return {i: out[i] for i in want_to_encode}
 
     def parity_delta(self, deltas: Mapping[int, bytes]
                      ) -> dict[int, bytes]:

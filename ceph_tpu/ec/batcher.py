@@ -72,6 +72,7 @@ import numpy as np
 
 from . import matrices
 from ..device.runtime import (DeviceBusy, DeviceRuntime, K_CLIENT_EC)
+from ..trace.span import span
 
 _WORD_DTYPE = {8: np.uint8, 16: np.uint16, 32: np.uint32}
 
@@ -155,12 +156,8 @@ class DeviceBatcher:
         self.items_encoded = 0
         self.host_flushes = 0        # flushes served by the host path
         self.sharded_flushes = 0     # flushes split across the mesh
-        # device-dispatch telemetry: per-flush wall time of the device
-        # call.  Kept for bench --trace and back-compat; per-OP
-        # attribution now rides the dispatch ticket instead of
-        # sampling these.
-        self.last_flush_s = 0.0
-        self.flush_seconds = 0.0
+        # per-flush wall time of the device call (host-blocked:
+        # upload + kernel + readback), read by bench.py --trace
         self.flush_history: list[float] = []   # bounded ring
 
     @classmethod
@@ -291,10 +288,7 @@ class DeviceBatcher:
                 rt, plan, matrix_key, int(w), klass, parts,
                 tenant=tenant, t_enqueue=t_enqueue, stream=stream)
         if out is not None:
-            dt = time.perf_counter() - t0
-            self.last_flush_s = dt
-            self.flush_seconds += dt
-            self.flush_history.append(dt)
+            self.flush_history.append(time.perf_counter() - t0)
             if len(self.flush_history) > 512:
                 del self.flush_history[:256]
         return out, ticket
@@ -361,17 +355,18 @@ class DeviceBatcher:
 
     @staticmethod
     def _deliver(pb: _PendingBatch, out: np.ndarray, ticket) -> None:
-        off = 0
-        for arr, fut, cb in zip(pb.arrays, pb.futures, pb.tickets):
-            ni = arr.shape[1]
-            if not fut.cancelled():
-                fut.set_result(out[:, off:off + ni])
-            if cb is not None and ticket is not None:
-                try:
-                    cb(ticket)
-                except Exception:
-                    pass    # attribution must never sink the flush
-            off += ni
+        with span("ec.deliver", items=len(pb.arrays)):
+            off = 0
+            for arr, fut, cb in zip(pb.arrays, pb.futures, pb.tickets):
+                ni = arr.shape[1]
+                if not fut.cancelled():
+                    fut.set_result(out[:, off:off + ni])
+                if cb is not None and ticket is not None:
+                    try:
+                        cb(ticket)
+                    except Exception:
+                        pass    # attribution must never sink the flush
+                off += ni
 
     async def _encode_shard(self, chip, matrix_key, w: int,
                             klass: str, parts: list[np.ndarray],
@@ -416,23 +411,24 @@ class DeviceBatcher:
             return self._host_shard(chip, matrix_key, w, parts), None
         bufs: list[np.ndarray] = []
         try:
-            for _lo, seg in plan:
-                bufs.append(chip.pool.lease((k, seg), dtype))
-            # pack items contiguously across the ladder (an item can
-            # straddle two segments); leased buffers come back zeroed
-            # so segment tails are exact GF zero columns
-            si, soff = 0, 0
-            for arr in parts:
-                ni, pos = arr.shape[1], 0
-                while pos < ni:
-                    take = min(plan[si][1] - soff, ni - pos)
-                    bufs[si][:, soff:soff + take] = \
-                        arr[:, pos:pos + take]
-                    soff += take
-                    pos += take
-                    if soff == plan[si][1]:
-                        si += 1
-                        soff = 0
+            with span("ec.stage", words=n, padded=padded):
+                for _lo, seg in plan:
+                    bufs.append(chip.pool.lease((k, seg), dtype))
+                # pack items contiguously across the ladder (an item
+                # can straddle two segments); leased buffers come back
+                # zeroed so segment tails are exact GF zero columns
+                si, soff = 0, 0
+                for arr in parts:
+                    ni, pos = arr.shape[1], 0
+                    while pos < ni:
+                        take = min(plan[si][1] - soff, ni - pos)
+                        bufs[si][:, soff:soff + take] = \
+                            arr[:, pos:pos + take]
+                        soff += take
+                        pos += take
+                        if soff == plan[si][1]:
+                            si += 1
+                            soff = 0
             chip.launch(ticket)         # injected-fault hook
             enc = self._encoder(matrix_key, int(w))
             outs = []
@@ -441,7 +437,10 @@ class DeviceBatcher:
                 for (_lo, seg), buf in zip(plan, bufs):
                     chip.note_program("ec", (matrix_key, int(w), seg))
                     u = min(seg, used)
-                    outs.append(np.asarray(enc(buf))[:, :u])
+                    with span("ec.dispatch", bytes_in=buf.nbytes,
+                              bytes_out=buf.nbytes // k
+                              * len(matrix_key)):
+                        outs.append(np.asarray(enc(buf))[:, :u])
                     used -= u
             out = (outs[0] if len(outs) == 1
                    else np.concatenate(outs, axis=1))

@@ -131,6 +131,7 @@ def _encode_pallas(bitmatrix: np.ndarray, w: int, k: int, m: int,
             out_specs=pl.BlockSpec((m, tile), lambda i: (i32(0), i32(i))),
             out_shape=jax.ShapeDtypeStruct((m, np_), data.dtype),
             interpret=interpret,
+            name="ec_encode_bitmatrix",
         )(bm, data)
         return out[:, :n] if pad else out
 
@@ -216,6 +217,7 @@ def _xor_schedule_pallas(bitmatrix: np.ndarray, tile: int):
                                    lambda i: (i32(0), i32(i))),
             out_shape=jax.ShapeDtypeStruct((out_rows * 8, P), planes.dtype),
             interpret=interpret,
+            name="ec_xor_schedule",
         )(planes)
 
     return run
@@ -404,6 +406,7 @@ def _fused_xor_pallas(bitmatrix: np.ndarray, tile_lanes: int):
                           (r * 8 + s + 1) * seg] = segs[s][r:r + 1, :]
 
     @jax.jit
+    @jax.named_scope("ec_encode_fused")
     def run(data32: jax.Array) -> jax.Array:
         P = data32.shape[1]
         pad = (-P) % tile_lanes
@@ -419,6 +422,7 @@ def _fused_xor_pallas(bitmatrix: np.ndarray, tile_lanes: int):
                                    lambda i: (i32(0), i32(i))),
             out_shape=jax.ShapeDtypeStruct((m, Pp), jnp.uint32),
             interpret=interpret,
+            name="ec_encode_fused",
         )(data32)
         return out[:, :P] if pad else out
 

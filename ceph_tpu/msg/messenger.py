@@ -43,6 +43,7 @@ import struct
 import time
 import zlib
 
+from ..trace.span import span
 from .message import (Message, UnknownMessage, decode_message,
                       encode_message)
 
@@ -234,8 +235,9 @@ class _PeerClosed(Exception):
 
 async def _write_frame(writer: asyncio.StreamWriter, tag: int,
                        payload: bytes) -> None:
-    writer.write(_HDR.pack(tag, len(payload), zlib.crc32(payload)))
-    writer.write(payload)
+    with span("msgr.write", bytes=len(payload)):
+        writer.write(_HDR.pack(tag, len(payload), zlib.crc32(payload)))
+        writer.write(payload)
     await writer.drain()
 
 
@@ -243,7 +245,12 @@ async def _read_frame(reader: asyncio.StreamReader) -> tuple[int, bytes]:
     hdr = await reader.readexactly(_HDR.size)
     tag, length, crc = _HDR.unpack(hdr)
     payload = await reader.readexactly(length)
-    if zlib.crc32(payload) != crc:
+    if tag == TAG_MSG:
+        with span("msgr.read_decode", bytes=length):
+            ok = zlib.crc32(payload) == crc
+    else:       # an ack or a close: a few bytes, not a span's worth
+        ok = zlib.crc32(payload) == crc
+    if not ok:
         raise ConnectionError_("frame crc mismatch (tag %d)" % tag)
     return tag, payload
 
@@ -289,7 +296,8 @@ class Connection:
         msg.src = self.msgr.entity
         # frames carry the sender's monotonic clock so the receiver
         # can estimate this peer's clock offset (multi-host span merge)
-        data = encode_message(msg, stamp=self.msgr.now())
+        with span("msgr.encode"):
+            data = encode_message(msg, stamp=self.msgr.now())
         if self.policy.resend:
             self.unacked.append((msg.seq, data))
         if _ACCOUNTING:
@@ -592,7 +600,8 @@ class Connection:
                     if self.policy.resend:
                         return      # transport fault: replay later
                     continue        # lossy: the frame vanishes
-                msg = decode_message(payload)  # poison frame = fault
+                with span("msgr.read_decode", bytes=len(payload)):
+                    msg = decode_message(payload)  # poison frame = fault
                 # received payload size: the ingest bytes accounting
                 # (mgr report telemetry) reads it off the message
                 msg.wire_bytes = len(payload)
@@ -1077,7 +1086,8 @@ class Messenger:
                 handler = getattr(d, "ms_dispatch", None)
                 if handler is None:
                     continue
-                res = handler(conn, msg)
+                with span("msgr.dispatch"):
+                    res = handler(conn, msg)
                 if asyncio.iscoroutine(res):
                     res = await res
                 if res:

@@ -69,6 +69,7 @@ from ...models.crushmap import (
     TAKE,
     CrushMap,
 )
+from ...trace.span import span
 from ._ln_tables import LL_TBL, RH_LH_TBL
 
 S64_MAX = (1 << 63) - 1
@@ -417,6 +418,8 @@ class FlatMap:
         self._row_cache: dict[int, np.ndarray] = {}
         self._roww_cache: dict[int, np.ndarray] = {}
         self._wpair_cache: dict[int, np.ndarray] = {}
+        # lanes of a dense pass -> its descents were traced in Pallas
+        self.descent_in_pallas: dict[int, bool] = {}
         # per-bucket metadata fetch for arbitrary bucket ids (the child
         # bucket chosen during descent): size(2) + btype(2)
         meta = np.zeros((B, 4), np.int8)
@@ -717,12 +720,16 @@ def _descend(fm: FlatMap, take_bid, x, r, want_type: int, pos,
     L = x.shape[0]
     if not resolve:
         from . import pallas_draw
-        if L % pallas_draw.TL == 0:
-            fn = _get_pallas_descend(fm, depth_sizes, want_type)
-            if fn is not None:
-                item, status = fn(x, r, take_bid, pos)
-                return (item, (status & 1) != 0, (status & 2) != 0,
-                        (status & 4) != 0)
+        fn = (_get_pallas_descend(fm, depth_sizes, want_type)
+              if L % pallas_draw.TL == 0 else None)
+        # which descent this lane count was built with, for the
+        # pallas_lanes count on the launch span: the XLA switch below
+        # is otherwise silent
+        fm.descent_in_pallas[L] = fn is not None
+        if fn is not None:
+            item, status = fn(x, r, take_bid, pos)
+            return (item, (status & 1) != 0, (status & 2) != 0,
+                    (status & 4) != 0)
     cur = take_bid
     item = jnp.full((L,), ITEM_NONE, jnp.int32)
     ok = jnp.zeros((L,), bool)
@@ -1511,8 +1518,10 @@ class DeviceMapper:
         def run(dev_weights, exists_b, isup_b, aff):
             def body(_, start):
                 xs = chunk(start)
-                raw, flag = core(xs, dev_weights)
-                up, prim = post(raw, xs, exists_b, isup_b, aff)
+                with jax.named_scope("crush_descend"):
+                    raw, flag = core(xs, dev_weights)
+                with jax.named_scope("crush_post"):
+                    up, prim = post(raw, xs, exists_b, isup_b, aff)
                 return 0, (raw, up, prim, flag)
 
             starts = (jnp.arange(n_chunks, dtype=jnp.uint32)
@@ -1636,6 +1645,7 @@ class DeviceMapper:
                   npg, self.RC_ROW, kt, pg_num) if kt else None)
 
         @jax.jit
+        @jax.named_scope("crush_resolve")
         def run(raw_t, up, prim, flag, w, ex, iu, af):
             if rc is not None:
                 idxp, validp, cnt = rc(flag)
@@ -1667,7 +1677,10 @@ class DeviceMapper:
         state = self.map_pool_state(
             ruleno, result_max, pg_num, pgp_num, pgp_num_mask, pool_id,
             hashpspool, dev_weights, exists, isup, aff, can_shift)
-        return np.array(state.up), np.array(state.prim)
+        with span("crush.readback", bytes=pg_num * (
+                state.up_full.nbytes + state.prim_full.nbytes)
+                // state.npg):
+            return np.array(state.up), np.array(state.prim)
 
     def map_pool_state(self, ruleno: int, result_max: int, pg_num: int,
                        pgp_num: int, pgp_num_mask: int, pool_id: int,
@@ -1682,8 +1695,10 @@ class DeviceMapper:
         iu_np = np.asarray(isup, dtype=bool)
         af_np = (np.asarray(aff, dtype=np.int32) if use_aff
                  else np.zeros((ex_np.shape[0],), np.int32))
-        w, ex = jnp.asarray(w_np), jnp.asarray(ex_np)
-        iu, af = jnp.asarray(iu_np), jnp.asarray(af_np)
+        with span("crush.upload", bytes=w_np.nbytes + ex_np.nbytes
+                  + iu_np.nbytes + af_np.nbytes):
+            w, ex = jnp.asarray(w_np), jnp.asarray(ex_np)
+            iu, af = jnp.asarray(iu_np), jnp.asarray(af_np)
         C = min(self.CHUNK, max(8, -(-pg_num // 8) * 8))
         n_chunks = -(-pg_num // C)
         npg = C * n_chunks
@@ -1691,7 +1706,9 @@ class DeviceMapper:
                                  use_aff, int(pgp_num),
                                  int(pgp_num_mask), int(pool_id),
                                  bool(hashpspool), C, n_chunks)
-        raw, up, prim, flag = fn(w, ex, iu, af)
+        with span("crush.launch", lanes=npg, pallas_lanes=(
+                npg if self.fm.descent_in_pallas.get(C) else 0)):
+            raw, up, prim, flag = fn(w, ex, iu, af)
         K1 = max(64, min(1 << 16,
                          1 << (max(1, pg_num - 1)).bit_length()))
         K2 = max(8, min(1 << 13, K1))
@@ -1702,10 +1719,12 @@ class DeviceMapper:
                 ruleno, result_max, bool(can_shift), use_aff,
                 int(pgp_num), int(pgp_num_mask), int(pool_id),
                 bool(hashpspool), K1, K2, K3, npg, pg_num, kt)
-            raw2, up2, prim2, counts = res(raw, up, prim, flag,
-                                           w, ex, iu, af)
-            nflag, n2, ndust, rowmax = (int(v)
-                                        for v in np.asarray(counts))
+            with span("crush.launch"):
+                raw2, up2, prim2, counts = res(raw, up, prim, flag,
+                                               w, ex, iu, af)
+            with span("crush.wait"):
+                nflag, n2, ndust, rowmax = (
+                    int(v) for v in np.asarray(counts))
             if kt and rowmax > kt:
                 # a row group overflowed its compaction slots: widen
                 kt = 128 * (-(-int(rowmax * 2) // 128))

@@ -423,6 +423,7 @@ def make_descend_kernel(fm, depth_sizes: tuple, want_type: int):
             out_specs=(lane, lane),
             out_shape=(shp, shp),
             interpret=interp,
+            name="crush_straw2_descend",
         )(x.reshape(8, W).astype(jnp.int32),
           r.reshape(8, W).astype(jnp.int32),
           bid.reshape(8, W).astype(jnp.int32),
@@ -507,6 +508,7 @@ def make_post_kernel(D: int, S: int, can_shift: bool):
             out_specs=tuple([lane] * S + [lane]),
             out_shape=tuple([shp] * S + [shp]),
             interpret=interp,
+            name="crush_post_up",
         )(kp, *cols)
         up = jnp.stack([o.reshape(L) for o in outs[:S]], axis=1)
         return up, outs[S].reshape(L)
@@ -566,6 +568,7 @@ def make_hitscan_kernel(D: int, S: int):
             out_specs=lane,
             out_shape=jax.ShapeDtypeStruct((8, W), jnp.int32),
             interpret=interp,
+            name="crush_hitscan",
         )(cp, *cols)
         return out.reshape(L) != 0
 
@@ -724,6 +727,7 @@ def make_rowcompact_kernel(n_lanes: int, row: int, kt: int,
                 jax.ShapeDtypeStruct((nr, 128), jnp.int32),
             ],
             interpret=interp,
+            name="crush_rowcompact",
         )(h2, jnp.asarray(U128), jnp.asarray(LxB), jnp.asarray(Gm))
         return (idx.reshape(-1), val.reshape(-1) != 0, cnt[:, 0])
 

@@ -46,6 +46,7 @@ from ..models.crushmap import ITEM_NONE
 from ..store.memstore import MemStore
 from ..store.objectstore import (NotFound, ObjectStore, Transaction,
                                  coll_t, hobject_t)
+from ..trace.span import span, watch_gc
 from ..utils import denc
 from ..utils.context import Context
 from .osdmap import OSDMap, consume_map_payload, pg_t
@@ -260,6 +261,7 @@ class OSD:
     # -- lifecycle ---------------------------------------------------------
 
     async def start(self, host: str = "127.0.0.1", port: int = 0) -> str:
+        watch_gc()
         self.store.mount()
         # previous incarnation's crash reports (the reboot ships them
         # to the mons; the paxos-committed ack clears them here)
@@ -835,6 +837,10 @@ class OSD:
                 self.store.apply_transaction(t)
 
     def _advance_pgs(self) -> None:
+        with span("osd.advance_pgs"):
+            self._advance_pgs_inner()
+
+    def _advance_pgs_inner(self) -> None:
         """Recompute mappings; create/advance PGs (OSD::advance_map).
         Large maps route through the bulk device mapper instead of
         per-PG scalar calls (the ParallelPGMapper role,
@@ -1777,6 +1783,10 @@ class OSD:
     # -- client ops --------------------------------------------------------
 
     def _handle_op(self, conn, msg: MOSDOp) -> None:
+        with span("osd.handle_op"):
+            self._handle_op_inner(conn, msg)
+
+    def _handle_op_inner(self, conn, msg: MOSDOp) -> None:
         self._op_event(msg, "reached_pg")
         if self.osdmap is None or msg.epoch > self.osdmap.epoch:
             self._op_event(msg, "waiting_for_map")
@@ -2655,84 +2665,89 @@ class OSD:
             await asyncio.sleep(conf["heartbeat_interval"])
             if self.osdmap is None or not self.booted:
                 continue
-            # recovery watchdog (OSD tick -> RecoveryPreemption /
-            # queue_recovery): a push flow aborted by an interval
-            # change or a dropped reply must not strand missing
-            # objects — re-kick any primary PG with outstanding work
-            # and re-check pg_temp release
-            for pg in list(self.pgs.values()):
-                if not pg.is_primary() or pg.state != STATE_ACTIVE:
-                    continue
-                if (pg.missing
-                        or any(pg.peer_missing.get(o)
-                               for o in pg.peer_missing)) \
-                        and not getattr(pg, "_recovery_flow", False):
-                    self._kick_recovery(pg)
-                elif pg.waiting_for_active and not pg.missing:
-                    # safety net against stuck parked ops: an active,
-                    # whole PG with waiters means a requeue edge was
-                    # lost (e.g. the push-reply that should have fired
-                    # it raced an interval flip) — requeue now, gated
-                    # on min_size so a still-degraded PG does not spin
-                    pool = self.osdmap.pools.get(pg.pool_id)
-                    if pool is not None and self._min_size_ok(pg,
-                                                              pool):
-                        self._requeue_waiters(pg)
-                self._maybe_clear_pg_temp(pg)
-            self._maybe_schedule_scrub()
-            self._maybe_send_mgr_report()
-            self._maybe_send_beacon()
-            # event plane: re-flush unacked clog entries and pending
-            # crash reports (delivery survives leader elections)
-            self.clog.flush()
-            self._maybe_ship_crashes()
-            now = time.monotonic()
-            grace = conf["heartbeat_grace"]
-            # prune state for peers the map says are down, so a later
-            # reboot starts with a fresh window instead of a stale
-            # stamp that would instantly re-report it failed
-            for osd in list(self.hb_last_rx):
-                if osd >= self.osdmap.max_osd \
-                        or not self.osdmap.is_up(osd):
-                    del self.hb_last_rx[osd]
-            # network plane housekeeping: the RTT tracker prunes by
-            # the same rule, the messenger drops dead osd peers'
-            # clock-offset and folded-wire entries (both tables would
-            # otherwise grow forever across kill/revive cycles), the
-            # wire ring takes a cumulative per-peer byte sample for
-            # the chrome-trace counter tracks, and the messenger
-            # resend/replay totals land in the perf counters
-            alive = [osd for osd in range(self.osdmap.max_osd)
-                     if self.osdmap.is_up(osd)]
-            self.network.prune(alive)
-            self.msgr.prune_peer_state("osd.%d" % o for o in alive)
-            net_rows = self.msgr.net_dump()
-            self.network.sample_wire(
-                now, {k: v for k, v in net_rows.items()
-                      if k.startswith("osd.")})
-            self.perf.set("msgr_resends", sum(
-                r["resends"] for r in net_rows.values()))
-            self.perf.set("msgr_replays", sum(
-                r["replays"] for r in net_rows.values()))
-            self.perf.set("msgr_mark_downs", sum(
-                r["mark_downs"] for r in net_rows.values()))
-            for osd in range(self.osdmap.max_osd):
-                if osd == self.whoami or not self.osdmap.is_up(osd):
-                    continue
-                addr = self.osdmap.osd_addrs.get(osd)
-                if not addr:
-                    continue
-                self.msgr.send_to(addr, MOSDPing(
-                    osd=self.whoami, op="ping", stamp=now,
-                    epoch=self.osdmap.epoch),
-                    entity_hint="osd.%d" % osd)
-                last = self.hb_last_rx.get(osd)
-                if last is None:
-                    self.hb_last_rx[osd] = now
-                elif now - last > grace:
-                    self._send_mons(MOSDFailure(
-                        target=osd, failed_for=now - last,
-                        epoch=self.osdmap.epoch))
+            with span("heartbeat"):
+                self._heartbeat_tick()
+
+    def _heartbeat_tick(self) -> None:
+        conf = self.ctx.conf
+        # recovery watchdog (OSD tick -> RecoveryPreemption /
+        # queue_recovery): a push flow aborted by an interval
+        # change or a dropped reply must not strand missing
+        # objects — re-kick any primary PG with outstanding work
+        # and re-check pg_temp release
+        for pg in list(self.pgs.values()):
+            if not pg.is_primary() or pg.state != STATE_ACTIVE:
+                continue
+            if (pg.missing
+                    or any(pg.peer_missing.get(o)
+                           for o in pg.peer_missing)) \
+                    and not getattr(pg, "_recovery_flow", False):
+                self._kick_recovery(pg)
+            elif pg.waiting_for_active and not pg.missing:
+                # safety net against stuck parked ops: an active,
+                # whole PG with waiters means a requeue edge was
+                # lost (e.g. the push-reply that should have fired
+                # it raced an interval flip) — requeue now, gated
+                # on min_size so a still-degraded PG does not spin
+                pool = self.osdmap.pools.get(pg.pool_id)
+                if pool is not None and self._min_size_ok(pg,
+                                                          pool):
+                    self._requeue_waiters(pg)
+            self._maybe_clear_pg_temp(pg)
+        self._maybe_schedule_scrub()
+        self._maybe_send_mgr_report()
+        self._maybe_send_beacon()
+        # event plane: re-flush unacked clog entries and pending
+        # crash reports (delivery survives leader elections)
+        self.clog.flush()
+        self._maybe_ship_crashes()
+        now = time.monotonic()
+        grace = conf["heartbeat_grace"]
+        # prune state for peers the map says are down, so a later
+        # reboot starts with a fresh window instead of a stale
+        # stamp that would instantly re-report it failed
+        for osd in list(self.hb_last_rx):
+            if osd >= self.osdmap.max_osd \
+                    or not self.osdmap.is_up(osd):
+                del self.hb_last_rx[osd]
+        # network plane housekeeping: the RTT tracker prunes by
+        # the same rule, the messenger drops dead osd peers'
+        # clock-offset and folded-wire entries (both tables would
+        # otherwise grow forever across kill/revive cycles), the
+        # wire ring takes a cumulative per-peer byte sample for
+        # the chrome-trace counter tracks, and the messenger
+        # resend/replay totals land in the perf counters
+        alive = [osd for osd in range(self.osdmap.max_osd)
+                 if self.osdmap.is_up(osd)]
+        self.network.prune(alive)
+        self.msgr.prune_peer_state("osd.%d" % o for o in alive)
+        net_rows = self.msgr.net_dump()
+        self.network.sample_wire(
+            now, {k: v for k, v in net_rows.items()
+                  if k.startswith("osd.")})
+        self.perf.set("msgr_resends", sum(
+            r["resends"] for r in net_rows.values()))
+        self.perf.set("msgr_replays", sum(
+            r["replays"] for r in net_rows.values()))
+        self.perf.set("msgr_mark_downs", sum(
+            r["mark_downs"] for r in net_rows.values()))
+        for osd in range(self.osdmap.max_osd):
+            if osd == self.whoami or not self.osdmap.is_up(osd):
+                continue
+            addr = self.osdmap.osd_addrs.get(osd)
+            if not addr:
+                continue
+            self.msgr.send_to(addr, MOSDPing(
+                osd=self.whoami, op="ping", stamp=now,
+                epoch=self.osdmap.epoch),
+                entity_hint="osd.%d" % osd)
+            last = self.hb_last_rx.get(osd)
+            if last is None:
+                self.hb_last_rx[osd] = now
+            elif now - last > grace:
+                self._send_mons(MOSDFailure(
+                    target=osd, failed_for=now - last,
+                    epoch=self.osdmap.epoch))
 
     # -- periodic scrub (the always-on integrity plane) --------------------
 

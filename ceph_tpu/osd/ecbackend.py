@@ -46,6 +46,7 @@ from ..msg.messages import (MOSDECSubOpRead, MOSDECSubOpReadReply,
                             MOSDECSubOpWrite, MOSDECSubOpWriteReply,
                             MOSDOpReply, MOSDPGPush)
 from ..store.objectstore import NotFound, Transaction, hobject_t
+from ..trace.span import mark, span
 from ..utils import denc
 from .pg import PG, LogEntry
 
@@ -488,14 +489,16 @@ class ECPGBackend:
                                      whiteout=whiteout,
                                      top=getattr(msg, "_top", None),
                                      reqid=(msg.src, msg.tid, outs))
-        ver = pg.info.last_update[1]
-        conn.send(MOSDOpReply(tid=msg.tid, result=0 if ok else -11,
-                              outs=outs, epoch=self.osd.osdmap.epoch,
-                              version=ver))
-        self.osd.perf.inc("ops")
-        if ok:
-            pg.stats.note_write(wbytes)
-        self.osd._op_finish(msg, "ec_write_done")
+        with span("osd.ec.op"):
+            ver = pg.info.last_update[1]
+            conn.send(MOSDOpReply(tid=msg.tid, result=0 if ok else -11,
+                                  outs=outs,
+                                  epoch=self.osd.osdmap.epoch,
+                                  version=ver))
+            self.osd.perf.inc("ops")
+            if ok:
+                pg.stats.note_write(wbytes)
+            self.osd._op_finish(msg, "ec_write_done")
 
     # -- write path --------------------------------------------------------
 
@@ -616,39 +619,40 @@ class ECPGBackend:
             pm.pop(oid, None)
         shards = (None if is_delete
                   else await self._encode_shards(pg, data, top=top))
-        hinfo = None if shards is None else hinfo_bytes(shards)
-        ho = hobject_t(oid)
+        with span("osd.ec.submit"):
+            hinfo = None if shards is None else hinfo_bytes(shards)
+            ho = hobject_t(oid)
 
-        txns: dict[int, Transaction] = {}
-        for j, osd_id in enumerate(pg.acting):
-            if osd_id == ITEM_NONE or osd_id < 0:
-                continue
-            t = Transaction()
-            if clone_to is not None:
-                t.clone(pg.cid, ho, hobject_t(oid, snap=clone_to))
-            if is_delete and whiteout:
-                t.truncate(pg.cid, ho, 0)
-                t.setattr(pg.cid, ho, snapmod.WHITEOUT_ATTR, b"1")
-                t.setattr(pg.cid, ho, VER_XATTR, _ver_bytes(version))
-            elif is_delete:
-                t.remove(pg.cid, ho)
-            else:
-                t.append(self._shard_txn(pg, ho, shards[j], j,
-                                         len(data), version, xattrs,
-                                         hinfo))
-                if snapset_b is not None:
-                    t.setattr(pg.cid, ho, snapmod.WHITEOUT_ATTR, b"0")
-            if snapset_b is not None and not (is_delete
-                                              and not whiteout):
-                t.setattr(pg.cid, ho, snapmod.SNAPSET_ATTR, snapset_b)
-            for sn in (sna_snaps or ()):
-                t.omap_setkeys(pg.cid, PGMETA_OID,
-                               {snapmod.sna_key(sn, oid): b"1"})
-            txns[j] = t
-        if reqid is not None:
-            src, tid, outs = reqid
-            pg.record_reqid(list(txns.values()), src, tid, 0,
-                            list(outs), version[1])
+            txns: dict[int, Transaction] = {}
+            for j, osd_id in enumerate(pg.acting):
+                if osd_id == ITEM_NONE or osd_id < 0:
+                    continue
+                t = Transaction()
+                if clone_to is not None:
+                    t.clone(pg.cid, ho, hobject_t(oid, snap=clone_to))
+                if is_delete and whiteout:
+                    t.truncate(pg.cid, ho, 0)
+                    t.setattr(pg.cid, ho, snapmod.WHITEOUT_ATTR, b"1")
+                    t.setattr(pg.cid, ho, VER_XATTR, _ver_bytes(version))
+                elif is_delete:
+                    t.remove(pg.cid, ho)
+                else:
+                    t.append(self._shard_txn(pg, ho, shards[j], j,
+                                             len(data), version, xattrs,
+                                             hinfo))
+                    if snapset_b is not None:
+                        t.setattr(pg.cid, ho, snapmod.WHITEOUT_ATTR, b"0")
+                if snapset_b is not None and not (is_delete
+                                                  and not whiteout):
+                    t.setattr(pg.cid, ho, snapmod.SNAPSET_ATTR, snapset_b)
+                for sn in (sna_snaps or ()):
+                    t.omap_setkeys(pg.cid, PGMETA_OID,
+                                   {snapmod.sna_key(sn, oid): b"1"})
+                txns[j] = t
+            if reqid is not None:
+                src, tid, outs = reqid
+                pg.record_reqid(list(txns.values()), src, tid, 0,
+                                list(outs), version[1])
         ok = await self._commit_shard_txns(pg, oid, entry, txns,
                                            top=top)
         if reqid is not None and not ok:
@@ -673,37 +677,38 @@ class ECPGBackend:
         ev = asyncio.Event()
         st = {"waiting": waiting, "event": ev}
         self._writes[tid] = st
-        for j, t in txns.items():
-            osd_id = pg.acting[j]
-            if osd_id == ITEM_NONE or osd_id < 0:
-                continue
-            if osd_id != self.osd.whoami \
-                    and not self.osd.osdmap.is_up(osd_id):
-                # a member the map already knows is down cannot ack:
-                # mark it behind immediately instead of stalling the
-                # client write on the sub-op timeout — but it still
-                # counts as NOT applied for the >= k durability check
-                pg.peer_missing.setdefault(osd_id, {})[oid] = entry.op
-                down_skipped.add(osd_id)
-                continue
-            if osd_id == self.osd.whoami:
-                entryt = Transaction()
-                entryt.append(t)
-                pg.persist_log_entry(entryt, entry)
-                pg.maybe_trim_log(entryt)
-                pg.persist_meta(entryt)
-                self.osd.store.apply_transaction(entryt)
-            else:
-                waiting.add(osd_id)
-                sub = MOSDECSubOpWrite(
-                    pool=pg.pool_id, ps=pg.ps, shard=j, tid=tid,
-                    txn=denc.encode(t.to_wire()),
-                    log_entry=entry.to_wire(), epoch=epoch)
-                # the sub-op joins the client op's cross-daemon span
-                # (and its tenant rides along for shard-side books)
-                sub.trace = top.trace if top is not None else None
-                sub.tenant = top.tenant if top is not None else None
-                self.osd._send_osd(osd_id, sub)
+        with span("osd.ec.submit"):
+            for j, t in txns.items():
+                osd_id = pg.acting[j]
+                if osd_id == ITEM_NONE or osd_id < 0:
+                    continue
+                if osd_id != self.osd.whoami \
+                        and not self.osd.osdmap.is_up(osd_id):
+                    # a member the map already knows is down cannot ack:
+                    # mark it behind immediately instead of stalling the
+                    # client write on the sub-op timeout — but it still
+                    # counts as NOT applied for the >= k durability check
+                    pg.peer_missing.setdefault(osd_id, {})[oid] = entry.op
+                    down_skipped.add(osd_id)
+                    continue
+                if osd_id == self.osd.whoami:
+                    entryt = Transaction()
+                    entryt.append(t)
+                    pg.persist_log_entry(entryt, entry)
+                    pg.maybe_trim_log(entryt)
+                    pg.persist_meta(entryt)
+                    self.osd.store.apply_transaction(entryt)
+                else:
+                    waiting.add(osd_id)
+                    sub = MOSDECSubOpWrite(
+                        pool=pg.pool_id, ps=pg.ps, shard=j, tid=tid,
+                        txn=denc.encode(t.to_wire()),
+                        log_entry=entry.to_wire(), epoch=epoch)
+                    # the sub-op joins the client op's cross-daemon span
+                    # (and its tenant rides along for shard-side books)
+                    sub.trace = top.trace if top is not None else None
+                    sub.tenant = top.tenant if top is not None else None
+                    self.osd._send_osd(osd_id, sub)
         if waiting:
             if top is not None:
                 top.mark_event("ec_sub_write_sent")
@@ -713,6 +718,8 @@ class ECPGBackend:
                     float(self.osd.ctx.conf["osd_ec_subop_timeout"]))
             except asyncio.TimeoutError:
                 pass
+            if st["waiting"]:
+                mark("osd.ec.subop_timeout")
             if top is not None:
                 top.mark_event("ec_sub_write_acked"
                                if not st["waiting"]
@@ -1034,36 +1041,38 @@ class ECPGBackend:
 
     def handle_sub_write(self, conn, msg: MOSDECSubOpWrite) -> None:
         """Shard side (ECBackend::handle_sub_write)."""
-        from .osdmap import pg_t
+        with span("osd.ec.sub_write"):
+            from .osdmap import pg_t
 
-        pgid = pg_t(msg.pool, msg.ps)
-        pg = self.osd.pgs.get(pgid)
-        if pg is None:
-            pg = PG(self.osd, msg.pool, msg.ps)
-            pg.create_onstore()
-            self.osd.pgs[pgid] = pg
-        t = Transaction.from_wire(denc.decode(msg.txn))
-        entry = LogEntry.from_wire(msg.log_entry)
-        pg.log.append(entry)
-        pg.info.last_update = entry.version
-        pg.missing.pop(entry.oid, None)  # the write heals the object
-        pg.persist_log_entry(t, entry)
-        pg.maybe_trim_log(t)
-        pg.persist_meta(t)
-        self.osd.store.apply_transaction(t)
-        conn.send(MOSDECSubOpWriteReply(
-            pool=msg.pool, ps=msg.ps, shard=msg.shard, tid=msg.tid,
-            result=0, epoch=msg.epoch))
-        self.osd._op_finish(msg, "ec_shard_applied")
+            pgid = pg_t(msg.pool, msg.ps)
+            pg = self.osd.pgs.get(pgid)
+            if pg is None:
+                pg = PG(self.osd, msg.pool, msg.ps)
+                pg.create_onstore()
+                self.osd.pgs[pgid] = pg
+            t = Transaction.from_wire(denc.decode(msg.txn))
+            entry = LogEntry.from_wire(msg.log_entry)
+            pg.log.append(entry)
+            pg.info.last_update = entry.version
+            pg.missing.pop(entry.oid, None)  # the write heals the object
+            pg.persist_log_entry(t, entry)
+            pg.maybe_trim_log(t)
+            pg.persist_meta(t)
+            self.osd.store.apply_transaction(t)
+            conn.send(MOSDECSubOpWriteReply(
+                pool=msg.pool, ps=msg.ps, shard=msg.shard, tid=msg.tid,
+                result=0, epoch=msg.epoch))
+            self.osd._op_finish(msg, "ec_shard_applied")
 
     def handle_sub_write_reply(self, msg: MOSDECSubOpWriteReply) -> None:
-        st = self._writes.get(msg.tid)
-        if st is None:
-            return
-        sender = int(msg.src.split(".")[1])
-        st["waiting"].discard(sender)
-        if not st["waiting"]:
-            st["event"].set()
+        with span("osd.ec.sub_reply"):
+            st = self._writes.get(msg.tid)
+            if st is None:
+                return
+            sender = int(msg.src.split(".")[1])
+            st["waiting"].discard(sender)
+            if not st["waiting"]:
+                st["event"].set()
 
     # -- read path ---------------------------------------------------------
 

@@ -39,6 +39,8 @@ from __future__ import annotations
 import asyncio
 import time
 
+from ..trace.span import span
+
 K_CLIENT = "client"
 K_RECOVERY = "recovery"
 K_SCRUB = "scrub"
@@ -311,29 +313,34 @@ class OpScheduler:
                 except asyncio.TimeoutError:
                     pass
                 continue
-            fn, waited = sh.pop(val, phase)
-            base, tenant = ((val[0], val[1])
-                            if isinstance(val, tuple)
-                            else (val, None))
-            self.dispatched[base] = self.dispatched.get(base, 0) + 1
-            if tenant is not None:
-                self.tenant_dispatched[tenant] = \
-                    self.tenant_dispatched.get(tenant, 0) + 1
-            book = self.queue_wait[base]
-            book[0] += 1
-            book[1] += waited
-            if self.on_wait is not None:
-                try:
-                    self.on_wait(base, waited, tenant)
-                except Exception:
-                    pass    # observability must never sink the worker
             try:
-                r = fn()
+                with span("osd.dequeue"):
+                    fn, waited = sh.pop(val, phase)
+                    self._book(val, waited)
+                    r = fn()
                 if asyncio.iscoroutine(r) or isinstance(r, asyncio.Future):
                     await r
             except Exception:       # worker must survive op failures
                 import traceback
                 traceback.print_exc()
+
+    def _book(self, val, waited: float) -> None:
+        """Dispatch counts and queue-wait books of one dequeued op."""
+        base, tenant = ((val[0], val[1])
+                        if isinstance(val, tuple)
+                        else (val, None))
+        self.dispatched[base] = self.dispatched.get(base, 0) + 1
+        if tenant is not None:
+            self.tenant_dispatched[tenant] = \
+                self.tenant_dispatched.get(tenant, 0) + 1
+        book = self.queue_wait[base]
+        book[0] += 1
+        book[1] += waited
+        if self.on_wait is not None:
+            try:
+                self.on_wait(base, waited, tenant)
+            except Exception:
+                pass    # observability must never sink the worker
 
     # -- entry points ------------------------------------------------------
 

@@ -33,6 +33,7 @@ from ..device.runtime import DeviceBusy, DeviceRuntime, K_MAPPING
 from ..models.crushmap import ITEM_NONE
 from ..ops.crush.hashes import hash32_2_v
 from ..osd.osdmap import OSD_EXISTS, OSD_UP, OSDMap, PGPool, pg_t
+from ..trace.span import span
 from ..utils.log import global_logger
 
 class PoolMapping:
@@ -72,7 +73,8 @@ class OSDMapMapping:
         self.pools: dict[int, PoolMapping] = {}
         self.device_pools = 0      # pools mapped on device this build
         self.scalar_pools = 0      # pools that fell back to host
-        self._build(osdmap, device_mapper, runtime, chip)
+        with span("crush.build", pools=len(osdmap.pools)):
+            self._build(osdmap, device_mapper, runtime, chip)
 
     def _build(self, osdmap: OSDMap, device_mapper, runtime,
                chip: int | None) -> None:
@@ -104,9 +106,10 @@ class OSDMapMapping:
                 self.scalar_pools += 1
             else:
                 self.device_pools += 1
-            pm = PoolMapping(pool, up, prim)
-            self._apply_exceptions(osdmap, pool, pm)
-            self.pools[pool.id] = pm
+            with span("crush.tables"):
+                pm = PoolMapping(pool, up, prim)
+                self._apply_exceptions(osdmap, pool, pm)
+                self.pools[pool.id] = pm
 
     def _map_pool_ticketed(self, osdmap, pool, dm, chip,
                            exists, isup, aff):

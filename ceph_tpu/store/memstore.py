@@ -10,6 +10,7 @@ from __future__ import annotations
 import threading
 from typing import Callable
 
+from ..trace.span import span
 from .objectstore import (
     OP_CLONE,
     OP_CLONERANGE2,
@@ -104,7 +105,7 @@ class MemStore(ObjectStore):
         on_applied: Callable[[], None] | None = None,
         on_commit: Callable[[], None] | None = None,
     ) -> None:
-        with self._lock:
+        with span("store.apply", txns=len(txs)), self._lock:
             for tx in txs:
                 self._apply(tx)
         if on_applied:

@@ -25,6 +25,17 @@ from __future__ import annotations
 import itertools
 import time
 
+from .span import mark
+
+# the stage stamps `op.retired` reads, by slot: a wait is the first
+# stamp of its start to the last stamp of its end
+_STAGE_SLOT = {"queued": 0, "reached_pg": 1,
+               "ec_encode_start": 2, "ec_encoded": 3,
+               "ec_sub_write_sent": 4, "ec_sub_write_acked": 5,
+               "ec_sub_write_timeout": 5}
+_STAGE_WAITS = (("queue_us", 0, 1), ("ec_batch_us", 2, 3),
+                ("subop_us", 4, 5))
+
 
 class TrackedOp:
     """One tracked request on one daemon (TrackedOp/OpRequest)."""
@@ -120,6 +131,7 @@ class OpTracker:
         self.ctx = ctx
         self.daemon = daemon
         self._seq = itertools.count(1)
+        self._client = int(daemon.startswith("client."))
         self.ops: dict[int, TrackedOp] = {}
         self.historic: list[TrackedOp] = []
         self.historic_slow: list[TrackedOp] = []
@@ -174,11 +186,26 @@ class OpTracker:
             if len(self.historic_slow) > scap:
                 del self.historic_slow[:len(self.historic_slow) - scap]
         self.recorder.note_op(op, slow=slow)
+        self._mark_retired(op)
         if self.on_retire is not None:
             try:
                 self.on_retire(op)
             except Exception:
                 pass    # observability must never sink the op path
+
+    def _mark_retired(self, op: TrackedOp) -> None:
+        """The op's stage waits onto the profiler's clock, every op,
+        unsampled: one pass over the stamps it already carries."""
+        at = [None] * 6
+        for t, event in op.events:
+            slot = _STAGE_SLOT.get(event)
+            if slot is not None and (slot & 1 or at[slot] is None):
+                at[slot] = t
+        args = {name: int((at[hi] - at[lo]) * 1e6)
+                for name, lo, hi in _STAGE_WAITS
+                if at[lo] is not None and at[hi] is not None}
+        mark("op.retired", total_us=int(op.age * 1e6),
+             client=self._client, **args)
 
     # -- slow-op detection ---------------------------------------------
 

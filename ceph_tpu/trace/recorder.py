@@ -35,15 +35,12 @@ backend leg vs recorder-off).
 
 from __future__ import annotations
 
-import os
 import time
 import zlib
 
 # process-wide enable switch (bench.py --trace measures the recorder's
-# overhead by flipping it); env CEPH_TPU_FLIGHT_RECORDER=0 disables at
-# boot for A/B runs outside the bench
-_ENABLED = os.environ.get("CEPH_TPU_FLIGHT_RECORDER", "1") \
-    not in ("0", "false", "no")
+# overhead by flipping it through set_enabled)
+_ENABLED = True
 
 _DEVICE_RING_CAP = 4096
 _DEVICE_RING: list[dict] = []

@@ -1,0 +1,131 @@
+"""Host spans of the program on the profiler's clock.
+
+``span(name, **args)`` is a ``jax.profiler.TraceAnnotation`` named
+``rados.<name>``: while a profiler session runs (``jax.profiler.
+start_trace``, which the benchmark's ``--trace 1`` does) the span lands
+in the profiler's own trace file, on the clock of the device events,
+with its keyword arguments as the event's stats.  With no session it is
+an inactive TraceMe (about half a microsecond).  There is no switch of
+its own: tracing is on when a profiler is.
+
+One thread runs every coroutine of a cluster, so a span held across an
+``await`` would nest with other requests' spans and self times would
+subtract the wrong children.  **A span wraps a synchronous section
+only**: no ``await``, ``yield`` or ``async with`` inside its body
+(tests/test_spans.py walks the AST).  Arguments are integers the caller
+already holds.
+
+``SPANS`` is the one registry: name -> (layer of PERF.md section 3, what
+the span covers).  A name that is not in it raises at the emit site, and
+tests/test_spans.py holds the table and the emit sites to each other.
+Never name a span ``bench.*``: the benchmark finds its window by that
+prefix.
+"""
+
+from __future__ import annotations
+
+import gc
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "rados."
+
+CLIENT = "client"
+HOST = "host path"
+BATCHER = "batcher and device runtime"
+MAPPING = "bulk mapping"
+
+SPANS: dict[str, tuple[str, str]] = {
+    # -- the served write path, by what the loop's one thread is doing ----
+    "client.calc_target": (CLIENT, "object -> pg -> acting primary on the "
+                           "client's map (host CRUSH)"),
+    "client.submit": (CLIENT, "submit_op: tid, tracked op, first send"),
+    "client.send_op": (CLIENT, "_send_op: target, build MOSDOp, queue it"),
+    "client.handle_reply": (CLIENT, "_handle_reply: retire, resolve the "
+                            "caller's future"),
+    "client.resend": (CLIENT, "mark: the resend ticker sent an op again; "
+                      "age_us since its first send"),
+    "msgr.encode": (HOST, "Connection.send: message -> wire bytes"),
+    "msgr.write": (HOST, "frame header, crc and payload handed to the "
+                   "transport; bytes of payload"),
+    "msgr.read_decode": (HOST, "a message frame's crc check, then wire "
+                         "bytes -> message; bytes of payload"),
+    "msgr.dispatch": (HOST, "a dispatcher's synchronous ms_dispatch; the "
+                      "handlers' own spans nest inside"),
+    "osd.dequeue": (HOST, "op queue: pick, bookkeeping, hand-over to the "
+                    "handler (nests inside)"),
+    "osd.handle_op": (HOST, "OSD._handle_op: checks, dup lookup, spawn "
+                      "the backend's op"),
+    "osd.ec.op": (HOST, "ECPGBackend._do_op: a write's last stretch, "
+                  "after submit_write: reply, counters, retire"),
+    "osd.ec.submit": (HOST, "submit_write after the encode: shard "
+                      "transactions, local apply, sub-op encode and send"),
+    "osd.ec.sub_write": (HOST, "handle_sub_write: decode, log, apply, "
+                         "reply"),
+    "osd.ec.sub_reply": (HOST, "handle_sub_write_reply"),
+    "osd.ec.subop_timeout": (HOST, "mark: a write stopped waiting for "
+                             "sub-op acks at osd_ec_subop_timeout"),
+    "osd.advance_pgs": (HOST, "OSD._advance_pgs: one new map epoch"),
+    "heartbeat": (HOST, "one tick of OSD._heartbeat_loop: watchdogs, "
+                  "reports, pings, failure reports"),
+    "ec.prepare": (BATCHER, "encode_async: payload bytes -> k rows of "
+                   "words"),
+    "ec.stage": (BATCHER, "_encode_shard: lease the ladder's buffers and "
+                 "pack the items; words packed, padded words staged"),
+    "ec.dispatch": (BATCHER, "one ladder segment's blocking upload + "
+                    "kernel + readback; bytes_in, bytes_out"),
+    "ec.deliver": (BATCHER, "parity slices to the waiting ops; items"),
+    "ec.collect": (BATCHER, "encode_async: parity rows -> shard bytes"),
+    "store.apply": (HOST, "MemStore.queue_transactions; txns applied"),
+    "gc": (HOST, "one garbage collection, start to stop; generation"),
+    "op.retired": (HOST, "mark: an op left its tracker; stage waits in "
+                   "us from the stamps it carried (queue_us, "
+                   "ec_batch_us, subop_us, total_us), client=1 for the "
+                   "client's own op"),
+    # -- the bulk remap ----------------------------------------------------
+    "crush.build": (MAPPING, "OSDMapMapping._build, whole; pools"),
+    "crush.upload": (MAPPING, "weights and state vectors to the device; "
+                     "bytes"),
+    "crush.launch": (MAPPING, "the call into a compiled program (returns "
+                     "before the device ends); on the pool program lanes, "
+                     "and pallas_lanes whose descent was built in Pallas "
+                     "(0 on the call that first traces it)"),
+    "crush.wait": (MAPPING, "the first blocking read: the host waits, "
+                   "the device works"),
+    "crush.readback": (MAPPING, "up/acting tables device -> host; bytes"),
+    "crush.tables": (MAPPING, "numpy on the host: exceptions, primaries, "
+                     "PoolMapping"),
+}
+
+_FULL = {name: PREFIX + name for name in SPANS}
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """``with span("osd.handle_op"): ...`` around a synchronous
+    section."""
+    return TraceAnnotation(_FULL[name], **args)
+
+
+def mark(name: str, **args) -> None:
+    """A counted event: a span entered and left at once."""
+    with TraceAnnotation(_FULL[name], **args):
+        pass
+
+
+_gc_open: list[TraceAnnotation] = []
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        s = span("gc", generation=info["generation"])
+        s.__enter__()
+        _gc_open.append(s)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def watch_gc() -> None:
+    """Span every garbage collection from here on (idempotent; called
+    where a daemon starts, never at import)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)

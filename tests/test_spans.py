@@ -1,0 +1,279 @@
+"""Program spans on the profiler's clock (ceph_tpu/trace/span.py).
+
+The table SPANS and the emit sites are held to each other; no span body
+may suspend (one thread runs every coroutine, so a span across an
+``await`` would nest with other requests' spans); with no profiler a
+span costs nothing visible; under a profiler session on the CPU backend
+one EC write leaves every rados-path span, properly nested per thread.
+"""
+
+import ast
+import asyncio
+import glob
+import os
+
+import pytest
+
+from ceph_tpu.trace import span as spanmod
+from ceph_tpu.trace.span import PREFIX, SPANS, mark, span
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# what one acknowledged k2m1 write_full must leave; the rest of SPANS is
+# the bulk remap's, a timer's (heartbeat, advance_pgs, gc) or a fault's
+WRITE_PATH = (
+    "client.calc_target", "client.submit", "client.send_op",
+    "client.handle_reply", "msgr.encode", "msgr.write",
+    "msgr.read_decode", "msgr.dispatch", "osd.dequeue", "osd.handle_op",
+    "osd.ec.op", "osd.ec.submit", "osd.ec.sub_write", "osd.ec.sub_reply",
+    "ec.prepare", "ec.stage", "ec.dispatch", "ec.deliver", "ec.collect",
+    "store.apply", "op.retired")
+REMAP_PATH = ("crush.build", "crush.upload", "crush.launch", "crush.wait",
+              "crush.readback", "crush.tables")
+
+
+def _is_span_call(node, names=("span", "mark")) -> bool:
+    return (isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+            and node.func.id in names)
+
+
+def _sources():
+    for path in sorted(glob.glob(os.path.join(ROOT, "ceph_tpu", "**",
+                                              "*.py"), recursive=True)):
+        if path.endswith(os.path.join("trace", "span.py")):
+            continue
+        with open(path) as f:
+            src = f.read()
+        if "trace.span import" in src or ".span import" in src:
+            yield os.path.relpath(path, ROOT), ast.parse(src)
+
+
+def _emitted() -> dict:
+    """name -> [file:line] of every span(...)/mark(...) in ceph_tpu/;
+    a name that is not a string literal is an error of its own."""
+    out: dict = {}
+    for path, tree in _sources():
+        for node in ast.walk(tree):
+            if not _is_span_call(node):
+                continue
+            arg = node.args[0] if node.args else None
+            assert isinstance(arg, ast.Constant) and \
+                isinstance(arg.value, str), \
+                "%s:%d: span name must be a literal" % (path, node.lineno)
+            out.setdefault(arg.value, []).append(
+                "%s:%d" % (path, node.lineno))
+    # the gc hook lives beside the primitive
+    out.setdefault("gc", []).append("ceph_tpu/trace/span.py")
+    return out
+
+
+EMITTED = _emitted()
+
+
+def test_every_emit_site_is_registered():
+    unknown = {n: at for n, at in EMITTED.items() if n not in SPANS}
+    assert not unknown, unknown
+
+
+@pytest.mark.parametrize("name", sorted(SPANS))
+def test_registered_span_is_emitted(name):
+    layer, meaning = SPANS[name]
+    assert layer in (spanmod.CLIENT, spanmod.HOST, spanmod.BATCHER,
+                     spanmod.MAPPING) and meaning
+    assert not name.startswith("bench.")
+    assert name in EMITTED, "%s is in SPANS and emitted nowhere" % name
+
+
+def _suspends(body) -> list:
+    """Lines in `body` that give the loop away, nested defs left out."""
+    found, todo = [], list(body)
+    while todo:
+        node = todo.pop()
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                             ast.Lambda)):
+            continue
+        if isinstance(node, (ast.Await, ast.Yield, ast.YieldFrom,
+                             ast.AsyncWith, ast.AsyncFor)):
+            found.append(node.lineno)
+        todo.extend(ast.iter_child_nodes(node))
+    return found
+
+
+def test_no_span_body_suspends():
+    bad = []
+    for path, tree in _sources():
+        for node in ast.walk(tree):
+            if isinstance(node, (ast.With, ast.AsyncWith)) and any(
+                    _is_span_call(i.context_expr, ("span",))
+                    for i in node.items):
+                bad += ["%s:%d" % (path, ln)
+                        for ln in _suspends(node.body)]
+    assert not bad, "await/yield inside a span body: %s" % bad
+
+
+def test_lint_sees_a_suspending_body():
+    tree = ast.parse("async def f():\n"
+                     "    with span('x'):\n"
+                     "        await g()\n"
+                     "    with span('y'):\n"
+                     "        def h():\n"
+                     "            yield 1\n")
+    withs = [n for n in ast.walk(tree) if isinstance(n, ast.With)]
+    assert sorted(len(_suspends(w.body)) for w in withs) == [0, 1]
+
+
+def test_span_without_a_profiler_is_transparent():
+    def work():
+        with span("osd.handle_op"):
+            return 41 + 1
+    assert work() == 42
+    assert mark("client.resend", age_us=7) is None
+    with pytest.raises(KeyError):
+        span("no.such.span")
+    with pytest.raises(ZeroDivisionError):
+        with span("store.apply", txns=1):
+            1 / 0
+    spanmod.watch_gc()
+    spanmod.watch_gc()
+    import gc
+    assert gc.callbacks.count(spanmod._on_gc) == 1
+    gc.collect()
+
+
+# -- one traced write on the CPU backend -----------------------------------
+
+
+def _host_events(log_dir: str) -> dict:
+    """thread line -> [(name, start_ns, end_ns, stats)] of rados.* host
+    events in the newest trace under log_dir."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    lines: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for ln in plane.lines:
+            for ev in ln.events:
+                if ev.name.startswith(PREFIX):
+                    lines.setdefault(ln.name, []).append(
+                        (ev.name[len(PREFIX):], int(ev.start_ns),
+                         int(ev.start_ns + ev.duration_ns),
+                         dict(ev.stats)))
+    return lines
+
+
+@pytest.fixture(scope="module")
+def traced_write(tmp_path_factory):
+    import jax
+
+    from ceph_tpu.testing import LocalCluster
+    log_dir = str(tmp_path_factory.mktemp("spans"))
+    old = os.environ.get("CEPH_TPU_EC_OFFLOAD")
+    os.environ["CEPH_TPU_EC_OFFLOAD"] = "1"
+
+    async def main():
+        c = await LocalCluster(n_osds=3).start()
+        try:
+            pid = await c.create_pool("spans", pg_num=4,
+                                      pool_type="erasure")
+            await c.wait_health(pid)
+            io = c.client.io_ctx("spans")
+            await io.write_full("warm", b"\x5a" * 8192)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(log_dir, profiler_options=opts)
+            try:
+                await io.write_full("traced", b"\xa5" * 8192)
+                await asyncio.sleep(0.2)    # the sub-ops retire
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            await c.stop()
+
+    try:
+        asyncio.run(asyncio.wait_for(main(), 240))
+    finally:
+        if old is None:
+            del os.environ["CEPH_TPU_EC_OFFLOAD"]
+        else:
+            os.environ["CEPH_TPU_EC_OFFLOAD"] = old
+    return _host_events(log_dir)
+
+
+@pytest.fixture(scope="module")
+def traced_remap(tmp_path_factory):
+    import jax
+
+    from chip_smoke import build_osdmap
+    from ceph_tpu.parallel.mapping import OSDMapMapping
+    log_dir = str(tmp_path_factory.mktemp("remap"))
+    m = build_osdmap(40, 256)
+    OSDMapMapping(m)                    # traces the pool's programs
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        mp = OSDMapMapping(m)
+    finally:
+        jax.profiler.stop_trace()
+    assert mp.device_pools == 1 and mp.scalar_pools == 0
+    return _host_events(log_dir), mp.pools[1]
+
+
+@pytest.mark.parametrize("name", REMAP_PATH)
+def test_traced_remap_leaves_span(traced_remap, name):
+    names = {ev[0] for evs in traced_remap[0].values() for ev in evs}
+    assert name in names, sorted(names)
+
+
+def test_traced_remap_counts_lanes_and_bytes(traced_remap):
+    lines, pm = traced_remap
+    by = {}
+    for evs in lines.values():
+        for name, lo, hi, stats in evs:
+            by.setdefault(name, []).append((lo, hi, stats))
+    (lo, hi, build), = by["crush.build"]
+    assert build == {"pools": 1}
+    assert all(lo <= a and b <= hi for name, evs in by.items()
+               for a, b, _s in evs), "a remap span outside crush.build"
+    lanes = [s for _a, _b, s in by["crush.launch"] if "lanes" in s]
+    # 256 PGs is no multiple of the Pallas tile: the XLA descent, counted
+    assert [(s["lanes"], s["pallas_lanes"]) for s in lanes] == [(256, 0)]
+    (_a, _b, back), = by["crush.readback"]
+    assert back["bytes"] == pm.up.nbytes + pm.up_primary.nbytes
+
+
+@pytest.mark.parametrize("name", WRITE_PATH)
+def test_traced_write_leaves_span(traced_write, name):
+    assert any(ev[0] == name for evs in traced_write.values()
+               for ev in evs), sorted({ev[0] for evs in
+                                       traced_write.values()
+                                       for ev in evs})
+
+
+def test_traced_write_spans_nest_per_thread(traced_write):
+    for line, evs in traced_write.items():
+        stack = []
+        for name, lo, hi, _stats in sorted(evs,
+                                           key=lambda e: (e[1], -e[2])):
+            while stack and stack[-1][1] <= lo:
+                stack.pop()
+            assert not stack or hi <= stack[-1][1], \
+                "%s: %s [%d, %d] straddles %s" % (line, name, lo, hi,
+                                                  stack[-1])
+            stack.append((name, hi))
+
+
+def test_traced_write_retires_the_clients_op(traced_write):
+    retired = [ev[3] for evs in traced_write.values() for ev in evs
+               if ev[0] == "op.retired"]
+    mine = [s for s in retired if s.get("client") == 1]
+    assert len(mine) == 1 and mine[0]["total_us"] > 0, retired
+    staged = [s for s in retired if "subop_us" in s]
+    assert staged and all(
+        s["queue_us"] >= 0 and s["ec_batch_us"] > 0 and s["subop_us"] > 0
+        for s in staged), retired
+    sizes = [ev[3]["bytes"] for evs in traced_write.values() for ev in evs
+             if ev[0] == "msgr.write"]
+    assert sizes and max(sizes) > 4096     # a shard's frame
