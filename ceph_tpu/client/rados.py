@@ -44,11 +44,52 @@ class ObjectNotFound(RadosError):
         super().__init__(-2, oid)
 
 
+class RttEstimator:
+    """Round trip of the client's own ops as RFC 6298 keeps it for a
+    TCP sender: smoothed round trip, mean deviation, and the
+    retransmission timeout ``rto`` read from both, never under
+    ``floor``, which is also where it starts."""
+
+    __slots__ = ("floor", "ceiling", "srtt", "rttvar", "rto")
+
+    def __init__(self, floor: float, ceiling: float):
+        self.floor = floor
+        self.ceiling = ceiling  # of a back-off, not of a measurement
+        self.srtt: float | None = None
+        self.rttvar = 0.0
+        self.rto = floor
+
+    def sample(self, r: float) -> None:
+        """The round trip of an op that was sent once (Karn's rule is
+        the caller's to keep).  Ends a back-off (RFC 6298 5.7)."""
+        if self.srtt is None:
+            self.srtt, self.rttvar = r, r / 2
+        else:
+            self.rttvar += (abs(r - self.srtt) - self.rttvar) / 4
+            self.srtt += (r - self.srtt) / 8
+        self.rto = max(self.floor, self._measured())
+
+    def timed_out(self, waited: float) -> None:
+        """A deadline of ``waited`` seconds passed unanswered: the rto
+        doubles (RFC 6298 5.5) until the next sample.  A deadline armed
+        under an older, smaller rto says nothing of this one, so ops
+        that time out together back off once, not once each.  Never
+        past the larger of ``ceiling`` and twice what the samples say:
+        a cluster that answers nothing is still asked again at the
+        caller's own pace."""
+        if waited >= self.rto:
+            self.rto = min(2 * self.rto,
+                           max(self.ceiling, 2 * self._measured()))
+
+    def _measured(self) -> float:
+        return 0.0 if self.srtt is None else self.srtt + 4 * self.rttvar
+
+
 class _InFlight:
     __slots__ = ("tid", "pool", "oid", "ops", "future", "target",
                  "pgid", "acting", "snapc", "snapid", "backoff",
-                 "next_resend", "first_sent", "trace", "top",
-                 "tenant")
+                 "next_resend", "first_sent", "sends", "wait", "trace",
+                 "top", "tenant")
 
     def __init__(self, tid, pool, oid, ops, future, snapc=None,
                  snapid=None, tenant=None):
@@ -65,6 +106,8 @@ class _InFlight:
         self.backoff = None     # ExpBackoff ramp (set on first send)
         self.next_resend = 0.0  # loop.time() the resend tick may fire
         self.first_sent = 0.0
+        self.sends = 0          # passes through _send_op, any cause
+        self.wait = 0.0         # the armed deadline, seconds from a send
         self.trace = None       # cross-daemon span id (reqid_t role)
         self.top = None         # TrackedOp in the client's OpTracker
         self.tenant = tenant    # tenant key stamped on every send
@@ -73,9 +116,13 @@ class _InFlight:
 class RadosClient:
     """Cluster handle (librados::Rados / RadosClient)."""
 
-    # op resend ramp: base far above a healthy op round trip so only
-    # genuinely lost ops (dropped frames, dead primaries the map has
-    # not yet condemned) re-fire; cap bounds recovery latency
+    # floors of the op resend ramp, whose deadlines follow the round
+    # trip the client measures (_resend_ramp): only genuinely lost ops
+    # (dropped frames, dead primaries the map has not yet condemned)
+    # may re-fire, so no deadline is under the estimator's rto.  On a
+    # cluster that answers in milliseconds the ramp is these two alone:
+    # the first copy after base/2..base, waits doubling up to cap,
+    # which bounds the recovery latency there.
     OP_RESEND_BASE = 0.5
     OP_RESEND_CAP = 5.0
 
@@ -119,6 +166,11 @@ class RadosClient:
         # change, or on that OSD's session reset
         self._backoffs: dict[tuple, tuple] = {}
         self._resend_task = None
+        # round trip of data ops answered on their first send; the
+        # resend deadline is read from it (_resend_ramp)
+        self.rtt = RttEstimator(floor=self.OP_RESEND_BASE / 2,
+                                ceiling=self.OP_RESEND_CAP / 2)
+        self.op_resends = 0     # ops the ticker sent again
         # client-side op tracking (Objecter's slice of the op span):
         # every submit registers with trace id "<entity>:<tid>", which
         # rides the MOSDOp envelope into the OSD pipeline
@@ -260,6 +312,7 @@ class RadosClient:
                             (op.pool, op.pgid.ps) == key[:2] and \
                             (oid is None or op.oid == oid):
                         op.next_resend = now
+                        op.wait = 0.0   # for cause: no deadline passed
 
     # -- event bus (watch-events subscription) -----------------------------
 
@@ -422,15 +475,28 @@ class RadosClient:
                 self._inflight.pop(op.tid, None)
         return sorted(set(names))
 
+    def _resend_ramp(self) -> ExpBackoff:
+        """The ramp of an op sent now.  ExpBackoff draws each wait from
+        the upper half of its interval, so an interval of twice the
+        rto keeps the jitter and puts no deadline under the rto.  At
+        the rto's floor this is (OP_RESEND_BASE, OP_RESEND_CAP)."""
+        interval = 2 * self.rtt.rto
+        return ExpBackoff(base=interval,
+                          cap=max(self.OP_RESEND_CAP, interval),
+                          rng=self.rng)
+
     def _send_op(self, op: _InFlight) -> None:
         with span("client.send_op"):
             loop = asyncio.get_running_loop()
             if op.backoff is None:
-                op.backoff = ExpBackoff(base=self.OP_RESEND_BASE,
-                                        cap=self.OP_RESEND_CAP,
-                                        rng=self.rng)
                 op.first_sent = loop.time()
-            op.next_resend = loop.time() + op.backoff.next_delay()
+            if op.backoff is None or \
+                    op.backoff.peek() < 2 * self.rtt.rto:
+                # a first send, or the rto has outgrown the op's ramp
+                op.backoff = self._resend_ramp()
+            op.sends += 1
+            op.wait = op.backoff.next_delay()
+            op.next_resend = loop.time() + op.wait
             primary, pgid, acting = self._calc_target(op.pool, op.oid)
             op.target = primary
             op.pgid = pgid
@@ -456,9 +522,13 @@ class RadosClient:
         """Objecter op-retry ticker: any op still in flight past its
         jittered exponential-backoff deadline is re-sent (a dropped
         frame or a silently dead primary otherwise strands it until a
-        map change).  PGs under an active MOSDBackoff are skipped —
-        the OSD parked the op and will answer; resending would spam a
-        peering PG (exactly what backoff exists to stop).
+        map change).  The deadline is never under the rto of the round
+        trips this client has measured (_resend_ramp), and a deadline
+        that passes backs that rto off, so a loaded cluster is not
+        sent every op twice.  PGs under an active MOSDBackoff are
+        skipped — the OSD parked the op and will answer; resending
+        would spam a peering PG (exactly what backoff exists to
+        stop).
 
         The same ticker renews the map subscription
         (MonClient::renew_subs): publication is fire-and-forget, so
@@ -489,7 +559,10 @@ class RadosClient:
                 if op.next_resend > now or self._backed_off(op):
                     continue
                 mark("client.resend",
-                     age_us=int((now - op.first_sent) * 1e6))
+                     age_us=int((now - op.first_sent) * 1e6),
+                     rto_us=int(op.wait * 1e6))
+                self.op_resends += 1
+                self.rtt.timed_out(op.wait)
                 self._send_op(op)
 
     def _handle_reply(self, msg: MOSDOpReply) -> None:
@@ -497,6 +570,11 @@ class RadosClient:
             op = self._inflight.pop(msg.tid, None)
             if op is None or op.future.done():
                 return
+            if op.sends == 1:
+                # Karn's rule: the reply of an op sent twice cannot be
+                # matched to a send (pgls ops never pass _send_op)
+                self.rtt.sample(asyncio.get_running_loop().time()
+                                - op.first_sent)
             if op.top is not None:
                 op.top.finish("reply_r%d" % (msg.result or 0))
             if msg.result == 0:

@@ -44,7 +44,10 @@ SPANS: dict[str, tuple[str, str]] = {
     "client.handle_reply": (CLIENT, "_handle_reply: retire, resolve the "
                             "caller's future"),
     "client.resend": (CLIENT, "mark: the resend ticker sent an op again; "
-                      "age_us since its first send"),
+                      "age_us since its first send, rto_us the deadline "
+                      "that passed (at the constants' floor: a lost "
+                      "frame; above it: the estimator fell short; 0: a "
+                      "backoff's release)"),
     "msgr.encode": (HOST, "Connection.send: message -> wire bytes"),
     "msgr.write": (HOST, "frame header, crc and payload handed to the "
                    "transport; bytes of payload"),

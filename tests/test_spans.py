@@ -127,7 +127,7 @@ def test_span_without_a_profiler_is_transparent():
         with span("osd.handle_op"):
             return 41 + 1
     assert work() == 42
-    assert mark("client.resend", age_us=7) is None
+    assert mark("client.resend", age_us=7, rto_us=3) is None
     with pytest.raises(KeyError):
         span("no.such.span")
     with pytest.raises(ZeroDivisionError):
