@@ -360,24 +360,27 @@ class ErasureCode(ErasureCodeInterface):
                 "surviving chunks have differing sizes %s" % lengths)
         import numpy as np
         matrix, w = dm
-        k = self.get_data_chunk_count()
-        have = tuple(sorted(chunks))
-        erased = tuple(i for i in sorted(want_to_read)
-                       if i not in chunks)
-        rows, chosen = reconstruct_matrix(k, w, matrix, erased, have)
-        arr = np.stack([
-            np.frombuffer(chunks[c], dtype=self._word_dtype(w))
-            for c in chosen])
+        with span("ec.decode_prepare"):
+            k = self.get_data_chunk_count()
+            have = tuple(sorted(chunks))
+            erased = tuple(i for i in sorted(want_to_read)
+                           if i not in chunks)
+            rows, chosen = reconstruct_matrix(k, w, matrix, erased,
+                                              have)
+            arr = np.stack([
+                np.frombuffer(chunks[c], dtype=self._word_dtype(w))
+                for c in chosen])
         words = await DeviceBatcher.get().encode(
             rows, w, arr, klass=klass or K_CLIENT_EC,
             on_ticket=on_ticket, chip=chip)
-        out = {}
-        for j, e in enumerate(erased):
-            out[e] = words[j].tobytes()
-        for i in want_to_read:
-            if i in chunks:
-                out[i] = bytes(chunks[i])
-        return out
+        with span("ec.decode_collect"):
+            out = {}
+            for j, e in enumerate(erased):
+                out[e] = words[j].tobytes()
+            for i in want_to_read:
+                if i in chunks:
+                    out[i] = bytes(chunks[i])
+            return out
 
     async def decode_concat_async(self, chunks: Mapping[int, bytes],
                                   klass: str | None = None,
@@ -388,8 +391,9 @@ class ErasureCode(ErasureCodeInterface):
         decoded = await self.decode_async(want, chunks, klass=klass,
                                           on_ticket=on_ticket,
                                           chip=chip)
-        return b"".join(decoded[self.chunk_index(i)]
-                        for i in range(k))
+        with span("ec.decode_collect"):
+            return b"".join(decoded[self.chunk_index(i)]
+                            for i in range(k))
 
     # Locality-aware codes (LRC, SHEC) can repair from FEWER than k
     # chunks (a local group / shingle window); they clear this flag so

@@ -13,7 +13,8 @@ Implemented service logic (OSDMonitor):
               (OSDMonitor::check_failure, mon/OSDMonitor.cc:3171),
               then the osd is marked down in a new epoch.
 * auto-out  — down for mon_osd_down_out_interval -> weight 0
-              (OSDMonitor::tick, "will mark out" flow).
+              (OSDMonitor::tick, "will mark out" flow).  `osd set noout`
+              holds it off for the length of a maintenance.
 * pools     — create/rm/set replicated and erasure pools; erasure
               profiles live in the map (OSDMap::erasure_code_profiles).
 * commands  — MMonCommand dict protocol ("osd pool create", "status",
@@ -37,7 +38,8 @@ from ..msg.messages import (MMonCommand, MMonCommandAck, MMonElection,
                             MMonGetMap, MMonPaxos, MMonSubscribe,
                             MOSDAlive, MOSDBoot, MOSDFailure,
                             MOSDMapMsg, MOSDOp)
-from ..osd.osdmap import (CEPH_OSD_OUT, OSD_EXISTS, OSD_UP,
+from ..osd.osdmap import (CEPH_OSD_OUT, CEPH_OSDMAP_NOOUT, CLUSTER_FLAGS,
+                          OSD_EXISTS, OSD_UP,
                           POOL_TYPE_ERASURE, POOL_TYPE_REPLICATED,
                           Incremental, OSDMap, PGPool)
 from ..store.kv import KeyValueDB, MemKV
@@ -1186,8 +1188,9 @@ class Monitor:
             self._tick()
 
     def _tick(self) -> None:
-        """Auto-out down osds after the down-out interval; decay +
-        persist connectivity scores and probe peer liveness."""
+        """Auto-out down osds after the down-out interval, unless the
+        operator set noout; decay + persist connectivity scores and
+        probe peer liveness."""
         if self.elector is not None:
             from .elector import CONNECTIVITY
 
@@ -1212,7 +1215,8 @@ class Monitor:
             if self.osdmap.is_up(osd):
                 del self.down_pending_out[osd]
                 continue
-            if now - down_at >= interval and self.osdmap.is_in(osd):
+            if now - down_at >= interval and self.osdmap.is_in(osd) \
+                    and not self.osdmap.test_flag(CEPH_OSDMAP_NOOUT):
                 self._pending().new_weight[osd] = CEPH_OSD_OUT
                 del self.down_pending_out[osd]
                 changed = True
@@ -1365,6 +1369,8 @@ class Monitor:
                 self.down_pending_out[osd] = time.monotonic()
                 self._propose_pending()
             return {}
+        if prefix in ("osd set", "osd unset"):
+            return self._cmd_osd_flag(prefix == "osd set", cmd)
         if prefix == "mgr register":
             # MgrMonitor's role: record the active manager's address
             # in the map so daemons know where to send MMgrReports
@@ -1405,8 +1411,29 @@ class Monitor:
         if prefix == "osd pool stats":
             return self._cmd_pool_stats(cmd)
         if prefix == "osd dump":
-            return self.osdmap.to_dict()
+            return {**self.osdmap.to_dict(), "flags_set": sorted(
+                name for name, bit in CLUSTER_FLAGS.items()
+                if self.osdmap.test_flag(bit))}
         raise ValueError("unknown command %r" % prefix)
+
+    def _cmd_osd_flag(self, on: bool, cmd: dict) -> dict:
+        """`osd set <key>` / `osd unset <key>` (OSDMonitor's flag
+        commands).  noout is the one flag this cluster honours; any
+        other key is refused.  Clearing it starts every down osd's
+        down-out clock over: the interval counts from the end of the
+        maintenance, not from the stop."""
+        bit = CLUSTER_FLAGS.get(cmd.get("key"))
+        if bit is None:
+            raise ValueError("unknown flag %r" % (cmd.get("key"),))
+        inc = self._pending()
+        flags = inc.new_flags if inc.new_flags >= 0 else self.osdmap.flags
+        inc.new_flags = flags | bit if on else flags & ~bit
+        if not on:
+            now = time.monotonic()
+            for osd in self.down_pending_out:
+                self.down_pending_out[osd] = now
+        self._propose_pending()
+        return {}
 
     # -- cluster stats surfaces (PGMap digest consumers) -------------------
 
@@ -1929,7 +1956,7 @@ class Monitor:
 _AUDIT_PREFIXES = frozenset((
     "osd pool create", "osd pool rm", "osd pool set",
     "osd erasure-code-profile set", "osd out", "osd in", "osd down",
-    "osd pool mksnap", "osd pool rmsnap", "osd snap create",
+    "osd set", "osd unset", "osd pool mksnap", "osd pool rmsnap", "osd snap create",
     "osd snap rm", "config set", "config rm", "crash archive",
     "crash archive-all", "crash rm", "mgr register",
 ))

@@ -130,6 +130,10 @@ class ECPGBackend:
         # telemetry: shard bytes fetched over the wire (RMW
         # amplification visibility; tests pin partial-write traffic)
         self.sub_read_bytes = 0
+        # client reads that had to rebuild a wanted position from the
+        # survivors (a degraded read), and the bytes they returned
+        self.reconstructed_reads = 0
+        self.reconstructed_read_bytes = 0
         # repair-traffic accounting (per codec plugin): survivor
         # bytes read through minimum_to_decode's minimal shard sets
         # vs rebuilt bytes pushed — shipped in MMgrReport osd_stats
@@ -344,7 +348,8 @@ class ECPGBackend:
                 if name in ("read", "stat"):
                     if not fetched:
                         data, _v, rattrs = await self.read_object_attrs(
-                            pg, msg.oid, snap=read_snap)
+                            pg, msg.oid, snap=read_snap,
+                            top=getattr(msg, "_top", None))
                         if (data is not None and read_snap is None
                                 and (rattrs or {}).get(
                                     snapmod.WHITEOUT_ATTR) == b"1"):
@@ -1099,72 +1104,77 @@ class ECPGBackend:
         return data, ver
 
     async def read_object_attrs(self, pg: PG, oid: str,
-                                snap: int = None):
+                                snap: int = None, top=None):
         """Reconstructing whole-object read; returns
         (data, version, attrs) or (None, None, None).  Fetches the
         minimum member set first and widens on shortfall; only shards
         stamped with the newest observed version are mixed (ec_ver);
         attrs come from any shard of the winning version (user xattrs
-        are written identically to every shard)."""
-        pool = self.osd.osdmap.pools[pg.pool_id]
-        codec = self.codec(pool)
-        k = codec.get_data_chunk_count()
-        ho = (hobject_t(oid) if snap is None
-              else hobject_t(oid, snap=snap))
-        members = []
-        for osd_id in pg.acting:
-            if osd_id != ITEM_NONE and osd_id >= 0 \
-                    and osd_id not in members \
-                    and (osd_id == self.osd.whoami
-                         or self.osd.osdmap.is_up(osd_id)):
-                # map-down members cannot answer: querying them only
-                # burns the sub-read timeout per object — degraded
-                # reads and recovery go straight to live shards
-                members.append(osd_id)
-        # per-version shard pools: {ver: {j: (bytes, size)}}
-        by_ver: dict[tuple, dict[int, tuple]] = {}
-        attrs_by_ver: dict[tuple, dict] = {}
-        local = self._local_shard(pg, ho) \
-            if self.osd.whoami in members else None
-        if local is not None:
-            j, buf, size, ver, lattrs = local
-            by_ver.setdefault(ver, {})[j] = (buf, size)
-            attrs_by_ver.setdefault(ver, dict(lattrs))
-        remote = [o for o in members if o != self.osd.whoami]
-        # ask the minimum first — planned through the codec's
-        # minimum_to_decode so locality-aware codecs (LRC local
-        # groups, SHEC shingle windows) fetch only their minimal
-        # shard set, not the first k members; shortfall still widens
-        # to everyone.  Falls back to the k-members heuristic when
-        # the plan fails (too few live members: widening handles it).
-        mapping = codec.get_chunk_mapping()
-        want_pos = ({mapping[i] for i in range(k)} if mapping
-                    else set(range(k)))
-        pos_member = {pos: osd_id
-                      for pos, osd_id in enumerate(pg.acting)
-                      if osd_id in members}
-        local_pos = next((p for p, o in pos_member.items()
-                          if o == self.osd.whoami), None)
-        minimal_pos = None
-        try:
-            minimal_pos = set(codec.minimum_to_decode(
-                want_pos, set(pos_member)))
-        except Exception:
-            pass
-        if minimal_pos is not None:
-            minimal_members = {pos_member[p] for p in minimal_pos}
-            first = [o for o in remote if o in minimal_members]
-        else:
-            have = 1 if local is not None else 0
-            first = remote[:max(0, k - have)]
-        rest = [o for o in remote if o not in first]
-        self.last_read_plan = {
-            "minimal": minimal_pos,
-            "local": local_pos,
-            "queried": {p for p, o in pos_member.items()
-                        if o in first},
-            "widened": False,
-        }
+        are written identically to every shard).  `top` is the client
+        op this read serves: it carries the read's stage stamps, and
+        only such a read counts as a reconstructed read."""
+        with span("osd.ec.read"):
+            pool = self.osd.osdmap.pools[pg.pool_id]
+            codec = self.codec(pool)
+            k = codec.get_data_chunk_count()
+            ho = (hobject_t(oid) if snap is None
+                  else hobject_t(oid, snap=snap))
+            members = []
+            for osd_id in pg.acting:
+                if osd_id != ITEM_NONE and osd_id >= 0 \
+                        and osd_id not in members \
+                        and (osd_id == self.osd.whoami
+                             or self.osd.osdmap.is_up(osd_id)):
+                    # map-down members cannot answer: querying them
+                    # only burns the sub-read timeout per object —
+                    # degraded reads and recovery go straight to live
+                    # shards
+                    members.append(osd_id)
+            # per-version shard pools: {ver: {j: (bytes, size)}}
+            by_ver: dict[tuple, dict[int, tuple]] = {}
+            attrs_by_ver: dict[tuple, dict] = {}
+            local = self._local_shard(pg, ho) \
+                if self.osd.whoami in members else None
+            if local is not None:
+                j, buf, size, ver, lattrs = local
+                by_ver.setdefault(ver, {})[j] = (buf, size)
+                attrs_by_ver.setdefault(ver, dict(lattrs))
+            remote = [o for o in members if o != self.osd.whoami]
+            # ask the minimum first — planned through the codec's
+            # minimum_to_decode so locality-aware codecs (LRC local
+            # groups, SHEC shingle windows) fetch only their minimal
+            # shard set, not the first k members; shortfall still
+            # widens to everyone.  Falls back to the k-members
+            # heuristic when the plan fails (too few live members:
+            # widening handles it).
+            mapping = codec.get_chunk_mapping()
+            want_pos = ({mapping[i] for i in range(k)} if mapping
+                        else set(range(k)))
+            pos_member = {pos: osd_id
+                          for pos, osd_id in enumerate(pg.acting)
+                          if osd_id in members}
+            local_pos = next((p for p, o in pos_member.items()
+                              if o == self.osd.whoami), None)
+            minimal_pos = None
+            try:
+                minimal_pos = set(codec.minimum_to_decode(
+                    want_pos, set(pos_member)))
+            except Exception:
+                pass
+            if minimal_pos is not None:
+                minimal_members = {pos_member[p] for p in minimal_pos}
+                first = [o for o in remote if o in minimal_members]
+            else:
+                have = 1 if local is not None else 0
+                first = remote[:max(0, k - have)]
+            rest = [o for o in remote if o not in first]
+            self.last_read_plan = {
+                "minimal": minimal_pos,
+                "local": local_pos,
+                "queried": {p for p, o in pos_member.items()
+                            if o in first},
+                "widened": False,
+            }
         for batch in ([first, rest] if first else [rest]):
             if not batch:
                 continue
@@ -1172,28 +1182,39 @@ class ECPGBackend:
                 self.last_read_plan["widened"] = True
                 self.last_read_plan["queried"] |= {
                     p for p, o in pos_member.items() if o in rest}
-            for sender, rows in \
-                    (await self._sub_read(pg, oid, batch,
-                                          snap=snap)).items():
-                for (j, buf, sz, verw, rattrs) in rows:
-                    ver = tuple(verw)
-                    by_ver.setdefault(ver, {}).setdefault(
-                        j, (buf, sz))
-                    if rattrs:
-                        attrs_by_ver.setdefault(ver, dict(rattrs))
-            best = self._best_version(codec, k, by_ver)
-            if best is not None:
-                ver, use_pos = best
-                chunks = {j: b for j, (b, _s) in
-                          by_ver[ver].items() if j in use_pos}
-                size = next(iter(by_ver[ver].values()))[1]
-                try:
-                    data = await codec.decode_concat_async(
-                        chunks, chip=self._chip())
-                except (IOError, OSError):
-                    continue  # widen to the remaining members
-                return (data[:size], ver,
-                        attrs_by_ver.get(ver, {}))
+            got = await self._sub_read(pg, oid, batch, snap=snap,
+                                       top=top)
+            with span("osd.ec.read"):
+                for sender, rows in got.items():
+                    for (j, buf, sz, verw, rattrs) in rows:
+                        ver = tuple(verw)
+                        by_ver.setdefault(ver, {}).setdefault(
+                            j, (buf, sz))
+                        if rattrs:
+                            attrs_by_ver.setdefault(ver, dict(rattrs))
+                best = self._best_version(codec, k, by_ver)
+                if best is not None:
+                    ver, use_pos = best
+                    chunks = {j: b for j, (b, _s) in
+                              by_ver[ver].items() if j in use_pos}
+                    size = next(iter(by_ver[ver].values()))[1]
+                    erased = len(want_pos - set(chunks))
+            if best is None:
+                continue
+            rebuilt = top is not None and erased > 0
+            if rebuilt:
+                top.mark_event("ec_decode_start")
+            try:
+                data = await codec.decode_concat_async(
+                    chunks, chip=self._chip())
+            except (IOError, OSError):
+                continue  # widen to the remaining members
+            if rebuilt:
+                top.mark_event("ec_decoded")
+                self.reconstructed_reads += 1
+                self.reconstructed_read_bytes += size
+                mark("osd.ec.reconstruct", erased=erased, bytes=size)
+            return data[:size], ver, attrs_by_ver.get(ver, {})
         return None, None, None
 
     def _best_version(self, codec, k, by_ver):
@@ -1246,29 +1267,35 @@ class ECPGBackend:
 
     async def _sub_read(self, pg: PG, oid: str,
                         members: list, snap: int = None,
-                        off: int = 0, length: int = -1) -> dict:
+                        off: int = 0, length: int = -1,
+                        top=None) -> dict:
         """One round of MOSDECSubOpRead to `members`; returns
         {sender: [(j, bytes, size, ver), ...]}.  snap targets a clone
         shard object; off/length select a shard byte range (-1 = the
         whole shard) — the ranged form is what makes partial-overwrite
-        RMW traffic proportional to the touched extent."""
+        RMW traffic proportional to the touched extent.  `top`, a
+        client read's tracked op, is stamped when the requests have
+        left and when the last reply lands (or the wait gives up)."""
         self._tid += 1
         tid = self._tid
         ev = asyncio.Event()
         st = {"waiting": set(members), "event": ev, "buffers": {},
-              "errors": {}}
+              "errors": {}, "top": top}
         self._reads[tid] = st
         for osd_id in members:
             self.osd._send_osd(osd_id, MOSDECSubOpRead(
                 pool=pg.pool_id, ps=pg.ps, shard=-1, tid=tid,
                 reads=[[oid, length, snap, off]],
                 epoch=self.osd.osdmap.epoch))
+        if top is not None:
+            top.mark_event("ec_sub_read_sent")
         try:
             await asyncio.wait_for(
                 ev.wait(),
                 float(self.osd.ctx.conf["osd_ec_subop_timeout"]))
         except asyncio.TimeoutError:
-            pass
+            if top is not None:
+                top.mark_event("ec_sub_read_timeout")
         self._reads.pop(tid, None)
         return st["buffers"]
 
@@ -1296,51 +1323,58 @@ class ECPGBackend:
         stamp and attrs."""
         from .osdmap import pg_t
 
-        pg = self.osd.pgs.get(pg_t(msg.pool, msg.ps))
-        buffers = []
-        errors = []
-        for row in msg.reads:
-            oid = row[0]
-            snap = row[2] if len(row) > 2 else None
-            off = row[3] if len(row) > 3 else 0
-            length = row[1] if len(row) > 1 else -1
-            if pg is None:
-                errors.append([oid, -2])
-                continue
-            ho = (hobject_t(oid) if snap is None
-                  else hobject_t(oid, snap=snap))
-            local = self._local_shard(pg, ho)
-            if local is None:
-                errors.append([oid, -2])
-                continue
-            j, buf, size, ver, attrs = local
-            if length is not None and length >= 0:
-                buf = buf[off:off + length]
-            wire_attrs = {k: v for k, v in attrs.items()
-                          if isinstance(k, str)}
-            buffers.append([oid, j, buf, size, list(ver), wire_attrs])
-        conn.send(MOSDECSubOpReadReply(
-            pool=msg.pool, ps=msg.ps, shard=msg.shard, tid=msg.tid,
-            buffers=buffers, errors=errors, epoch=msg.epoch))
+        with span("osd.ec.sub_read") as sp:
+            pg = self.osd.pgs.get(pg_t(msg.pool, msg.ps))
+            buffers = []
+            errors = []
+            for row in msg.reads:
+                oid = row[0]
+                snap = row[2] if len(row) > 2 else None
+                off = row[3] if len(row) > 3 else 0
+                length = row[1] if len(row) > 1 else -1
+                if pg is None:
+                    errors.append([oid, -2])
+                    continue
+                ho = (hobject_t(oid) if snap is None
+                      else hobject_t(oid, snap=snap))
+                local = self._local_shard(pg, ho)
+                if local is None:
+                    errors.append([oid, -2])
+                    continue
+                j, buf, size, ver, attrs = local
+                if length is not None and length >= 0:
+                    buf = buf[off:off + length]
+                wire_attrs = {k: v for k, v in attrs.items()
+                              if isinstance(k, str)}
+                buffers.append([oid, j, buf, size, list(ver),
+                                wire_attrs])
+            conn.send(MOSDECSubOpReadReply(
+                pool=msg.pool, ps=msg.ps, shard=msg.shard, tid=msg.tid,
+                buffers=buffers, errors=errors, epoch=msg.epoch))
+            sp.set_metadata(bytes=sum(len(b[2]) for b in buffers))
 
     def handle_sub_read_reply(self, msg: MOSDECSubOpReadReply) -> None:
-        st = self._reads.get(msg.tid)
-        if st is None:
-            return
-        sender = int(msg.src.split(".")[1])
-        rows = []
-        for row in msg.buffers:
-            oid, j, buf, sz, ver = row[0], row[1], row[2], row[3], \
-                row[4]
-            attrs = row[5] if len(row) > 5 else {}
-            self.sub_read_bytes += len(buf)
-            rows.append((j, buf, sz, ver, attrs))
-        st["buffers"][sender] = rows
-        for oid, err in msg.errors:
-            st["errors"][sender] = err
-        st["waiting"].discard(sender)
-        if not st["waiting"]:
-            st["event"].set()
+        with span("osd.ec.sub_read_reply",
+                  bytes=sum(len(row[2]) for row in msg.buffers)):
+            st = self._reads.get(msg.tid)
+            if st is None:
+                return
+            sender = int(msg.src.split(".")[1])
+            rows = []
+            for row in msg.buffers:
+                oid, j, buf, sz, ver = row[0], row[1], row[2], \
+                    row[3], row[4]
+                attrs = row[5] if len(row) > 5 else {}
+                self.sub_read_bytes += len(buf)
+                rows.append((j, buf, sz, ver, attrs))
+            st["buffers"][sender] = rows
+            for oid, err in msg.errors:
+                st["errors"][sender] = err
+            st["waiting"].discard(sender)
+            if not st["waiting"]:
+                if st["top"] is not None:
+                    st["top"].mark_event("ec_sub_read_acked")
+                st["event"].set()
 
     # -- recovery ----------------------------------------------------------
 

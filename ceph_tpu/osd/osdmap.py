@@ -39,6 +39,13 @@ POOL_TYPE_ERASURE = 3
 
 FLAG_HASHPSPOOL = 1
 
+# cluster-wide flags (OSDMap::flags, `ceph osd set <key>`); the one an
+# operator sets here is noout: a down osd stays in, CRUSH keeps its
+# position, no backfill starts (doc/rados/troubleshooting/
+# troubleshooting-osd.rst, "Stopping w/out Rebalancing")
+CEPH_OSDMAP_NOOUT = 1 << 3
+CLUSTER_FLAGS = {"noout": CEPH_OSDMAP_NOOUT}
+
 
 def calc_bits_of(t: int) -> int:
     b = 0
@@ -207,6 +214,7 @@ class OSDMap:
         self.epoch = 0
         self.fsid = ""
         self.max_osd = 0
+        self.flags = 0                       # CLUSTER_FLAGS bits
         self.osd_state: list[int] = []
         self.osd_weight: list[int] = []      # 16.16 in/out weight
         self.osd_primary_affinity: list[int] | None = None
@@ -253,6 +261,9 @@ class OSDMap:
 
     def is_out(self, osd: int) -> bool:
         return not self.is_in(osd)
+
+    def test_flag(self, bit: int) -> bool:
+        return bool(self.flags & bit)
 
     def get_weight(self, osd: int) -> int:
         return self.osd_weight[osd]
@@ -449,6 +460,8 @@ class OSDMap:
         self.epoch = inc.epoch
         if inc.new_max_osd >= 0:
             self.set_max_osd(inc.new_max_osd)
+        if inc.new_flags >= 0:
+            self.flags = inc.new_flags
         if inc.new_mgr_addr is not None:
             self.mgr_addr = inc.new_mgr_addr
         for pid, pool in inc.new_pools.items():
@@ -517,6 +530,7 @@ class OSDMap:
             "epoch": self.epoch,
             "fsid": self.fsid,
             "max_osd": self.max_osd,
+            "flags": self.flags,
             "osd_state": list(self.osd_state),
             "osd_weight": list(self.osd_weight),
             "osd_primary_affinity": (
@@ -548,6 +562,7 @@ class OSDMap:
         m.epoch = d["epoch"]
         m.fsid = d["fsid"]
         m.max_osd = d["max_osd"]
+        m.flags = d.get("flags", 0)
         m.osd_state = list(d["osd_state"])
         m.osd_weight = list(d["osd_weight"])
         m.osd_primary_affinity = (
@@ -579,7 +594,8 @@ class OSDMap:
     #   2 — +osd_up_thru, +pool compression fields (additive: compat
     #       stays 1, old decoders read their known keys)
     #   3 — +pool dedup_chunk_pool (additive, compat stays 1)
-    STRUCT_V = 3
+    #   4 — +flags, the cluster-wide flags word (additive)
+    STRUCT_V = 4
     STRUCT_COMPAT = 1
 
     def encode(self) -> bytes:
@@ -637,6 +653,7 @@ class Incremental:
 
     epoch: int
     new_max_osd: int = -1
+    new_flags: int = -1         # the whole flags word; -1: unchanged
     new_mgr_addr: str | None = None
     new_pools: dict[int, PGPool] = field(default_factory=dict)
     old_pools: list[int] = field(default_factory=list)
@@ -661,6 +678,7 @@ class Incremental:
         return {
             "epoch": self.epoch,
             "new_max_osd": self.new_max_osd,
+            "new_flags": self.new_flags,
             "new_mgr_addr": self.new_mgr_addr,
             "new_pools": {str(k): p.to_dict()
                           for k, p in self.new_pools.items()},
@@ -695,6 +713,7 @@ class Incremental:
     def from_dict(cls, d: dict) -> "Incremental":
         inc = cls(epoch=d["epoch"])
         inc.new_max_osd = d["new_max_osd"]
+        inc.new_flags = d.get("new_flags", -1)
         inc.new_mgr_addr = d.get("new_mgr_addr")
         inc.new_pools = {int(k): PGPool.from_dict(p)
                          for k, p in d["new_pools"].items()}
@@ -725,7 +744,7 @@ class Incremental:
             d.get("old_erasure_code_profiles", []))
         return inc
 
-    STRUCT_V = 2        # 2: +new_up_thru (additive)
+    STRUCT_V = 3        # 2: +new_up_thru; 3: +new_flags (both additive)
     STRUCT_COMPAT = 1
 
     def encode(self) -> bytes:

@@ -32,9 +32,13 @@ from .span import mark
 _STAGE_SLOT = {"queued": 0, "reached_pg": 1,
                "ec_encode_start": 2, "ec_encoded": 3,
                "ec_sub_write_sent": 4, "ec_sub_write_acked": 5,
-               "ec_sub_write_timeout": 5}
+               "ec_sub_write_timeout": 5,
+               "ec_sub_read_sent": 6, "ec_sub_read_acked": 7,
+               "ec_sub_read_timeout": 7,
+               "ec_decode_start": 8, "ec_decoded": 9}
 _STAGE_WAITS = (("queue_us", 0, 1), ("ec_batch_us", 2, 3),
-                ("subop_us", 4, 5))
+                ("subop_us", 4, 5), ("sub_read_us", 6, 7),
+                ("decode_us", 8, 9))
 
 
 class TrackedOp:
@@ -196,7 +200,7 @@ class OpTracker:
     def _mark_retired(self, op: TrackedOp) -> None:
         """The op's stage waits onto the profiler's clock, every op,
         unsampled: one pass over the stamps it already carries."""
-        at = [None] * 6
+        at = [None] * (2 * len(_STAGE_WAITS))
         for t, event in op.events:
             slot = _STAGE_SLOT.get(event)
             if slot is not None and (slot & 1 or at[slot] is None):

@@ -56,6 +56,8 @@ OP_STAGES = frozenset({
     "device_dispatched", "device_stream_retired",
     "ec_sub_write_sent", "ec_sub_write_acked",
     "ec_sub_write_timeout", "ec_write_done", "ec_read_done",
+    "ec_sub_read_sent", "ec_sub_read_acked", "ec_sub_read_timeout",
+    "ec_decode_start", "ec_decoded",
     "ec_shard_applied", "ec_delta_rmw", "ec_delta_done",
     "ec_error_reply",
 })

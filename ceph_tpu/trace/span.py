@@ -68,6 +68,16 @@ SPANS: dict[str, tuple[str, str]] = {
     "osd.ec.sub_reply": (HOST, "handle_sub_write_reply"),
     "osd.ec.subop_timeout": (HOST, "mark: a write stopped waiting for "
                              "sub-op acks at osd_ec_subop_timeout"),
+    "osd.ec.read": (HOST, "read_object_attrs: the primary's read plan "
+                    "(members, minimum_to_decode) and, after each "
+                    "round of sub-reads, the version vote"),
+    "osd.ec.sub_read": (HOST, "handle_sub_read: the local shard off "
+                        "the store, the reply queued; bytes of shard"),
+    "osd.ec.sub_read_reply": (HOST, "handle_sub_read_reply; bytes of "
+                              "shard received"),
+    "osd.ec.reconstruct": (HOST, "mark: a client read rebuilt wanted "
+                           "positions from the survivors; erased "
+                           "positions, bytes of the object"),
     "osd.advance_pgs": (HOST, "OSD._advance_pgs: one new map epoch"),
     "heartbeat": (HOST, "one tick of OSD._heartbeat_loop: watchdogs, "
                   "reports, pings, failure reports"),
@@ -79,12 +89,19 @@ SPANS: dict[str, tuple[str, str]] = {
                     "kernel + readback; bytes_in, bytes_out"),
     "ec.deliver": (BATCHER, "parity slices to the waiting ops; items"),
     "ec.collect": (BATCHER, "encode_async: parity rows -> shard bytes"),
+    "ec.decode_prepare": (BATCHER, "decode_async: the reconstruction "
+                          "matrix (cached), k survivors stacked into "
+                          "rows of words"),
+    "ec.decode_collect": (BATCHER, "decode_async: rebuilt rows -> chunk "
+                          "bytes; decode_concat_async: the k data "
+                          "chunks joined into the object"),
     "store.apply": (HOST, "MemStore.queue_transactions; txns applied"),
     "gc": (HOST, "one garbage collection, start to stop; generation"),
     "op.retired": (HOST, "mark: an op left its tracker; stage waits in "
                    "us from the stamps it carried (queue_us, "
-                   "ec_batch_us, subop_us, total_us), client=1 for the "
-                   "client's own op"),
+                   "ec_batch_us, subop_us; a read's sub_read_us, "
+                   "decode_us; total_us), client=1 for the client's "
+                   "own op"),
     # -- the bulk remap ----------------------------------------------------
     "crush.build": (MAPPING, "OSDMapMapping._build, whole; pools"),
     "crush.upload": (MAPPING, "weights and state vectors to the device; "

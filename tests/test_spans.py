@@ -4,7 +4,9 @@ The table SPANS and the emit sites are held to each other; no span body
 may suspend (one thread runs every coroutine, so a span across an
 ``await`` would nest with other requests' spans); with no profiler a
 span costs nothing visible; under a profiler session on the CPU backend
-one EC write leaves every rados-path span, properly nested per thread.
+one EC write leaves every rados-path span, properly nested per thread,
+and a read with a data holder stopped under noout leaves the read
+path's.
 """
 
 import ast
@@ -28,6 +30,11 @@ WRITE_PATH = (
     "osd.ec.op", "osd.ec.submit", "osd.ec.sub_write", "osd.ec.sub_reply",
     "ec.prepare", "ec.stage", "ec.dispatch", "ec.deliver", "ec.collect",
     "store.apply", "op.retired")
+# what a k2m1 read leaves with the OSD of a data shard stopped under
+# noout, beside the client's, the messenger's and the op queue's spans
+READ_PATH = ("osd.ec.read", "osd.ec.sub_read", "osd.ec.sub_read_reply",
+             "osd.ec.reconstruct", "ec.decode_prepare", "ec.decode_collect",
+             "ec.dispatch", "op.retired")
 REMAP_PATH = ("crush.build", "crush.upload", "crush.launch", "crush.wait",
               "crush.readback", "crush.tables")
 
@@ -202,6 +209,59 @@ def traced_write(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
+def traced_degraded_reads(tmp_path_factory):
+    """(events, reads): four 8 KiB objects read with the OSD that holds
+    data position 0 of each stopped under noout."""
+    import jax
+
+    from ceph_tpu.testing import LocalCluster
+    log_dir = str(tmp_path_factory.mktemp("reads"))
+    names = []
+
+    async def main():
+        c = await LocalCluster(n_osds=3).start()
+        try:
+            pid = await c.create_pool("reads", pg_num=4,
+                                      pool_type="erasure")
+            await c.wait_health(pid)
+            io = c.client.io_ctx("reads")
+            om = c.client.osdmap
+            await c.client.mon_command("osd set", key="noout")
+            victim = 1
+
+            def first_data_osd(name):
+                pg = om.pools[pid].raw_pg_to_pg(
+                    om.object_locator_to_pg(name, pid))
+                return om.pg_to_up_acting_osds(pg)[2][0]
+
+            i = 0
+            while len(names) < 4:
+                i += 1
+                if first_data_osd("obj%d" % i) == victim:
+                    names.append("obj%d" % i)
+                    await io.write_full(names[-1], b"\x5a" * 8192)
+            await c.kill_osd(victim)
+            await c.wait_osd_down(victim)
+            assert await io.read(names[0]) == b"\x5a" * 8192   # warm
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(log_dir, profiler_options=opts)
+            try:
+                for name in names:
+                    assert await io.read(name) == b"\x5a" * 8192
+                await asyncio.sleep(0.2)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            await c.stop()
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("CEPH_TPU_EC_OFFLOAD", "1")
+        asyncio.run(asyncio.wait_for(main(), 240))
+    return _host_events(log_dir), len(names)
+
+
+@pytest.fixture(scope="module")
 def traced_remap(tmp_path_factory):
     import jax
 
@@ -277,3 +337,48 @@ def test_traced_write_retires_the_clients_op(traced_write):
     sizes = [ev[3]["bytes"] for evs in traced_write.values() for ev in evs
              if ev[0] == "msgr.write"]
     assert sizes and max(sizes) > 4096     # a shard's frame
+
+
+# -- traced reads with one data holder stopped under noout -------------------
+
+
+def _named(lines: dict, name: str) -> list:
+    return [ev for evs in lines.values() for ev in evs if ev[0] == name]
+
+
+@pytest.mark.parametrize("name", READ_PATH)
+def test_traced_degraded_read_leaves_span(traced_degraded_reads, name):
+    lines, _reads = traced_degraded_reads
+    assert _named(lines, name), sorted({ev[0] for evs in lines.values()
+                                        for ev in evs})
+
+
+def test_traced_degraded_read_marks_each_reconstruction(
+        traced_degraded_reads):
+    lines, reads = traced_degraded_reads
+    marks = [ev[3] for ev in _named(lines, "osd.ec.reconstruct")]
+    assert marks == [{"erased": 1, "bytes": 8192}] * reads
+
+
+def test_traced_degraded_read_counts_shard_bytes(traced_degraded_reads):
+    """k2m1 with data position 0 gone: each read fetches the one remote
+    survivor's 4 KiB shard, whichever of the two the primary holds."""
+    lines, reads = traced_degraded_reads
+    served = [ev[3]["bytes"] for ev in _named(lines, "osd.ec.sub_read")]
+    got = [ev[3]["bytes"] for ev in _named(lines, "osd.ec.sub_read_reply")]
+    assert served == [4096] * reads and got == [4096] * reads
+
+
+def test_traced_degraded_read_retires_with_read_stages(
+        traced_degraded_reads):
+    lines, reads = traced_degraded_reads
+    staged = [ev[3] for ev in _named(lines, "op.retired")
+              if "sub_read_us" in ev[3]]
+    assert len(staged) == reads
+    assert all(s["sub_read_us"] > 0 and s["decode_us"] > 0
+               and s["total_us"] >= s["sub_read_us"] + s["decode_us"]
+               and "subop_us" not in s for s in staged), staged
+
+
+def test_traced_degraded_read_spans_nest_per_thread(traced_degraded_reads):
+    test_traced_write_spans_nest_per_thread(traced_degraded_reads[0])
