@@ -2,13 +2,14 @@
 
 Analog of src/librados (RadosClient/IoCtx) over src/osdc/Objecter.cc:
 the client computes placement itself from its subscribed OSDMap
-(_calc_target, Objecter.cc:2776 — the same pg_to_up_acting_osds
-pipeline every daemon runs), sends MOSDOp straight to the acting
-primary, and owns all retry logic: on every new map epoch it re-targets
-in-flight ops and resends those whose primary moved (handle_osd_map ->
-_scan_requests, Objecter.cc:1303,2091); a connection reset requeues
-everything that was in flight on that session (lossy client policy —
-the reference's RESETSESSION handling).
+(_calc_target, Objecter.cc:2776 — the same up/acting pipeline every
+daemon runs, run once per pg and map epoch: _pg_target), sends MOSDOp
+straight to the acting primary, and owns all retry logic: on every new
+map epoch it re-targets in-flight ops and resends those whose primary
+moved (handle_osd_map -> _scan_requests, Objecter.cc:1303,2091); a
+connection reset requeues everything that was in flight on that
+session (lossy client policy — the reference's RESETSESSION
+handling).
 """
 
 from __future__ import annotations
@@ -100,7 +101,7 @@ class _InFlight:
         self.future = future
         self.target = -1        # osd the op was last sent to
         self.pgid = None
-        self.acting: list = []  # acting set at send time
+        self.acting: tuple = ()  # acting set at send time
         self.snapc = snapc      # (seq, [snapids desc]) on writes
         self.snapid = snapid    # read-from-snapshot id
         self.backoff = None     # ExpBackoff ramp (set on first send)
@@ -125,6 +126,11 @@ class RadosClient:
     # which bounds the recovery latency there.
     OP_RESEND_BASE = 0.5
     OP_RESEND_CAP = 5.0
+    # entries the per-epoch target table (_pg_target) may hold before
+    # it is dropped and refilled: a pool's touched PGs fit where pools
+    # are small, and a client of a 10M-PG pool does not grow without
+    # limit on a map that never changes
+    TARGET_TABLE_CAP = 1 << 16
 
     def __init__(self, mon_addr, ctx: Context | None = None,
                  name: str = "client.0", seed: int | None = None):
@@ -171,6 +177,12 @@ class RadosClient:
         self.rtt = RttEstimator(floor=self.OP_RESEND_BASE / 2,
                                 ceiling=self.OP_RESEND_CAP / 2)
         self.op_resends = 0     # ops the ticker sent again
+        # pg -> (acting primary, acting) on ONE map state, the one of
+        # _targets_of = (the map object, its epoch); see _pg_target
+        self._targets: dict[pg_t, tuple[int, tuple]] = {}
+        self._targets_of: tuple = (None, -1)
+        self.target_hits = 0    # lookups the table answered
+        self.target_misses = 0  # lookups that ran the CRUSH descent
         # client-side op tracking (Objecter's slice of the op span):
         # every submit registers with trace id "<entity>:<tid>", which
         # rides the MOSDOp envelope into the OSD pipeline
@@ -362,6 +374,10 @@ class RadosClient:
     def _handle_map(self, msg: MOSDMapMsg) -> None:
         self.osdmap, changed = consume_map_payload(
             self.osdmap, msg.full, msg.incrementals)
+        if changed:
+            # mutated in place or replaced: the table was another
+            # map state's, and _scan_requests below must not read it
+            self._drop_targets()
         # any map receipt (even the pre-boot epoch-0 one) proves the
         # mon link is up — connect() must not hang on a fresh cluster
         self._map_event.set()
@@ -384,9 +400,7 @@ class RadosClient:
                 if pool_id not in self.osdmap.pools:
                     del self._backoffs[key]
                     continue
-                _up, _upp, _acting, primary = \
-                    self.osdmap.pg_to_up_acting_osds(
-                        pg_t(pool_id, ps))
+                primary, _acting = self._pg_target(pg_t(pool_id, ps))
                 if primary != self._backoffs[key][0]:
                     del self._backoffs[key]
             self._scan_requests()
@@ -414,13 +428,44 @@ class RadosClient:
 
     # -- op submission -----------------------------------------------------
 
+    def _drop_targets(self) -> None:
+        self._targets = {}
+        self._targets_of = (self.osdmap, self.osdmap.epoch)
+
+    def _pg_target(self, pgid: pg_t) -> tuple[int, tuple]:
+        """(acting primary, acting) of a pg on the client's map: the
+        one place the client resolves placement.  The answer is a pure
+        function of (map state, pg) and the host CRUSH descent costs
+        milliseconds, so it runs the first time a pg is asked for in
+        an epoch and the table answers after.  The table is one map
+        state's: _handle_map drops it on every change, and a map that
+        is another object or at another epoch than the one it was
+        filled from (a caller assigned ``osdmap``) drops it here.  A
+        pg the map cannot place (no pool, no primary) is kept like any
+        other, for that epoch.  ``acting`` is a tuple: callers compare
+        it and never change it."""
+        m = self.osdmap
+        of_map, of_epoch = self._targets_of
+        if of_map is not m or of_epoch != m.epoch:
+            self._drop_targets()
+        target = self._targets.get(pgid)
+        if target is not None:
+            self.target_hits += 1
+            mark("client.target_hit")
+            return target
+        self.target_misses += 1
+        _up, _upp, acting, actingp = m.pg_to_up_acting_osds(pgid)
+        if len(self._targets) >= self.TARGET_TABLE_CAP:
+            self._drop_targets()
+        target = self._targets[pgid] = (actingp, tuple(acting))
+        return target
+
     def _calc_target(self, pool_id: int, oid: str):
         with span("client.calc_target"):
             pool = self.osdmap.pools[pool_id]
             raw = self.osdmap.object_locator_to_pg(oid, pool_id)
             pgid = pool.raw_pg_to_pg(raw)  # Objecter.cc:2830
-            up, upp, acting, actingp = \
-                self.osdmap.pg_to_up_acting_osds(pgid)
+            actingp, acting = self._pg_target(pgid)
             return actingp, pgid, acting
 
     def submit_op(self, pool_id: int, oid: str, ops: list[dict],
@@ -447,10 +492,8 @@ class RadosClient:
         pool = self.osdmap.pools[pool_id]
         names: list[str] = []
         for ps in range(pool.pg_num):
-            pgid = pool.raw_pg_to_pg(
-                __import__("ceph_tpu.osd.osdmap",
-                           fromlist=["pg_t"]).pg_t(pool_id, ps))
-            up, upp, acting, actingp =                 self.osdmap.pg_to_up_acting_osds(pgid)
+            pgid = pool.raw_pg_to_pg(pg_t(pool_id, ps))
+            actingp, acting = self._pg_target(pgid)
             if actingp < 0:
                 continue
             addr = self.osdmap.osd_addrs.get(actingp)

@@ -38,7 +38,10 @@ MAPPING = "bulk mapping"
 SPANS: dict[str, tuple[str, str]] = {
     # -- the served write path, by what the loop's one thread is doing ----
     "client.calc_target": (CLIENT, "object -> pg -> acting primary on the "
-                           "client's map (host CRUSH)"),
+                           "client's map (host CRUSH the first time a "
+                           "pg is asked for in an epoch)"),
+    "client.target_hit": (CLIENT, "mark: a pg's target was answered from "
+                          "the client's per-epoch table, no CRUSH descent"),
     "client.submit": (CLIENT, "submit_op: tid, tracked op, first send"),
     "client.send_op": (CLIENT, "_send_op: target, build MOSDOp, queue it"),
     "client.handle_reply": (CLIENT, "_handle_reply: retire, resolve the "

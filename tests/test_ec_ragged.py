@@ -276,7 +276,14 @@ def test_delta_write_device_route_and_amplification():
     from ceph_tpu.testing import LocalCluster
 
     async def main():
-        c = await LocalCluster(n_osds=3, seed=77).start()
+        # nobody dies in this test, and the first encode compiles on
+        # the loop every daemon shares: at FAST_CONF's 0.6 s grace that
+        # stall gets all three OSDs marked down (ROADMAP A-first) just
+        # as the partial write is sent, and a write that lands in a
+        # degraded interval is a whole-object RMW by design, 64 KiB of
+        # sub-reads.  The shipped grace keeps the premise: a clean PG.
+        c = await LocalCluster(n_osds=3, seed=77,
+                               conf={"heartbeat_grace": 6.0}).start()
         try:
             out = await c.client.mon_command(
                 "osd pool create", pool="ragdelta", pg_num=8,

@@ -31,12 +31,21 @@ WRITE_PATH = (
     "ec.prepare", "ec.stage", "ec.dispatch", "ec.deliver", "ec.collect",
     "store.apply", "op.retired")
 # what a k2m1 read leaves with the OSD of a data shard stopped under
-# noout, beside the client's, the messenger's and the op queue's spans
-READ_PATH = ("osd.ec.read", "osd.ec.sub_read", "osd.ec.sub_read_reply",
-             "osd.ec.reconstruct", "ec.decode_prepare", "ec.decode_collect",
-             "ec.dispatch", "op.retired")
+# noout, beside the client's, the messenger's and the op queue's spans;
+# the first object was read once before the trace, in the same epoch, so
+# its target comes from the client's table
+READ_PATH = ("client.target_hit", "osd.ec.read", "osd.ec.sub_read",
+             "osd.ec.sub_read_reply", "osd.ec.reconstruct",
+             "ec.decode_prepare", "ec.decode_collect", "ec.dispatch",
+             "op.retired")
 REMAP_PATH = ("crush.build", "crush.upload", "crush.launch", "crush.wait",
               "crush.readback", "crush.tables")
+# the first EC write of a process compiles on the loop every daemon
+# shares; at FAST_CONF's 0.6 s grace that stall gets all three OSDs
+# marked down at once (ROADMAP A-first) and the write in flight can come
+# back -EAGAIN.  Nothing here is about failure detection: the fixtures'
+# clusters keep the shipped grace, and the one kill waits that long.
+SHIPPED_GRACE = {"heartbeat_grace": 6.0}
 
 
 def _is_span_call(node, names=("span", "mark")) -> bool:
@@ -180,7 +189,7 @@ def traced_write(tmp_path_factory):
     os.environ["CEPH_TPU_EC_OFFLOAD"] = "1"
 
     async def main():
-        c = await LocalCluster(n_osds=3).start()
+        c = await LocalCluster(n_osds=3, conf=SHIPPED_GRACE).start()
         try:
             pid = await c.create_pool("spans", pg_num=4,
                                       pool_type="erasure")
@@ -219,7 +228,7 @@ def traced_degraded_reads(tmp_path_factory):
     names = []
 
     async def main():
-        c = await LocalCluster(n_osds=3).start()
+        c = await LocalCluster(n_osds=3, conf=SHIPPED_GRACE).start()
         try:
             pid = await c.create_pool("reads", pg_num=4,
                                       pool_type="erasure")
