@@ -39,8 +39,7 @@ already runs on-chip: a rolling hash over every byte position
   reference, which is the same function.
 
 Bit-parity contract: `chunk_host` and the device path produce the
-identical cut lists and fingerprints (pinned by tests/test_dedup.py
-and the `bench.py --dedup` gate).
+identical cut lists and fingerprints (pinned by tests/test_dedup.py).
 """
 
 from __future__ import annotations

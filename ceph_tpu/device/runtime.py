@@ -734,11 +734,7 @@ class DeviceRuntime:
         self._probe_cap = 1.0
         self.shard_min_words = _SHARD_MIN_WORDS
         self.util_window = 10.0     # utilization-integral window (s)
-        # continuous dispatch stream (device.stream): mode + geometry.
-        # "stream" is the architecture default — the flush batcher
-        # survives behind "flush" as the degradation route and the
-        # bench baseline
-        self.dispatch_mode = "stream"
+        # continuous dispatch stream (device.stream): geometry
         self.stream_interval = 100e-6   # admission-loop idle tick (s)
         self.stream_slot_words = 1 << 19  # slot-group geometry cap
         self.stream_max_slots = 4         # in-flight slots per chip
@@ -808,9 +804,8 @@ class DeviceRuntime:
                 0.1, float(conf["device_util_window"]))
         except (KeyError, TypeError, ValueError):
             pass
-        # dispatch-stream mode + geometry + per-tenant admission rows
+        # dispatch-stream geometry + per-tenant admission rows
         try:
-            self.dispatch_mode = str(conf["device_dispatch_mode"])
             self.stream_interval = max(
                 1e-6, int(conf["device_stream_interval_us"]) / 1e6)
             self.stream_slot_words = max(
@@ -824,16 +819,6 @@ class DeviceRuntime:
             self.tenant_qos = parse_tenant_qos(
                 str(conf.get("osd_mclock_tenant_qos", "") or ""))
         except Exception:
-            pass
-        # flush-mode tunables ride along: the loop's batcher adopts
-        # the conf window/size triggers (the stream ignores both)
-        try:
-            from ..ec.batcher import DeviceBatcher
-            bat = DeviceBatcher.get()
-            bat.window_us = max(1, int(conf["ec_batch_flush_us"]))
-            bat.max_batch_bytes = max(
-                1 << 12, int(conf["ec_batch_max_bytes"]))
-        except (KeyError, TypeError, ValueError, RuntimeError):
             pass
 
     # -- mesh placement ----------------------------------------------------

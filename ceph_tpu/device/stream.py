@@ -1,20 +1,18 @@
 """Persistent per-chip dispatch streams: continuous EC admission.
 
-The flush batcher (ec.batcher) accumulates items per key and flushes a
-whole batch as one dispatch — so under mixed client/recovery/scrub/
-tenant load a small urgent op waits for whichever flush it rode: the
-deadline window, the co-batched bulk, and the single all-or-nothing
-retire.  The PR-10 utilization integrals (`queue_wait_frac`) measure
-exactly that wait; this module removes it, following continuous
-batching from LLM serving — the Ragged Paged Attention kernel
-(arXiv:2604.15464) pages heterogeneous work through one compiled
-program family instead of re-bucketing per flush, and the GF(2^w)
-inner loops tolerate the fixed-geometry restructuring (the
-XOR-scheduling results of arXiv:2108.02692).
+An accumulate-and-flush batcher makes a small urgent op wait for
+whichever flush it rides: the deadline window, the co-batched bulk,
+and the single all-or-nothing retire.  The utilization integrals
+(`queue_wait_frac`) measure exactly that wait; the stream has none of
+it, following continuous batching from LLM serving — the Ragged Paged
+Attention kernel (arXiv:2604.15464) pages heterogeneous work through
+one compiled program family instead of re-bucketing per flush, and
+the GF(2^w) inner loops tolerate the fixed-geometry restructuring
+(the XOR-scheduling results of arXiv:2108.02692).
 
 One ``DispatchStream`` per ``ChipRuntime``:
 
-* **continuous admission** — `submit` lands an op (one encode/delta/
+* **continuous admission** — `encode` lands an op (one encode/delta/
   decode matmul request) in the stream with a weighted-fair virtual
   finish tag: class shares mirror ``osd.scheduler
   DEVICE_DISPATCH_WEIGHTS`` and tenant-stamped client ops order by
@@ -25,26 +23,26 @@ One ``DispatchStream`` per ``ChipRuntime``:
   apart) and packs whatever is resident into **slots**;
 * **fixed-geometry slots** — a slot group is the tag-contiguous run
   of pending ops sharing one program family (matrix, w, class),
-  capped at ``device_stream_slot_words``; its words stage across the
-  same pow2 bucket ladder flush batching uses (``DeviceRuntime.
-  ragged_plan``), so slot programs are the already-compiled bucket
-  family and the <=8-program budget is untouched.  Oversized groups
-  mesh-shard exactly like oversized flushes;
+  capped at ``device_stream_slot_words``; its words stage across a
+  pow2 bucket ladder (``DeviceRuntime.ragged_plan``), so slot
+  programs are one already-compiled bucket family and the
+  <=8-program budget holds.  Oversized groups mesh-shard across the
+  available chips (ec.batcher);
 * **independent retire** — each slot dispatches as its own task and
   retires ITS ops' futures the moment it completes: an urgent client
   op never waits on a co-batched recovery stripe's flush, and a
   recovery slot in flight never blocks the next client slot's
   admission;
 * **degradation** — a poisoned chip or failed dispatch host-encodes
-  the slot's ops (bit-parity with the host codecs by construction,
-  the same ``host_encode`` route flush batching degrades to), so
-  every submitted future retires exactly once, mid-stream chip loss
-  included.
+  the slot's ops (bit-parity with the host codecs by construction:
+  ``ec.batcher.host_encode``), so every submitted future retires
+  exactly once, mid-stream chip loss included.
 
 Every slot carries a ``DispatchTicket`` stamped with the earliest
 admitted op's arrival (queue_wait = arrival -> grant, the honest
-figure) and ``stream=True``, so the flight recorder renders the
-before/after on the same Perfetto device lanes.
+figure) and ``stream=True`` (other planes' tickets are not stream
+tickets), which the flight recorder renders on the Perfetto device
+lanes.
 """
 
 from __future__ import annotations
@@ -143,8 +141,8 @@ class DispatchStream:
 
     async def encode(self, matrix, w: int, data, klass: str,
                      on_ticket=None, tenant: str | None = None):
-        """Stream-mode analog of DeviceBatcher.encode: admit the op
-        and await its independently-retired parity slice."""
+        """What DeviceBatcher.encode enqueues onto: admit the op and
+        await its independently-retired parity slice."""
         matrix_key = tuple(tuple(r) for r in matrix)
         loop = asyncio.get_event_loop()
         fut = loop.create_future()

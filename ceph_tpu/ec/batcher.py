@@ -6,24 +6,14 @@ ride one dispatch.  This is the aggregation layer the reference doesn't
 need (ISA-L encodes synchronously per call inside the OSD thread,
 src/erasure-code/isa/ErasureCodeIsa.cc:129).
 
-Two dispatch architectures share this module's staging/encode path:
-
-* **stream** (``device_dispatch_mode=stream``, the default):
-  `encode` is a thin enqueue shim onto the caller chip's persistent
-  dispatch stream (ceph_tpu.device.stream) — continuous admission
-  into fixed-geometry slots, independent per-slot retire, no flush
-  barrier.  The stream's slot dispatches call back into
-  `stream_dispatch` below, so staging, mesh sharding, tickets and
-  host degradation are identical in both modes.
-* **flush** (the legacy architecture, kept as the bench baseline and
-  the degradation route): concurrent `encode_async` calls from any
-  number of PGs/objects in the same event loop are queued per
-  (coding-matrix, w, service-class) key and flushed as ONE device
-  matmul batch — either when the pending payload reaches
-  `max_batch_bytes` (conf ``ec_batch_max_bytes``) or when the oldest
-  entry has waited `window_us` (conf ``ec_batch_flush_us``; the
-  deadline flush keeps p99 bounded, the way the reference bounds
-  batching with per-op deadlines elsewhere).
+`DeviceBatcher.encode` is a thin enqueue shim onto the caller chip's
+persistent dispatch stream (ceph_tpu.device.stream): continuous
+admission into fixed-geometry slots, independent per-slot retire.
+The stream's slot dispatches call back into `stream_dispatch` below,
+which owns staging, mesh sharding, tickets and host degradation; a
+"flush" in this module is one such slot dispatch.  With the whole
+mesh down there is no stream to enter and `encode` host-encodes the
+call inline.
 
 Every flush routes through the shared device runtime
 (ceph_tpu.device.runtime) onto a mesh **chip** — the caller's
@@ -115,57 +105,24 @@ def tenant_label(tenants) -> str | None:
     return "mixed"
 
 
-class _PendingBatch:
-    __slots__ = ("arrays", "futures", "tickets", "tenants", "n_words",
-                 "timer", "t_first")
-
-    def __init__(self):
-        import time
-        self.arrays: list[np.ndarray] = []   # each [k, n_i] words
-        self.futures: list[asyncio.Future] = []
-        self.tickets: list = []              # per-item on_ticket cbs
-        self.tenants: list = []              # per-item tenant keys
-        self.n_words = 0
-        self.timer = None
-        # first item's arrival: the flush ticket's t_enqueue, so
-        # queue_wait honestly includes the batch-window wait (the
-        # figure the dispatch stream is gated against)
-        self.t_first = time.monotonic()
-
-    def tenant_label(self) -> str | None:
-        return tenant_label(self.tenants)
-
-
 class DeviceBatcher:
     """Batches GF(2^w) region matmuls across concurrent callers.
 
-    One instance per event loop (get() is loop-local); keys are
-    (matrix-tuple, w, klass) so every profile/erasure-signature gets
-    its own stream per service class but shares the flush machinery.
+    One instance per event loop (get() is loop-local): the dispatch
+    path every chip's stream shares, and the counters of what it ran.
     """
 
-    def __init__(self, window_us: int = 300,
-                 max_batch_bytes: int = 8 << 20):
-        # flush-mode tunables; conf-backed (ec_batch_flush_us /
-        # ec_batch_max_bytes, adopted via DeviceRuntime.configure) so
-        # the bench can sweep them
-        self.window_us = window_us
-        self.max_batch_bytes = max_batch_bytes
-        self._pending: dict[tuple, _PendingBatch] = {}
+    def __init__(self):
         self.batches_flushed = 0
         self.items_encoded = 0
         self.host_flushes = 0        # flushes served by the host path
         self.sharded_flushes = 0     # flushes split across the mesh
-        # per-flush wall time of the device call (host-blocked:
-        # upload + kernel + readback), read by bench.py --trace
-        self.flush_history: list[float] = []   # bounded ring
 
     @classmethod
     def get(cls) -> "DeviceBatcher":
         """Per-event-loop instance, stored ON the loop object so its
         lifetime tracks the loop's (an id(loop)-keyed registry would
-        hand a recycled address a stale instance whose dead timer
-        blocks the deadline flush forever)."""
+        hand a recycled address a dead loop's instance)."""
         loop = asyncio.get_event_loop()
         inst = getattr(loop, "_ceph_tpu_ec_batcher", None)
         if inst is None:
@@ -207,103 +164,50 @@ class DeviceBatcher:
         concurrent callers using the same (matrix, w, klass, chip).
 
         `chip` is the caller's mesh affinity (OSDs pass their bound
-        chip; None routes to the first available chip) — batches are
-        keyed per chip so each chip runs its own stream and a
-        poisoned chip degrades only its own callers.
+        chip; None routes to the first available chip) — each chip
+        runs its own stream and a poisoned chip degrades only its own
+        callers.
 
-        `on_ticket` (if given) receives the flush's DispatchTicket
+        `on_ticket` (if given) receives the slot's DispatchTicket
         after the device call — exact per-op dispatch attribution
-        (the primary shard's ticket when the flush sharded across the
-        mesh).  Host-fallback flushes deliver no ticket (there was no
+        (the primary shard's ticket when the slot sharded across the
+        mesh).  Host-encoded work delivers no ticket (there was no
         device dispatch to attribute).
 
-        Dispatch architecture: under ``device_dispatch_mode=stream``
-        (the default) this call is a thin enqueue shim onto the
-        caller's chip's persistent dispatch stream (device.stream) —
-        continuous admission, independent retire.  The accumulate-
-        and-flush path below survives as the ``flush`` mode (bench
-        baseline) and as the stream's degradation route."""
+        A thin enqueue shim onto the routed chip's persistent dispatch
+        stream (device.stream): continuous admission, independent
+        retire.  With the whole mesh down there is no stream to enter
+        and this call is host-encoded inline."""
         rt = DeviceRuntime.get()
-        if rt.dispatch_mode == "stream":
-            target = rt.route(chip)
-            if target is not None:
-                return await target.stream.encode(
-                    matrix, int(w), np.ascontiguousarray(data),
-                    klass, on_ticket=on_ticket, tenant=tenant)
-        key = (tuple(tuple(r) for r in matrix), int(w), klass,
-               None if chip is None else int(chip))
-        loop = asyncio.get_event_loop()
-        pb = self._pending.get(key)
-        if pb is None:
-            pb = _PendingBatch()
-            self._pending[key] = pb
-        fut = loop.create_future()
-        pb.arrays.append(np.ascontiguousarray(data))
-        pb.futures.append(fut)
-        pb.tickets.append(on_ticket)
-        pb.tenants.append(tenant)
-        pb.n_words += data.shape[1]
-        word_bytes = _WORD_DTYPE[int(w)]().itemsize
-        if (pb.n_words * data.shape[0] * word_bytes
-                >= self.max_batch_bytes):
-            self._flush(key)
-        elif pb.timer is None:
-            pb.timer = loop.call_later(self.window_us / 1e6,
-                                       self._flush, key)
-        return await fut
+        data = np.ascontiguousarray(data)
+        target = rt.route(chip)
+        if target is not None:
+            return await target.stream.encode(
+                matrix, int(w), data, klass, on_ticket=on_ticket,
+                tenant=tenant)
+        try:
+            out = self._host_dispatch(
+                rt.chip(chip), tuple(tuple(r) for r in matrix),
+                int(w), [data])
+        except Exception as e:
+            # a real codec error: it must reach the awaiting OSD op
+            raise IOError("EC encode failed: %r" % e) from e
+        self.batches_flushed += 1
+        self.items_encoded += 1
+        return out
 
-    def _flush(self, key) -> None:
-        """Detach the pending batch and dispatch it as a task (the
-        device path awaits admission, so the flush body is async —
-        call_later fires this sync shim)."""
-        pb = self._pending.pop(key, None)
-        if pb is None:
-            return
-        if pb.timer is not None:
-            pb.timer.cancel()
-        asyncio.get_event_loop().create_task(self._flush_async(key, pb))
-
-    async def _device_dispatch(self, rt, target, matrix_key, w: int,
-                               klass: str, parts: list[np.ndarray],
-                               n: int, tenant: str | None,
-                               t_enqueue: float | None,
-                               stream: bool):
-        """The shared device attempt both architectures ride: shard
-        plan -> single-chip or mesh-sharded encode, flush timing
-        recorded.  Returns (out, ticket) — (None, None) when the
-        device pushed back or was lost (caller degrades to the host
-        codec)."""
-        if target is None or not target.available:
-            return None, None
-        import time
-        t0 = time.perf_counter()
-        plan = rt.shard_plan(target, n)
-        if len(plan) == 1:
-            out, ticket = await self._encode_shard(
-                target, matrix_key, int(w), klass, parts, n,
-                solo=True, tenant=tenant, t_enqueue=t_enqueue,
-                stream=stream)
-        else:
-            out, ticket = await self._encode_sharded(
-                rt, plan, matrix_key, int(w), klass, parts,
-                tenant=tenant, t_enqueue=t_enqueue, stream=stream)
-        if out is not None:
-            self.flush_history.append(time.perf_counter() - t0)
-            if len(self.flush_history) > 512:
-                del self.flush_history[:256]
-        return out, ticket
-
-    def _host_dispatch(self, rt, target, chip_idx, matrix_key, w: int,
+    def _host_dispatch(self, chip, matrix_key, w: int,
                        parts: list[np.ndarray]) -> np.ndarray:
-        """Host-codec degradation route (device lost / DeviceBusy):
-        bit-parity with the device path by construction.  Raises on a
-        real codec error — the caller must fail the awaiting futures,
-        never hang them."""
+        """Host-codec degradation route, counted on `chip` (device
+        lost / DeviceBusy / whole mesh down; a slot, or one shard of a
+        mesh-split slot): bit-parity with the device path by
+        construction, so correctness never depends on the mesh.
+        Raises on a real codec error — the caller must fail the
+        awaiting futures, never hang them."""
         flat = (parts[0] if len(parts) == 1
                 else np.concatenate(parts, axis=1))
         out = host_encode([list(r) for r in matrix_key], w, flat)
-        (target if target is not None
-         else rt.chip(chip_idx)).host_fallbacks += 1
+        chip.host_fallbacks += 1
         self.host_flushes += 1
         return out
 
@@ -312,68 +216,33 @@ class DeviceBatcher:
                               n: int, tenant: str | None = None,
                               t_enqueue: float | None = None):
         """One stream slot's dispatch (device.stream DispatchStream):
-        the same device path flushes ride — ragged bucket-ladder
-        staging on the slot's chip, mesh sharding for oversized
-        groups — with the host codec as the degradation route.
+        ragged bucket-ladder staging on the slot's chip, mesh
+        sharding for oversized groups, with the host codec as the
+        degradation route.
         Returns (out, ticket-or-None); raises only on a host-codec
         failure."""
-        rt = chip.rt
-        out, ticket = await self._device_dispatch(
-            rt, chip if chip.available else None, matrix_key, w,
-            klass, parts, n, tenant, t_enqueue, stream=True)
+        out = ticket = None
+        if chip.available:
+            plan = chip.rt.shard_plan(chip, n)
+            if len(plan) == 1:
+                out, ticket = await self._encode_shard(
+                    chip, matrix_key, int(w), klass, parts, n,
+                    solo=True, tenant=tenant, t_enqueue=t_enqueue)
+            else:
+                out, ticket = await self._encode_sharded(
+                    plan, matrix_key, int(w), klass, parts,
+                    tenant=tenant, t_enqueue=t_enqueue)
         if out is None:
-            out = self._host_dispatch(rt, chip, chip.index,
-                                      matrix_key, w, parts)
+            out = self._host_dispatch(chip, matrix_key, w, parts)
         self.batches_flushed += 1
         self.items_encoded += len(parts)
         return out, ticket
-
-    async def _flush_async(self, key, pb: _PendingBatch) -> None:
-        matrix_key, w, klass, chip_idx = key
-        rt = DeviceRuntime.get()
-        target = rt.route(chip_idx)
-        out, ticket = await self._device_dispatch(
-            rt, target, matrix_key, int(w), klass, pb.arrays,
-            pb.n_words, pb.tenant_label(), pb.t_first, stream=False)
-        if out is None:
-            try:
-                out = self._host_dispatch(rt, target, chip_idx,
-                                          matrix_key, w, pb.arrays)
-            except Exception as e:
-                # a host-path failure is a real codec error: it must
-                # reach the awaiting OSD ops (they would otherwise
-                # hang forever — submit_write's sub-op timeout sits
-                # AFTER the encode await)
-                for fut in pb.futures:
-                    if not fut.cancelled():
-                        fut.set_exception(
-                            IOError("EC encode failed: %r" % e))
-                return
-        self.batches_flushed += 1
-        self.items_encoded += len(pb.arrays)
-        self._deliver(pb, out, ticket)
-
-    @staticmethod
-    def _deliver(pb: _PendingBatch, out: np.ndarray, ticket) -> None:
-        with span("ec.deliver", items=len(pb.arrays)):
-            off = 0
-            for arr, fut, cb in zip(pb.arrays, pb.futures, pb.tickets):
-                ni = arr.shape[1]
-                if not fut.cancelled():
-                    fut.set_result(out[:, off:off + ni])
-                if cb is not None and ticket is not None:
-                    try:
-                        cb(ticket)
-                    except Exception:
-                        pass    # attribution must never sink the flush
-                off += ni
 
     async def _encode_shard(self, chip, matrix_key, w: int,
                             klass: str, parts: list[np.ndarray],
                             n: int, solo: bool,
                             tenant: str | None = None,
-                            t_enqueue: float | None = None,
-                            stream: bool = False):
+                            t_enqueue: float | None = None):
         """One chip's slice of a flush: admit on the chip's queue,
         stage the ragged total into its pooled bucket-ladder buffers,
         dispatch on its device.  Returns (parity [m, n], ticket).
@@ -387,7 +256,7 @@ class DeviceBatcher:
         stops burning bucket-ceiling bandwidth (GF parity is
         column-independent, so the segment split is exact).  Items may
         span segment boundaries; per-item offsets stay global column
-        offsets, so `_deliver`'s slicing is unchanged.
+        offsets, so the stream's per-op slicing is unchanged.
 
         `solo=True` is the whole-flush single-chip path: DeviceBusy
         and device loss return (None, None) so the caller degrades
@@ -402,13 +271,13 @@ class DeviceBatcher:
         ticket = chip.open_ticket(klass, padded,
                                   n * k * dtype().itemsize,
                                   tenant=tenant, t_enqueue=t_enqueue,
-                                  stream=stream)
+                                  stream=True)
         try:
             await chip.admit(ticket)
         except DeviceBusy:
             if solo:
                 return None, None
-            return self._host_shard(chip, matrix_key, w, parts), None
+            return self._host_dispatch(chip, matrix_key, w, parts), None
         bufs: list[np.ndarray] = []
         try:
             with span("ec.stage", words=n, padded=padded):
@@ -455,26 +324,15 @@ class DeviceBatcher:
             chip.poison(e)
             if solo:
                 return None, None
-            return self._host_shard(chip, matrix_key, w, parts), None
+            return self._host_dispatch(chip, matrix_key, w, parts), None
         finally:
             for buf in bufs:
                 chip.pool.release(buf)
 
-    def _host_shard(self, chip, matrix_key, w: int,
-                    parts: list[np.ndarray]) -> np.ndarray:
-        """Host-encode one shard of a mesh-split flush (its chip was
-        lost or pushed back): correctness never depends on the mesh."""
-        flat = (parts[0] if len(parts) == 1
-                else np.concatenate(parts, axis=1))
-        chip.host_fallbacks += 1
-        self.host_flushes += 1
-        return host_encode([list(r) for r in matrix_key], w, flat)
-
-    async def _encode_sharded(self, rt, plan, matrix_key, w: int,
+    async def _encode_sharded(self, plan, matrix_key, w: int,
                               klass: str, arrays: list[np.ndarray],
                               tenant: str | None = None,
-                              t_enqueue: float | None = None,
-                              stream: bool = False):
+                              t_enqueue: float | None = None):
         """Mesh-shard one oversized flush across the plan's chips:
         contiguous column slices encode concurrently (proven
         collective-free over the stripe axis) and reassemble
@@ -485,8 +343,7 @@ class DeviceBatcher:
         parts = await asyncio.gather(*[
             self._encode_shard(chip, matrix_key, w, klass,
                                [flat[:, lo:hi]], hi - lo, solo=False,
-                               tenant=tenant, t_enqueue=t_enqueue,
-                               stream=stream)
+                               tenant=tenant, t_enqueue=t_enqueue)
             for chip, lo, hi in plan])
         out = np.concatenate([p for p, _t in parts], axis=1)
         ticket = next((t for _p, t in parts if t is not None), None)

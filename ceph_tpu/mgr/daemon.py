@@ -42,8 +42,8 @@ def _fam_header(lines: list, fam: str, kind: str,
 
 def ingest_prom_lines(pgmap) -> list[str]:
     """Telemetry-fabric ingest families rendered from a PGMap's
-    accounting (module-level so `bench.py --scale`'s ingest leg can
-    lint the exposition without a live Manager): per-format report
+    accounting (module-level so tests/test_ingest.py can lint the
+    exposition without a live Manager): per-format report
     row/byte counters, the apply-latency histogram, the row-loop
     fallback counter, and the visible prune counters."""
     from ..utils.exporter import hist_lines
@@ -702,8 +702,7 @@ class Manager:
                 self.ctx.log.info("mgr", "balancer failed: %r" % e)
 
     async def balancer_tick(self) -> dict:
-        """One optimizer round + commit (shared by the autonomous
-        loop and `bench.py --scale`).  Mode rides
+        """One optimizer round + commit.  Mode rides
         `mgr_balancer_mode`: 'batched' generates every candidate move
         and scores them in bulk device dispatches
         (scale.balancer.batched_calc_pg_upmaps — the TPU-scored

@@ -770,8 +770,7 @@ class PGMap:
 class DictPGMap:
     """The original dict-of-rows PGMap: the golden reference the
     columnar fold AND the columnar ingest path are pinned against
-    (tests/test_scale.py, tests/test_ingest.py) and the baseline for
-    the `bench.py --scale` ingest/fold micro-benchmarks.  Keep its
+    (tests/test_scale.py, tests/test_ingest.py).  Keep its
     semantics bit-for-bit when touching either class."""
 
     def __init__(self, stale_after: float = 15.0):

@@ -56,16 +56,6 @@ TAG_CLOSE = 4
 
 _HDR = struct.Struct(">BII")  # tag, length, crc32
 
-# wire-accounting switch: bench.py --net A/Bs the cost of the
-# telemetry plane, so disabling must short-circuit every hot-path
-# accounting touch (per-type dicts, queue-wait stamps)
-_ACCOUNTING = True
-
-
-def set_net_accounting(on: bool) -> None:
-    global _ACCOUNTING
-    _ACCOUNTING = bool(on)
-
 
 class WireStats:
     """Per-peer wire accounting for one Connection (folded into the
@@ -73,8 +63,7 @@ class WireStats:
     counters survive connection churn and session reconnects).
 
     Dump keys are registered in ``trace.registry.NET_STAGES`` and
-    consumed by the mgr exporter, ``collect_diagnostics()`` and the
-    ``bench.py --net`` leg.
+    consumed by the mgr exporter and ``collect_diagnostics()``.
     """
 
     __slots__ = ("tx_msgs", "tx_bytes", "rx_msgs", "rx_bytes",
@@ -300,18 +289,14 @@ class Connection:
             data = encode_message(msg, stamp=self.msgr.now())
         if self.policy.resend:
             self.unacked.append((msg.seq, data))
-        if _ACCOUNTING:
-            self.stats.note_tx(msg.TYPE, len(data))
-            # queue-wait is SAMPLED 1-in-16: the clock-stamp pair
-            # (monotonic at enqueue + at pop) is the most expensive
-            # accounting instruction on this path, and the estimator
-            # only ever reports averages and maxima — both survive
-            # sampling.  Third element = enqueue stamp.
-            if self.out_seq & 0xF == 0:
-                self.out_q.put_nowait((TAG_MSG, data,
-                                       time.monotonic()))
-            else:
-                self.out_q.put_nowait((TAG_MSG, data))
+        self.stats.note_tx(msg.TYPE, len(data))
+        # queue-wait is SAMPLED 1-in-16: the clock-stamp pair
+        # (monotonic at enqueue + at pop) is the most expensive
+        # accounting instruction on this path, and the estimator
+        # only ever reports averages and maxima — both survive
+        # sampling.  Third element = enqueue stamp.
+        if self.out_seq & 0xF == 0:
+            self.out_q.put_nowait((TAG_MSG, data, time.monotonic()))
         else:
             self.out_q.put_nowait((TAG_MSG, data))
 
@@ -507,7 +492,7 @@ class Connection:
                     return
             item = await self.out_q.get()
             tag, payload = item[0], item[1]
-            if len(item) > 2 and _ACCOUNTING:
+            if len(item) > 2:
                 # queue wait: enqueue stamp -> pop (injected delays
                 # and socket drain are wire time, not queue time)
                 self.stats.note_queue_wait(time.monotonic() - item[2])
@@ -605,8 +590,7 @@ class Connection:
                 # received payload size: the ingest bytes accounting
                 # (mgr report telemetry) reads it off the message
                 msg.wire_bytes = len(payload)
-                if _ACCOUNTING:
-                    self.stats.note_rx(msg.TYPE, len(payload))
+                self.stats.note_rx(msg.TYPE, len(payload))
                 self.msgr.note_peer_clock(
                     msg.src, getattr(msg, "send_stamp", None))
                 # dedup: a lossless session replays after reconnect,
@@ -617,7 +601,7 @@ class Connection:
                 # REORDERING as duplication and silently drop frames
                 dup = (msg.seq <= self.in_seq if self.policy.resend
                        else msg.seq == self.in_seq)
-                if dup and self.policy.resend and _ACCOUNTING:
+                if dup and self.policy.resend:
                     # a session-replay duplicate absorbed by seq
                     self.stats.replays += 1
                 self.in_seq = max(self.in_seq, msg.seq)
@@ -667,13 +651,9 @@ class Connection:
             if item[0] == TAG_MSG:
                 pending.append(item)
         replay = {d: None for _, d in self.unacked}
-        if replay and _ACCOUNTING:
-            self.stats.resends += len(replay)
+        self.stats.resends += len(replay)
         for d in replay:
-            if _ACCOUNTING:
-                self.out_q.put_nowait((TAG_MSG, d, time.monotonic()))
-            else:
-                self.out_q.put_nowait((TAG_MSG, d))
+            self.out_q.put_nowait((TAG_MSG, d, time.monotonic()))
         for item in pending:
             if item[1] not in replay:
                 self.out_q.put_nowait(item)

@@ -71,16 +71,13 @@ FAST_CONF = {
     # window so saturation integrals react within a round
     "flight_recorder_sample": 1,
     "device_util_window": 5.0,
-    # continuous dispatch at dev pacing: the per-chip stream is the
-    # default architecture; a tight admission tick and the production
-    # slot geometry so urgent ops never wait a flush window, and the
-    # flush-mode tunables pinned so mode-comparison tests are stable
+    # continuous dispatch at dev pacing: a tight admission tick and
+    # the production slot geometry (device_dispatch_mode has one
+    # value; the driver benchmark/drivers/rados_bench reads the name)
     "device_dispatch_mode": "stream",
     "device_stream_interval_us": 100,
     "device_stream_slot_words": 1 << 19,
     "device_stream_max_slots": 4,
-    "ec_batch_flush_us": 300,
-    "ec_batch_max_bytes": 8 << 20,
     # tenant SLO plane at dev pacing: burn windows of seconds (not
     # SRE-scale minutes) so a bully round's burn both RAISES and
     # DECAYS within a thrash round, and a small min-ops floor so

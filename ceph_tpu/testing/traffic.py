@@ -13,9 +13,8 @@ The canonical scenario is the noisy neighbor: a bully tenant floods
 (many streams, wide windows) while victims run a modest steady load —
 with per-tenant dmClock rows configured (`osd_mclock_tenant_qos`),
 the bully is throttled at its limit tag and the victims' p99 holds.
-`bench.py --traffic` publishes exactly that figure behind a
-regression gate; the thrasher's `bully_tenant` action replays it
-mid-fault-schedule.
+tests/test_traffic_slo.py holds that figure; the thrasher's
+`bully_tenant` action replays it mid-fault-schedule.
 
 Acked-write tracking mirrors testing.thrasher.Workload: only writes
 whose future resolved are recorded, and `verify()` reads every one

@@ -29,8 +29,6 @@ as the primary scaling signal.  This module is both:
 Reachable via the admin socket (`dump_flight_recorder`),
 `LocalCluster.export_trace()`, the `rados trace export` CLI verb, and
 auto-dumped beside the diagnostics bundle on any failed thrash round.
-Overhead is benched and gated (`bench.py --trace`: <= 5% on the EC
-backend leg vs recorder-off).
 """
 
 from __future__ import annotations
@@ -38,8 +36,8 @@ from __future__ import annotations
 import time
 import zlib
 
-# process-wide enable switch (bench.py --trace measures the recorder's
-# overhead by flipping it through set_enabled)
+# process-wide enable switch; its one caller is
+# tests/test_flight_recorder.py (ROADMAP C14)
 _ENABLED = True
 
 _DEVICE_RING_CAP = 4096

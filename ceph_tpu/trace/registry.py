@@ -1,11 +1,11 @@
 """Stage/series name registries + the drift lint.
 
-The flight recorder, `bench.py --trace`, and the trace tests all
-reference OpTracker stage names and device exporter series by string
-literal.  A renamed stage at its emission site (`mark_event("...")`)
-would silently break every consumer — the timeline still renders, the
-bench still prints, but the renamed stage just stops matching.  This
-module makes that a tier-1 lint failure instead:
+The flight recorder and the trace tests reference OpTracker stage
+names and device exporter series by string literal.  A renamed stage
+at its emission site (`mark_event("...")`) would silently break every
+consumer — the timeline still renders, but the renamed stage just
+stops matching.  This module makes that a tier-1 lint failure
+instead:
 
 * ``OP_STAGES`` / ``OP_STAGE_PREFIXES`` — the canonical registry of
   every stage name the tracker can emit (prefixes cover the dynamic
@@ -15,7 +15,7 @@ module makes that a tier-1 lint failure instead:
   publishes (checked against a live ChipRuntime, so a metrics() key
   added without registration also fails);
 * ``CONSUMER_STAGE_REFS`` — which stage names each consumer file
-  (bench.py, the trace tests) is known to reference.
+  (the trace tests) is known to reference.
 
 ``lint_repo()`` closes the loop in both directions: every emitted
 literal must be registered, every registered name must still be
@@ -185,9 +185,6 @@ EVENT_TYPES = frozenset({
 # consumers referencing history series / event types by literal —
 # every entry must be registered AND still present in the file
 CONSUMER_HISTORY_REFS = {
-    "bench.py": (
-        "io.write_ops_s", "device.busy_frac",
-    ),
     "tests/test_history.py": (
         "io.write_ops_s", "device.busy_frac", "tenant.p99_ms",
         "pg.degraded",
@@ -200,10 +197,6 @@ CONSUMER_HISTORY_REFS = {
 # consumers referencing the net plane (WireStats fields / exporter
 # families) by literal — registered AND literally present, both ways
 CONSUMER_NET_REFS = {
-    "bench.py": (
-        "ceph_tpu_net_rtt_ms", "ceph_tpu_net_peer_tx_bytes_total",
-        "resends", "queue_depth",
-    ),
     "tests/test_net.py": (
         "ceph_tpu_net_rtt_ms", "ceph_tpu_net_resends_total",
         "resends", "replays", "queue_wait_s",
@@ -217,14 +210,10 @@ CONSUMER_EVENT_REFS = {
     ),
 }
 
-# consumers referencing the ingest families by literal (the bench
-# ingest leg asserts its exposition render; the ingest tests pin the
-# scrape surface) — every entry must be registered AND present
+# consumers referencing the ingest families by literal (the ingest
+# tests pin the scrape surface) — every entry must be registered AND
+# present
 CONSUMER_MGR_REFS = {
-    "bench.py": (
-        "ceph_tpu_mgr_ingest_seconds",
-        "ceph_tpu_mgr_report_rows_total",
-    ),
     "tests/test_ingest.py": (
         "ceph_tpu_mgr_report_rows_total",
         "ceph_tpu_mgr_report_bytes_total",
@@ -247,10 +236,6 @@ CONSUMER_MGR_REFS = {
 # lint demands every entry be registered AND literally present in the
 # file, so a stage rename that misses a consumer fails here
 CONSUMER_STAGE_REFS = {
-    "bench.py": (
-        "queued", "reached_pg", "sub_op_sent", "ec_sub_write_sent",
-        "ec_sub_write_acked", "ec_encode_start", "ec_encoded",
-    ),
     "tests/test_optracker.py": (
         "queued", "reached_pg", "started_write", "sub_op_sent",
         "started_apply", "applied", "ec_encode_start", "ec_encoded",
@@ -272,14 +257,8 @@ CONSUMER_SERIES_REFS = {
         "device_util_busy", "device_util_queue_wait",
         "device_util_idle",
     ),
-    # the continuous-dispatch + repair-traffic + compression bench
-    # legs and their tests consume these series by literal name
-    "bench.py": (
-        "device_slot_occupancy", "device_admission_wait",
-        "device_repair_bytes_read", "device_repair_bytes_moved",
-        "device_compress_bytes_in", "device_compress_bytes_out",
-        "device_fingerprint_chunks", "device_fingerprint_bytes",
-    ),
+    # the continuous-dispatch, repair-traffic, compression and dedup
+    # tests consume these series by literal name
     "tests/test_tlz.py": (
         "device_compress_bytes_in", "device_compress_bytes_out",
     ),

@@ -282,17 +282,6 @@ DEFAULT_SCHEMA: list[Option] = [
     Option("osd_max_pg_log_entries", OPT_INT, 2000,
            "pg log length before trimming (peers that fall behind the"
            " trimmed tail are backfilled instead of log-recovered)"),
-    Option("ec_batch_max_stripes", OPT_INT, 4096,
-           "max stripes aggregated into one device EC dispatch"),
-    Option("ec_batch_flush_us", OPT_INT, 300,
-           "flush-mode deadline before a partial EC batch is flushed"
-           " (µs): the window the DEADLINE flush rides when"
-           " device_dispatch_mode=flush (the continuous stream has no"
-           " flush barrier and ignores it)"),
-    Option("ec_batch_max_bytes", OPT_INT, 8 << 20,
-           "flush-mode size trigger: a pending EC batch at or above"
-           " this many staged bytes flushes immediately instead of"
-           " waiting out ec_batch_flush_us"),
     Option("osd_objectstore", OPT_STR, "memstore",
            "backing store engine (src/common/options osd_objectstore)",
            enum_allowed=("memstore", "kstore", "extentstore")),
@@ -318,15 +307,13 @@ DEFAULT_SCHEMA: list[Option] = [
     Option("device_warmup", OPT_INT, 1,
            "pre-compile common EC shape buckets when a profile's codec"
            " is first built (0 disables)"),
+    # one value, no reader in ceph_tpu/: the name stays because the
+    # driver benchmark/drivers/rados_bench reads it out of the schema
     Option("device_dispatch_mode", OPT_STR, "stream",
-           "EC dispatch architecture: 'stream' runs the persistent"
-           " per-chip dispatch stream (continuous admission into"
-           " fixed-geometry slots, independent retire — the"
-           " continuous-batching recipe from LLM serving);"
-           " 'flush' keeps the legacy accumulate-and-flush batcher"
-           " (also the stream's host-fallback/DeviceBusy degradation"
-           " route and the bench baseline)",
-           enum_allowed=("stream", "flush")),
+           "EC dispatch architecture: the persistent per-chip dispatch"
+           " stream (continuous admission into fixed-geometry slots,"
+           " independent retire)",
+           enum_allowed=("stream",)),
     Option("device_stream_interval_us", OPT_INT, 100,
            "admission-loop idle tick (µs) of the per-chip dispatch"
            " stream: the loop wakes immediately on arrivals and slot"

@@ -20,8 +20,8 @@ from __future__ import annotations
 def enable_compile_cache() -> str | None:
     """Turn on JAX's persistent compilation cache for a process that
     holds the chip, and return the directory in use.  Called from the
-    process entry points (chip_smoke.py, bench.py, cli/vstart.py,
-    cli/osdmaptool.py), never at package import.
+    process entry points (chip_smoke.py, benchmark/run.py,
+    cli/vstart.py, cli/osdmaptool.py), never at package import.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
     no directory is set here.  Otherwise the cache lives at one fixed

@@ -199,7 +199,7 @@ async def phase_ec(args, rng, rt) -> None:
 # -- crush: OSDMapMapping over one 10M-PG pool ------------------------------
 
 def build_osdmap(n_osds: int, pg_num: int):
-    """bench.py's map: hosts of 20 OSDs, straw2, chooseleaf firstn host."""
+    """Hosts of 20 OSDs, straw2, chooseleaf firstn host."""
     from ceph_tpu.models.crushmap import (CHOOSELEAF_FIRSTN, EMIT, STRAW2,
                                           TAKE, CrushMap)
     from ceph_tpu.osd.osdmap import (OSD_EXISTS, OSD_UP, Incremental,

@@ -13,7 +13,6 @@ CEPH_TPU_EC_OFFLOAD=1 exercises the device path on the CPU backend —
 the programs are identical on TPU (same recipe as test_ec_batcher)."""
 
 import asyncio
-import copy
 import random
 import zlib
 
@@ -434,52 +433,9 @@ def test_thrasher_dedup_rounds():
     run(main(), timeout=420)
 
 
-# -- registry + bench gate -------------------------------------------------
+# -- registry ----------------------------------------------------------------
 
 
 def test_registry_lint_clean_with_dedup_series():
     from ceph_tpu.trace import registry
     assert registry.lint_repo() == []
-
-
-def test_bench_dedup_gate_logic():
-    import bench
-    good = {
-        "backend": "cpu",
-        "kernel": {
-            "cuts_parity_ok": True, "fingerprint_parity_ok": True,
-            "chunk_sizes_ok": True, "boundary_path": "device",
-            "fingerprint_path": "device", "compile_count": 4,
-            "host_fallbacks": 0, "device_fingerprint_chunks": 10,
-            "device_fingerprint_bytes": 1000,
-            "device_mibps": 1e9, "host_mibps": 2e9},
-        "shifted": {
-            "cdc_ratio": 1.6, "fixed_block_ratio": 1.1},
-        "cluster": {
-            "dedup_ratio": 2.5, "accounting_ok": True,
-            "readback_ok": True, "status_dedup_panel": {"1": {}},
-            "scrub_clean": True, "lost_acked_writes": 0},
-    }
-    g = bench._gate_dedup(good)
-    assert g["ok"], g
-    assert g["deferred"]        # CPU cannot decide throughput
-    bad = copy.deepcopy(good)
-    bad["kernel"]["cuts_parity_ok"] = False
-    bad["kernel"]["compile_count"] = 9
-    bad["cluster"]["dedup_ratio"] = 1.2
-    bad["cluster"]["lost_acked_writes"] = 1
-    bad["cluster"]["scrub_clean"] = False
-    g = bench._gate_dedup(bad)
-    assert not g["ok"]
-    assert len(g["failures"]) >= 5, g
-    # the shifted corpus must beat fixed-block addressing
-    skew = copy.deepcopy(good)
-    skew["shifted"] = {"cdc_ratio": 1.1, "fixed_block_ratio": 1.2}
-    g = bench._gate_dedup(skew)
-    assert not g["ok"]
-    assert any("resynchroniz" in f for f in g["failures"]), g
-    tpu = copy.deepcopy(good)
-    tpu["backend"] = "tpu"      # slower-than-host is a TPU failure
-    g = bench._gate_dedup(tpu)
-    assert not g["ok"]
-    assert not g["deferred"]

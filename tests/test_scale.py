@@ -16,8 +16,8 @@ Covers ISSUE 7's acceptance surface at tier-1 size:
   device-runtime dispatch (ticket asserted) and its emitted items are
   identical in effect to the calc_pg_upmaps validity rules.
 
-The 1k/5k/10k sweeps live in `bench.py --scale`; a pytest-marked slow
-variant boots 1k here for CI-style full passes.
+A pytest-marked slow variant boots 1k here for CI-style full passes;
+5k and 10k have no runner.
 """
 
 import asyncio
@@ -362,8 +362,7 @@ def test_late_joiner_full_map_plus_incrementals():
 
 @pytest.mark.slow
 def test_scale_cluster_1k():
-    """The 1k leg of the bench sweep as a CI-style full-pass test
-    (5k/10k stay bench-only)."""
+    """A 1k-OSD fleet as a CI-style full-pass test."""
 
     async def main():
         c = await ScaleCluster(1000, conf=QUIET).start()
