@@ -21,7 +21,10 @@ src/msg/Policy.h), re-expressed on asyncio instead of epoll threads:
 Structure: every Connection is owned by ONE supervisor task that loops
 {acquire transport -> run session (reader+writer subtasks) -> decide
 redial/die} — no fire-and-forget task chains, so faults can't orphan
-state.
+state.  A transport is a ``_Wire``, the messenger's own
+``asyncio.BufferedProtocol`` from the banner on: the handshake reads
+exact byte counts off it, then it cuts frames out of its receive buffer
+and lets the kernel write a large frame straight into the frame's own.
 
 Fault injection: set ``inject_socket_failures`` to N>0 to abort roughly
 one in N frame writes (ms_inject_socket_failures,
@@ -38,6 +41,7 @@ would break the lossless contract the session machinery guarantees.
 from __future__ import annotations
 
 import asyncio
+import collections
 import random
 import struct
 import time
@@ -222,26 +226,247 @@ class _PeerClosed(Exception):
     """Peer sent TAG_CLOSE: orderly teardown, not a fault."""
 
 
-async def _write_frame(writer: asyncio.StreamWriter, tag: int,
-                       payload: bytes) -> None:
+# One receive buffer per transport, as large as the pieces asyncio's
+# own stream reader asks the socket for.  A frame that fits it whole is
+# cut out of it (one copy, no wake-up of its own); a frame that cannot
+# fit gets a buffer of its own at the length its header states: what
+# came in the same read as its header is copied over, and the kernel
+# writes the rest of it there, a piece of this size per read.  Pieces,
+# because a 4 MiB frame taken in one read holds the loop, which every
+# daemon of the process shares, for as long as the kernel copies it; and
+# no smaller first read to spare that copy, which bought nothing on the
+# chip's host (PERF.md, PR 32).
+RX_BUF = 256 * 1024
+# A frame's length field is 32 bits and its buffer is allocated at once,
+# so a garbled header must not be believed: anything over this is a
+# transport fault, as a crc mismatch is.  The largest frame the tree
+# sends is a recovery push of 16 whole objects (OSD._replicated_recover):
+# 64 MiB and their attrs at rados bench's 4 MiB an object.
+MAX_FRAME = 256 << 20
+
+
+class _Wire(asyncio.BufferedProtocol):
+    """One TCP transport, both directions, from the banner on.
+
+    Receiving has two phases.  During the handshake the dialling or
+    accepting coroutine pulls exact byte counts out of the receive
+    buffer (``readexactly``).  ``start_frames`` ends it: from then on
+    ``buffer_updated`` cuts every whole frame the last ``recv_into``
+    delivered into ``frames``, raw and in arrival order, for the one
+    task that checks, decodes and dispatches them (``next_frame``).
+    What arrived behind the handshake's last byte is already in the
+    buffer and is cut first.
+
+    Back-pressure: when the consuming task has not drained what the
+    previous read cut and more than one frame waits, reading is paused
+    until the queue is empty, so a connection holds at most the frame
+    in dispatch, the frames of two reads and the one being filled.
+
+    Sending is the transport's ``write``; ``drain`` waits while the
+    transport reports its buffer over the high-water mark.
+    """
+
+    def __init__(self, on_accept=None):
+        self._on_accept = on_accept     # server side: called once connected
+        self.transport: asyncio.Transport | None = None
+        self._buf = bytearray(RX_BUF)
+        self._view = memoryview(self._buf)
+        self._lo = 0                    # unparsed bytes are _buf[_lo:_hi]
+        self._hi = 0
+        self._framed = False
+        self._body: bytearray | None = None     # a large payload filling
+        self._body_got = 0
+        self._body_head = (0, 0)        # its tag and crc
+        self.frames: collections.deque = collections.deque()
+        self._waiter: asyncio.Future | None = None  # the reading task
+        self._fault: Exception | None = None
+        self._rx_paused = False
+        self._tx_paused = False
+        self._drain_waiters: list[asyncio.Future] = []
+
+    # -- transport callbacks -------------------------------------------------
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        if self._on_accept is not None:
+            self._on_accept(self)
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        if self._body is not None:
+            got = self._body_got
+            return memoryview(self._body)[got:got + RX_BUF]
+        return self._view[self._hi:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        if not self._framed:
+            self._hi += nbytes
+            if self._hi == RX_BUF:
+                self._compact()
+                if self._hi == RX_BUF:
+                    # nobody reads the handshake's bytes yet (an inbound
+                    # transport waits for its session): stop here
+                    self._pause_rx()
+            self._wake()
+            return
+        with span("msgr.recv", bytes=nbytes,
+                  direct=nbytes if self._body is not None else 0):
+            behind = bool(self.frames)
+            if self._body is None:
+                self._hi += nbytes
+                self._cut()
+            else:
+                self._body_got += nbytes
+                if self._body_got == len(self._body):
+                    self.frames.append((*self._body_head, self._body))
+                    self._body = None
+            if self.frames:
+                if behind and len(self.frames) > 1:
+                    self._pause_rx()
+                self._wake()
+
+    def eof_received(self) -> None:
+        # the peer is gone mid-stream: a fault, and the transport closes
+        self._fail(ConnectionResetError("peer closed the stream"))
+
+    def connection_lost(self, exc) -> None:
+        self._fail(exc or ConnectionResetError("transport closed"))
+        self.resume_writing()
+
+    def pause_writing(self) -> None:
+        self._tx_paused = True
+
+    def resume_writing(self) -> None:
+        self._tx_paused = False
+        for waiter in self._drain_waiters:
+            if not waiter.done():
+                waiter.set_result(None)
+
+    # -- receive side --------------------------------------------------------
+
+    def _cut(self) -> None:
+        """Every whole frame in the buffer goes to ``frames``; a partial
+        one moves to the front, or into a buffer of its own when it can
+        never fit here."""
+        buf, view, lo, hi = self._buf, self._view, self._lo, self._hi
+        while hi - lo >= _HDR.size:
+            tag, length, crc = _HDR.unpack_from(buf, lo)
+            start = lo + _HDR.size
+            if start + length <= hi:
+                self.frames.append(
+                    (tag, crc, bytes(view[start:start + length])))
+                lo = start + length
+            elif length > MAX_FRAME:
+                self._fail(ConnectionError_(
+                    "frame of %d bytes (tag %d)" % (length, tag)))
+                self._pause_rx()
+                break
+            elif _HDR.size + length > RX_BUF:
+                body = bytearray(length)
+                body[:hi - start] = view[start:hi]
+                self._body, self._body_got = body, hi - start
+                self._body_head = (tag, crc)
+                lo = hi
+                break
+            else:
+                break
+        self._lo, self._hi = lo, hi
+        self._compact()
+
+    def _compact(self) -> None:
+        if self._lo:
+            rest = self._hi - self._lo
+            self._buf[:rest] = self._buf[self._lo:self._hi]
+            self._lo, self._hi = 0, rest
+
+    def _pause_rx(self) -> None:
+        if not self._rx_paused:
+            self._rx_paused = True
+            self.transport.pause_reading()
+
+    def _resume_rx(self) -> None:
+        if self._rx_paused and self._fault is None:
+            self._rx_paused = False
+            self.transport.resume_reading()
+
+    def _fail(self, exc: Exception) -> None:
+        if self._fault is None:
+            self._fault = exc
+        self._wake()
+
+    def _wake(self) -> None:
+        waiter = self._waiter
+        if waiter is not None and not waiter.done():
+            waiter.set_result(None)
+
+    async def _wait(self) -> None:
+        """Until more bytes or a fault; one task reads a wire."""
+        if self._fault is not None:
+            raise self._fault
+        self._waiter = asyncio.get_running_loop().create_future()
+        try:
+            await self._waiter
+        finally:
+            self._waiter = None
+
+    async def readexactly(self, n: int) -> bytes:
+        """The handshake's reads: banner, ident and auth blobs."""
+        if n > RX_BUF:
+            raise ConnectionError_("handshake blob of %d bytes" % n)
+        while self._hi - self._lo < n:
+            if self._rx_paused:
+                self._compact()
+                self._resume_rx()
+            await self._wait()
+        out = bytes(self._view[self._lo:self._lo + n])
+        self._lo += n
+        return out
+
+    def start_frames(self) -> None:
+        """The handshake is over: whatever the peer sent behind its last
+        byte is frames."""
+        self._framed = True
+        self._cut()
+        self._resume_rx()
+
+    async def next_frame(self) -> tuple:
+        """(tag, crc, payload) in arrival order; a transport fault is
+        raised once every frame that arrived whole has been taken."""
+        while not self.frames:
+            await self._wait()
+        frame = self.frames.popleft()
+        if not self.frames:
+            self._resume_rx()
+        return frame
+
+    # -- send side -----------------------------------------------------------
+
+    def write(self, data) -> None:
+        self.transport.write(data)
+
+    async def drain(self) -> None:
+        if self.transport.is_closing():
+            await asyncio.sleep(0)      # let connection_lost be heard
+        while True:
+            if self._fault is not None:
+                raise self._fault
+            if not self._tx_paused:
+                return
+            waiter = asyncio.get_running_loop().create_future()
+            self._drain_waiters.append(waiter)
+            try:
+                await waiter
+            finally:
+                self._drain_waiters.remove(waiter)
+
+    def close(self) -> None:
+        self.transport.close()
+
+
+async def _write_frame(wire: _Wire, tag: int, payload: bytes) -> None:
     with span("msgr.write", bytes=len(payload)):
-        writer.write(_HDR.pack(tag, len(payload), zlib.crc32(payload)))
-        writer.write(payload)
-    await writer.drain()
-
-
-async def _read_frame(reader: asyncio.StreamReader) -> tuple[int, bytes]:
-    hdr = await reader.readexactly(_HDR.size)
-    tag, length, crc = _HDR.unpack(hdr)
-    payload = await reader.readexactly(length)
-    if tag == TAG_MSG:
-        with span("msgr.read_decode", bytes=length):
-            ok = zlib.crc32(payload) == crc
-    else:       # an ack or a close: a few bytes, not a span's worth
-        ok = zlib.crc32(payload) == crc
-    if not ok:
-        raise ConnectionError_("frame crc mismatch (tag %d)" % tag)
-    return tag, payload
+        wire.write(_HDR.pack(tag, len(payload), zlib.crc32(payload)))
+        wire.write(payload)
+    await wire.drain()
 
 
 class Connection:
@@ -269,8 +494,8 @@ class Connection:
         self._open = True
         self._transports: asyncio.Queue = asyncio.Queue()  # inbound only
         self._supervisor: asyncio.Task | None = None
-        self._writer: asyncio.StreamWriter | None = None
-        self._framer = None             # AEAD bound to live transport
+        self._wire: _Wire | None = None  # the live transport
+        self._framer = None             # AEAD bound to it
 
     # -- public API --------------------------------------------------------
 
@@ -306,7 +531,7 @@ class Connection:
             return
         self._open = False
         self.stats.mark_downs += 1
-        if self._writer is not None:
+        if self._wire is not None:
             # a partition must also block the graceful CLOSE: the peer
             # has to see a transport fault (dead host semantics, and
             # lossless replay stays armed), never an orderly shutdown
@@ -324,10 +549,10 @@ class Connection:
                     if self._framer is not None:
                         payload = self._framer.seal(
                             payload, bytes([TAG_CLOSE]))
-                    self._writer.write(_HDR.pack(
+                    self._wire.write(_HDR.pack(
                         TAG_CLOSE, len(payload), zlib.crc32(payload))
                         + payload)
-                self._writer.close()
+                self._wire.close()
             except Exception:
                 pass
         self._drain_transports()
@@ -340,8 +565,7 @@ class Connection:
         an abandoned open socket would wedge Server.wait_closed()."""
         while not self._transports.empty():
             try:
-                _r, w = self._transports.get_nowait()[:2]
-                w.close()
+                self._transports.get_nowait()[0].close()
             except Exception:
                 pass
 
@@ -365,21 +589,20 @@ class Connection:
                         rng=self.msgr._conn_rng(
                             "%s|backoff" % self.peer_addr))
         while self._open:
-            writer = None
+            wire = None
             try:
                 t0 = time.monotonic()
                 host, port = self.peer_addr.rsplit(":", 1)
-                reader, writer = await asyncio.open_connection(
-                    host, int(port))
-                framer, comp = await self.msgr._handshake_out(
-                    self, reader, writer)
+                _transport, wire = await asyncio.get_running_loop() \
+                    .create_connection(_Wire, host, int(port))
+                framer, comp = await self.msgr._handshake_out(self, wire)
             except asyncio.CancelledError:
-                if writer is not None:
-                    writer.close()
+                if wire is not None:
+                    wire.close()
                 return
             except Exception:
-                if writer is not None:
-                    writer.close()
+                if wire is not None:
+                    wire.close()
                 if self.policy.lossy:
                     await self._die()
                     return
@@ -392,7 +615,7 @@ class Connection:
             bo.reset()
             self.stats.backoff_s = 0.0
             self.stats.note_handshake(time.monotonic() - t0)
-            closed = await self._session(reader, writer, framer, comp)
+            closed = await self._session(wire, framer, comp)
             if closed or self.policy.lossy:
                 await self._die()
                 return
@@ -402,33 +625,32 @@ class Connection:
         try:
             while self._open:
                 try:
-                    reader, writer, framer, comp = \
-                        await self._transports.get()
+                    wire, framer, comp = await self._transports.get()
                 except asyncio.CancelledError:
                     return
-                closed = await self._session(reader, writer, framer,
-                                             comp)
+                closed = await self._session(wire, framer, comp)
                 if closed or self.policy.lossy:
                     await self._die()
                     return
         finally:
             self._drain_transports()
 
-    async def _session(self, reader, writer, framer=None,
+    async def _session(self, wire: _Wire, framer=None,
                        comp=None) -> bool:
         """Run one transport until it faults. Returns True when the
         peer closed gracefully (no replay should follow).  The AEAD
         framer is BOUND to this transport (derived from this
         handshake's nonces), so counters restart exactly when the
         peer's do."""
-        self._writer = writer
+        self._wire = wire
         self._framer = framer
         if self.policy.resend:
             self._replay_unacked()
+        wire.start_frames()
         rt = asyncio.ensure_future(
-            self._read_frames(reader, framer, comp))
+            self._read_frames(wire, framer, comp))
         wt = asyncio.ensure_future(
-            self._write_frames(writer, framer, comp))
+            self._write_frames(wire, framer, comp))
         try:
             done, pending = await asyncio.wait(
                 {rt, wt}, return_when=asyncio.FIRST_COMPLETED)
@@ -441,10 +663,10 @@ class Connection:
             t.cancel()
         results = await asyncio.gather(rt, wt, return_exceptions=True)
         try:
-            writer.close()
+            wire.close()
         except Exception:
             pass
-        self._writer = None
+        self._wire = None
         self._framer = None
         return any(isinstance(r, _PeerClosed) for r in results)
 
@@ -457,7 +679,7 @@ class Connection:
 
     # -- frame loops (subtasks of _session) ---------------------------------
 
-    async def _write_frames(self, writer, framer=None,
+    async def _write_frames(self, wire: _Wire, framer=None,
                             comp=None) -> None:
         async def emit(tag: int, payload: bytes) -> None:
             if comp is not None and tag == TAG_MSG:
@@ -475,7 +697,7 @@ class Connection:
                 # the tag rides as AEAD associated data: relabeled
                 # frames fail the MAC at the receiver
                 payload = framer.seal(payload, bytes([tag]))
-            await _write_frame(writer, tag, payload)
+            await _write_frame(wire, tag, payload)
 
         held: list[tuple[int, bytes]] = []  # reordered frames
         while True:
@@ -556,11 +778,22 @@ class Connection:
                 # and will be replayed on the next transport
                 return
 
-    async def _read_frames(self, reader, framer=None,
+    async def _read_frames(self, wire: _Wire, framer=None,
                            comp=None) -> None:
+        """The one task that takes this transport's frames, in arrival
+        order and one at a time: a dispatcher that awaits holds back
+        the frames behind it, and the wire stops reading."""
         while True:
             try:
-                tag, payload = await _read_frame(reader)
+                tag, crc, payload = await wire.next_frame()
+                if tag == TAG_MSG:
+                    with span("msgr.read_decode", bytes=len(payload)):
+                        ok = zlib.crc32(payload) == crc
+                else:   # an ack or a close: a few bytes, not a span's worth
+                    ok = zlib.crc32(payload) == crc
+                if not ok:
+                    raise ConnectionError_(
+                        "frame crc mismatch (tag %d)" % tag)
                 if framer is not None:
                     # every tag is authenticated, TAG_CLOSE included:
                     # an unverifiable close is a transport fault (so
@@ -693,10 +926,10 @@ class Messenger:
         # fire-and-forget tasks would be GC'd mid-await
         self._tasks: set = set()
         # every accepted transport, so shutdown can force-close ones
-        # still mid-handshake (weak: sessions own live writers)
+        # still mid-handshake (weak: sessions own live wires)
         import weakref
 
-        self._in_writers: weakref.WeakSet = weakref.WeakSet()
+        self._in_wires: weakref.WeakSet = weakref.WeakSet()
         self._shutting_down = False
         self.default_policy = Policy.lossy_client()
         self.peer_policy: dict[str, Policy] = {}    # by entity type
@@ -773,8 +1006,8 @@ class Messenger:
         return task
 
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> str:
-        self._server = await asyncio.start_server(
-            self._accept, host=host, port=port)
+        self._server = await asyncio.get_running_loop().create_server(
+            lambda: _Wire(self._accepted), host=host, port=port)
         sock = self._server.sockets[0]
         self.addr = "%s:%d" % sock.getsockname()[:2]
         return self.addr
@@ -796,7 +1029,7 @@ class Messenger:
                 break
             await asyncio.gather(*list(self._tasks),
                                  return_exceptions=True)
-        for w in list(self._in_writers):
+        for w in list(self._in_wires):
             try:
                 w.close()
             except Exception:
@@ -838,10 +1071,10 @@ class Messenger:
                 entity_hint: str = "") -> None:
         self.connect_to(addr, entity_hint).send(msg)
 
-    async def _handshake_out(self, conn, reader, writer) -> None:
+    async def _handshake_out(self, conn, wire: _Wire):
         from ..utils import denc
 
-        writer.write(BANNER)
+        wire.write(BANNER)
         # "ack" mirrors ProtocolV2's reconnect msg_seq exchange
         # (ProtocolV2.cc ReconnectFrame): each side tells the other how
         # much it already received, so replay covers only the gap
@@ -849,13 +1082,13 @@ class Messenger:
                              "addr": self.addr or "",
                              "ack": conn.in_seq,
                              "comp": self.compress_algos})
-        writer.write(struct.pack(">I", len(ident)) + ident)
-        await writer.drain()
-        banner = await reader.readexactly(len(BANNER))
+        wire.write(struct.pack(">I", len(ident)) + ident)
+        await wire.drain()
+        banner = await wire.readexactly(len(BANNER))
         if banner != BANNER:
             raise ConnectionError_("bad banner %r" % banner)
-        (n,) = struct.unpack(">I", await reader.readexactly(4))
-        peer_blob = await reader.readexactly(n)
+        (n,) = struct.unpack(">I", await wire.readexactly(4))
+        peer_blob = await wire.readexactly(n)
         peer = denc.decode(peer_blob)
         if self.fault_injector is not None and \
                 self.fault_injector.partitioned(
@@ -873,8 +1106,7 @@ class Messenger:
         # proven the cluster key — a forged ident must not be able to
         # drop queued lossless messages (mirror of the acceptor's
         # READ-ONLY session peek)
-        framer = await self._auth_out(reader, writer,
-                                      bind=ident + peer_blob)
+        framer = await self._auth_out(wire, bind=ident + peer_blob)
         conn.peer_entity = peer["entity"]
         nonce = peer.get("nonce", 0)
         if conn.peer_nonce >= 0 and conn.peer_nonce != nonce:
@@ -886,17 +1118,17 @@ class Messenger:
         return framer, comp
 
     @staticmethod
-    async def _read_auth_blob(reader, cap: int = 4096,
+    async def _read_auth_blob(wire: _Wire, cap: int = 4096,
                               timeout: float = 5.0) -> bytes:
         """Pre-auth reads are fully bounded (time AND size): this is
         attacker-reachable surface."""
         (n,) = struct.unpack(">I", await asyncio.wait_for(
-            reader.readexactly(4), timeout))
+            wire.readexactly(4), timeout))
         if n > cap:
             raise ConnectionError_("auth blob too large (%d)" % n)
-        return await asyncio.wait_for(reader.readexactly(n), timeout)
+        return await asyncio.wait_for(wire.readexactly(n), timeout)
 
-    async def _auth_out(self, reader, writer, bind: bytes = b""):
+    async def _auth_out(self, wire: _Wire, bind: bytes = b""):
         """Initiator side of the cluster-auth exchange (the cephx
         authorizer round): mutual HMAC challenge-response over the
         shared key, with the pre-auth ident transcript mixed into the
@@ -908,13 +1140,13 @@ class Messenger:
         from .auth import SecureFramer
         ncb, hello = self.auth.client_hello()
         blob = denc.encode(hello)
-        writer.write(struct.pack(">I", len(blob)) + blob)
-        await writer.drain()
-        challenge = denc.decode(await self._read_auth_blob(reader))
+        wire.write(struct.pack(">I", len(blob)) + blob)
+        await wire.drain()
+        challenge = denc.decode(await self._read_auth_blob(wire))
         nsb, reply = self.auth.client_verify(ncb, challenge, bind)
         blob = denc.encode(reply)
-        writer.write(struct.pack(">I", len(blob)) + blob)
-        await writer.drain()
+        wire.write(struct.pack(">I", len(blob)) + blob)
+        await wire.drain()
         if self.auth.secure:
             return SecureFramer(self.auth.session_key(ncb, nsb),
                                 initiator=True)
@@ -922,30 +1154,37 @@ class Messenger:
 
     # -- inbound -----------------------------------------------------------
 
-    async def _accept(self, reader: asyncio.StreamReader,
-                      writer: asyncio.StreamWriter) -> None:
+    def _accepted(self, wire: _Wire) -> None:
+        """A dialer connected: run its handshake in a task of its own.
+        Not ``spawn``: what a stranger's bytes raise in the handshake
+        is not this daemon's crash."""
+        task = asyncio.ensure_future(self._accept(wire))
+        self._tasks.add(task)
+        task.add_done_callback(self._tasks.discard)
+
+    async def _accept(self, wire: _Wire) -> None:
         """Inbound handler.  EVERY exit path must either hand the
-        transport to a Connection or close the writer: an abandoned
+        transport to a Connection or close the wire: an abandoned
         open socket makes Server.wait_closed() (which waits on all
         accepted connections in py3.12) hang shutdown forever."""
         handed_off = False
-        self._in_writers.add(writer)
+        self._in_wires.add(wire)
         try:
-            handed_off = await self._accept_inner(reader, writer)
+            handed_off = await self._accept_inner(wire)
         finally:
             if not handed_off:
                 # single close point: any refusal/exception path that
                 # did not hand the transport to a Connection closes it
                 # (an abandoned socket wedges Server.wait_closed)
                 try:
-                    writer.close()
+                    wire.close()
                 except Exception:
                     pass
 
-    async def _accept_inner(self, reader, writer) -> bool:
+    async def _accept_inner(self, wire: _Wire) -> bool:
         """Returns True only when the transport was handed off to a
         Connection; every other outcome is a refusal and _accept
-        closes the writer."""
+        closes the wire."""
         from ..utils import denc
 
         t0 = time.monotonic()
@@ -953,16 +1192,16 @@ class Messenger:
             # pre-auth reads are time-bounded: an idle dialer must not
             # pin an accept handler (and thus shutdown) indefinitely
             banner = await asyncio.wait_for(
-                reader.readexactly(len(BANNER)), 10.0)
+                wire.readexactly(len(BANNER)), 10.0)
             if banner != BANNER:
                 return False
-            peer_blob = await self._read_auth_blob(reader,
+            peer_blob = await self._read_auth_blob(wire,
                                                    timeout=10.0)
             peer = denc.decode(peer_blob)
             entity = peer["entity"]
-        except (ConnectionError, OSError, asyncio.IncompleteReadError,
-                asyncio.TimeoutError, ValueError, KeyError,
-                struct.error, RecursionError, ConnectionError_):
+        except (ConnectionError, OSError, asyncio.TimeoutError,
+                ValueError, KeyError, struct.error, RecursionError,
+                ConnectionError_):
             return False
         if self.fault_injector is not None and \
                 self.fault_injector.partitioned(self.entity, entity):
@@ -983,20 +1222,19 @@ class Messenger:
                    if existing is not None
                    and existing.peer_nonce == nonce else 0)
         try:
-            writer.write(BANNER)
+            wire.write(BANNER)
             ident = denc.encode({"entity": self.entity,
                                  "nonce": self.nonce,
                                  "addr": self.addr or "",
                                  "ack": ack_out,
                                  "comp": self.compress_algos})
-            writer.write(struct.pack(">I", len(ident)) + ident)
-            await writer.drain()
+            wire.write(struct.pack(">I", len(ident)) + ident)
+            await wire.drain()
         except (ConnectionError, OSError):
             return False
         comp = _pick_compressor(self.compress_algos,
                                 peer.get("comp") or [])
-        ok, framer = await self._auth_in(reader, writer,
-                                         bind=peer_blob + ident)
+        ok, framer = await self._auth_in(wire, bind=peer_blob + ident)
         if not ok:
             return False    # unauthenticated peer: refused
         if self._shutting_down:
@@ -1024,10 +1262,10 @@ class Messenger:
         if not conn.is_open:
             return False    # raced mark_down: nobody will run this
         conn.stats.note_handshake(time.monotonic() - t0)
-        conn._transports.put_nowait((reader, writer, framer, comp))
+        conn._transports.put_nowait((wire, framer, comp))
         return True
 
-    async def _auth_in(self, reader, writer, bind: bytes = b""):
+    async def _auth_in(self, wire: _Wire, bind: bytes = b""):
         """Acceptor side: refuse any peer that cannot prove the key
         (AuthRegistry's cephx_cluster_required gate).  Returns
         (authenticated, framer)."""
@@ -1036,20 +1274,19 @@ class Messenger:
         from ..utils import denc
         from .auth import AuthError, SecureFramer
         try:
-            hello = denc.decode(await self._read_auth_blob(reader))
+            hello = denc.decode(await self._read_auth_blob(wire))
             ncb, nsb, challenge = self.auth.server_challenge(
                 hello, bind)
             blob = denc.encode(challenge)
-            writer.write(struct.pack(">I", len(blob)) + blob)
-            await writer.drain()
+            wire.write(struct.pack(">I", len(blob)) + blob)
+            await wire.drain()
             self.auth.server_verify(ncb, nsb, denc.decode(
-                await self._read_auth_blob(reader)), bind)
+                await self._read_auth_blob(wire)), bind)
         except (AuthError, asyncio.TimeoutError, ConnectionError,
-                ConnectionError_, OSError,
-                asyncio.IncompleteReadError, ValueError, KeyError,
+                ConnectionError_, OSError, ValueError, KeyError,
                 struct.error, RecursionError):
             try:
-                writer.close()
+                wire.close()
             except Exception:
                 pass
             return False, None

@@ -54,6 +54,10 @@ SPANS: dict[str, tuple[str, str]] = {
     "msgr.encode": (HOST, "Connection.send: message -> wire bytes"),
     "msgr.write": (HOST, "frame header, crc and payload handed to the "
                    "transport; bytes of payload"),
+    "msgr.recv": (HOST, "_Wire.buffer_updated: what one recv_into "
+                  "delivered cut into frames (the recv_into itself runs "
+                  "inside asyncio, before the span); bytes arrived, "
+                  "direct of them landed in a large frame's own buffer"),
     "msgr.read_decode": (HOST, "a message frame's crc check, then wire "
                          "bytes -> message; bytes of payload"),
     "msgr.dispatch": (HOST, "a dispatcher's synchronous ms_dispatch; the "

@@ -25,7 +25,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the bulk remap's, a timer's (heartbeat, advance_pgs, gc) or a fault's
 WRITE_PATH = (
     "client.calc_target", "client.submit", "client.send_op",
-    "client.handle_reply", "msgr.encode", "msgr.write",
+    "client.handle_reply", "msgr.encode", "msgr.write", "msgr.recv",
     "msgr.read_decode", "msgr.dispatch", "osd.dequeue", "osd.handle_op",
     "osd.ec.op", "osd.ec.submit", "osd.ec.sub_write", "osd.ec.sub_reply",
     "ec.prepare", "ec.stage", "ec.dispatch", "ec.deliver", "ec.collect",
@@ -346,6 +346,16 @@ def test_traced_write_retires_the_clients_op(traced_write):
     sizes = [ev[3]["bytes"] for evs in traced_write.values() for ev in evs
              if ev[0] == "msgr.write"]
     assert sizes and max(sizes) > 4096     # a shard's frame
+
+
+def test_traced_write_counts_what_each_read_brought(traced_write):
+    """msgr.recv: bytes of one recv_into, and how many of them went
+    straight into a large frame's own buffer (none of a 4 KiB shard's)."""
+    recvs = [ev[3] for evs in traced_write.values() for ev in evs
+             if ev[0] == "msgr.recv"]
+    assert recvs and all(s["bytes"] >= s["direct"] >= 0 for s in recvs)
+    assert sum(s["bytes"] for s in recvs) > 8192 * 3 // 2
+    assert sum(s["direct"] for s in recvs) == 0
 
 
 # -- traced reads with one data holder stopped under noout -------------------
