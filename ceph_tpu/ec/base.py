@@ -317,18 +317,20 @@ class ErasureCode(ErasureCodeInterface):
         if (nbytes == 0 or not device_offload_enabled()
                 or not DeviceRuntime.get().chip_available(chip)):
             return self.parity_delta(deltas)
-        pad = (-nbytes) % word
-        k = self.get_data_chunk_count()
-        arr = np.zeros((k, (nbytes + pad) // word),
-                       dtype=self._word_dtype(w))
-        for j, d in deltas.items():
-            arr[int(j)] = np.frombuffer(
-                bytes(d) + b"\0" * pad if pad else d,
-                dtype=self._word_dtype(w))
+        with span("ec.delta_prepare"):
+            pad = (-nbytes) % word
+            k = self.get_data_chunk_count()
+            arr = np.zeros((k, (nbytes + pad) // word),
+                           dtype=self._word_dtype(w))
+            for j, d in deltas.items():
+                arr[int(j)] = np.frombuffer(
+                    bytes(d) + b"\0" * pad if pad else d,
+                    dtype=self._word_dtype(w))
         parity = await DeviceBatcher.get().encode(
             matrix, w, arr, klass=klass or K_CLIENT_EC,
             on_ticket=on_ticket, chip=chip, tenant=tenant)
-        return {i: parity[i].tobytes() for i in range(len(matrix))}
+        with span("ec.delta_collect"):
+            return {i: parity[i].tobytes() for i in range(len(matrix))}
 
     async def decode_async(self, want_to_read: set[int],
                            chunks: Mapping[int, bytes],

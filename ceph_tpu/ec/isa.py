@@ -53,6 +53,11 @@ class ErasureCodeIsa(ErasureCode):
     CAUCHY = "cauchy"
     DEFAULT_K = 7
     DEFAULT_M = 3
+    # isa-l codes over GF(2^8) and nothing else: the word size the OSD's
+    # parity-delta path asks a matrix codec for (ecbackend
+    # _try_delta_write; without it every partial write on an isa pool
+    # took the whole-object path)
+    w = 8
 
     def __init__(self, technique: str = VANDERMONDE,
                  cache: IsaTableCache | None = None):

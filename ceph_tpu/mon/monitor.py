@@ -39,6 +39,7 @@ from ..msg.messages import (MMonCommand, MMonCommandAck, MMonElection,
                             MOSDAlive, MOSDBoot, MOSDFailure,
                             MOSDMapMsg, MOSDOp)
 from ..osd.osdmap import (CEPH_OSD_OUT, CEPH_OSDMAP_NOOUT, CLUSTER_FLAGS,
+                          FLAG_EC_OVERWRITES,
                           OSD_EXISTS, OSD_UP,
                           POOL_TYPE_ERASURE, POOL_TYPE_REPLICATED,
                           Incremental, OSDMap, PGPool)
@@ -1901,6 +1902,21 @@ class Monitor:
             pool.erasure_code_profile = str(val)
         elif key == "crush_rule":
             pool.crush_rule = int(val)
+        elif key == "allow_ec_overwrites":
+            # OSDMonitor::prepare_command_pool_set: erasure pools
+            # only, and once set it stays (objects written through
+            # partial overwrites cannot go back)
+            if not pool.is_erasure():
+                raise ValueError("ec overwrites can only be enabled "
+                                 "for an erasure coded pool")
+            val = str(val).lower()
+            if val not in ("true", "false"):
+                raise ValueError("allow_ec_overwrites: true|false")
+            if val == "true":
+                pool.flags |= FLAG_EC_OVERWRITES
+            elif pool.allows_ecoverwrites():
+                raise ValueError("ec overwrites cannot be disabled "
+                                 "once enabled")
         elif key == "compression_mode":
             if val not in ("none", "force"):
                 raise ValueError("compression_mode: none|force")

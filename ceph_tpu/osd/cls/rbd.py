@@ -18,6 +18,7 @@ SIZE_XATTR = "rbd.size"
 LAYOUT_XATTR = "rbd.layout"
 SNAPS_XATTR = "rbd.snaps"
 PARENT_XATTR = "rbd.parent"     # denc {"image","snapid","overlap"}
+DATA_POOL_XATTR = "rbd.data_pool"   # id of the pool of rbd_data.*
 _CHILD_PREFIX = b"child."       # omap child.<snapid>.<name> on parent
 
 
@@ -34,6 +35,8 @@ def create(ctx: MethodContext, inp: dict) -> dict:
     ctx.setxattr(SIZE_XATTR, b"%d" % size)
     ctx.setxattr(LAYOUT_XATTR, bytes(layout))
     ctx.setxattr(SNAPS_XATTR, denc.encode({}))
+    if inp.get("data_pool") is not None:
+        ctx.setxattr(DATA_POOL_XATTR, b"%d" % int(inp["data_pool"]))
     return {}
 
 
@@ -45,6 +48,9 @@ def get_metadata(ctx: MethodContext, inp: dict) -> dict:
     snaps_blob = ctx.getxattr(SNAPS_XATTR)
     snaps = denc.decode(snaps_blob) if snaps_blob else {}
     out = {"size": int(size), "layout": layout, "snaps": snaps}
+    data_pool = ctx.getxattr(DATA_POOL_XATTR)
+    if data_pool is not None:
+        out["data_pool"] = int(data_pool)
     parent = ctx.getxattr(PARENT_XATTR)
     if parent:
         out["parent"] = denc.decode(parent)

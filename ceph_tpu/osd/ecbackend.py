@@ -58,6 +58,15 @@ HINFO_XATTR = "ec_hinfo"   # crc32 of every shard, comma-joined (the
                            # identifies a rotted shard by its crc)
 
 
+# why a partial write left the parity-delta path for the whole-object
+# one: the index is the `why` of the mark osd.ec.rmw_fallback
+RMW_FALLBACK_WHY = ("growth", "degraded member", "stale shard",
+                    "big span", "no hinfo", "ragged chunk",
+                    "short old read", "no matrix codec")
+(_WHY_GROWTH, _WHY_DEGRADED, _WHY_STALE, _WHY_BIG, _WHY_NO_HINFO,
+ _WHY_RAGGED, _WHY_SHORT_READ, _WHY_CODEC) = range(len(RMW_FALLBACK_WHY))
+
+
 def hinfo_bytes(shards: dict[int, bytes]) -> bytes:
     import zlib
 
@@ -134,6 +143,11 @@ class ECPGBackend:
         # survivors (a degraded read), and the bytes they returned
         self.reconstructed_reads = 0
         self.reconstructed_read_bytes = 0
+        # partial writes the parity-delta path committed, the bytes
+        # they overwrote, and those that fell to the whole-object path
+        self.delta_writes = 0
+        self.delta_write_bytes = 0
+        self.rmw_fallbacks = 0
         # repair-traffic accounting (per codec plugin): survivor
         # bytes read through minimum_to_decode's minimal shard sets
         # vs rebuilt bytes pushed — shipped in MMgrReport osd_stats
@@ -261,7 +275,15 @@ class ECPGBackend:
 
     async def handle_op(self, pg: PG, conn, msg) -> None:
         """Primary-side execution of one client op list."""
+        # a partial write's wait for the object's lock is a stage of
+        # its own: overlapping RMW cycles of one object run one at a
+        # time here (upstream pipelines them through its extent cache)
+        partial = any(o["op"] == "write" for o in msg.ops)
+        if partial:
+            self.osd._op_event(msg, "ec_delta_lock_wait")
         async with self.oid_lock(pg, msg.oid):
+            if partial:
+                self.osd._op_event(msg, "ec_delta_locked")
             # dup re-check under the oid lock: a resend that queued
             # behind the original acquires the lock after the first
             # execution journaled its reply
@@ -398,6 +420,13 @@ class ECPGBackend:
         # parity-delta RMW (bytes moved proportional to the touched
         # range, not the object — ECBackend start_rmw's role)
         self.osd._op_event(msg, "ec_write_started")
+        if not self.osd.osdmap.pools[pg.pool_id].allows_ecoverwrites() \
+                and await self._overwrites(pg, msg):
+            conn.send(MOSDOpReply(
+                tid=msg.tid, result=-95,
+                outs=[{"error": "pool lacks allow_ec_overwrites"}],
+                epoch=epoch, version=0))
+            return
         wbytes = sum(len(o.get("data") or b"") for o in msg.ops
                      if isinstance(o, dict))
         self.osd.note_op_size(wbytes)
@@ -506,6 +535,33 @@ class ECPGBackend:
             self.osd._op_finish(msg, "ec_write_done")
 
     # -- write path --------------------------------------------------------
+
+    async def _overwrites(self, pg: PG, msg) -> bool:
+        """Whether the op list changes bytes the object already has: a
+        truncate, or a write that does not start at the object's end
+        (an append).  Asked only on a pool without allow_ec_overwrites
+        (pg_pool_t::FLAG_EC_OVERWRITES), which answers such an op
+        -EOPNOTSUPP; the size comes from a shard's attrs, no object
+        is read."""
+        size = None
+        for op in msg.ops:
+            name = op["op"]
+            if name == "truncate":
+                return True
+            if name == "writefull":
+                size = len(op["data"])
+            elif name == "delete":
+                size = 0
+            elif name == "write":
+                if size is None:
+                    exists, white = await self._head_state(pg, msg.oid)
+                    size = (int(await self._fetch_xattr(
+                        pg, msg.oid, SIZE_XATTR) or 0)
+                        if exists and not white else 0)
+                if int(op.get("offset", 0)) != size:
+                    return True
+                size += len(op["data"])
+        return False
 
     def _chip(self) -> int | None:
         """This daemon's mesh-chip index (OSD->chip affinity): every
@@ -785,6 +841,14 @@ class ECPGBackend:
             snapset_b = snapmod.snapset_bytes(ss)
         return clone_to, snapset_b, sna_snaps, whiteout
 
+    def _rmw_fallback(self, why: int) -> None:
+        """A partial write leaves the parity-delta path for the
+        whole-object one: counted, marked with the reason's index in
+        RMW_FALLBACK_WHY.  Returns what _try_delta_write then returns."""
+        self.rmw_fallbacks += 1
+        mark("osd.ec.rmw_fallback", why=why)
+        return None
+
     async def _try_delta_write(self, pg: PG, msg):
         """Chunk-aware partial overwrite: parity-delta RMW
         (ECBackend::start_rmw + ECUtil stripe math, ECBackend.cc:1898,
@@ -815,80 +879,86 @@ class ECPGBackend:
         The per-object oid_lock plays the ExtentCache role of
         serializing overlapping RMW cycles."""
         import zlib
-        pool = self.osd.osdmap.pools[pg.pool_id]
-        codec = self.codec(pool)
-        matrix = getattr(codec, "matrix", None)
-        if (not matrix or getattr(codec, "w", 0) not in (8, 16, 32)
-                or codec.get_chunk_mapping()):
-            return None
-        # w=16/32: parity changes at word granularity (GF products
-        # mix bits across the word), so column intervals align to the
-        # word boundary below; the data-chunk writes themselves stay
-        # byte-granular
-        word = codec.w // 8
-        k = codec.get_data_chunk_count()
-        n = codec.get_chunk_count()
-        m = n - k
-        if msg.oid in pg.missing or any(
-                msg.oid in pm for pm in pg.peer_missing.values()):
-            # a stale shard exists somewhere: the delta path cannot
-            # detect it (it never reads untouched shards) and must not
-            # re-stamp versions over old bytes — whole-object RMW
-            # rewrites every shard and heals instead
-            return None
-        local = self._local_shard(pg, hobject_t(msg.oid))
-        if local is None:
-            return None                      # primary degraded: RMW
-        _j, _buf, size, ver, lattrs = local
-        from . import snaps as snapmod
-        if lattrs.get(snapmod.WHITEOUT_ATTR) == b"1":
-            return None
-        hinfo_raw = lattrs.get(HINFO_XATTR)
-        if hinfo_raw is None:
-            return None
-        old_crcs = [int(x) for x in hinfo_raw.split(b",")]
-        if len(old_crcs) != n:
-            return None
-        writes = []
-        total = 0
-        for op in msg.ops:
-            off = int(op.get("offset", 0))
-            data = bytes(op["data"])
-            if off < 0 or off + len(data) > size or not data:
-                return None                  # growth/degenerate: RMW
-            writes.append((off, data))
-            total += len(data)
-        if total * 4 > size:
-            return None                      # big span: full RMW wins
-        cs = codec.get_chunk_size(size)
-        if cs % word:
-            return None          # word-ragged chunk layout: full RMW
-        # per-chunk parts: {j: [(c0, new_bytes), ...]} in column space
-        per_chunk: dict[int, list] = {}
-        for off, data in writes:
-            pos = off
-            while pos < off + len(data):
-                j = pos // cs
-                c0 = pos % cs
-                take = min(cs - c0, off + len(data) - pos)
-                per_chunk.setdefault(j, []).append(
-                    (c0, data[pos - off:pos - off + take]))
-                pos += take
-        # merged column intervals (parity changes exactly there),
-        # floored/ceiled to the codec's word boundary — a sub-word
-        # overwrite dirties its whole containing parity word; a
-        # boundary-crossing write yields ranges at OPPOSITE chunk ends
-        # — they must stay separate reads, never one covering span
-        raw_ivs = sorted(((c0 // word) * word,
-                          min(cs, -(-(c0 + len(d)) // word) * word))
-                         for parts in per_chunk.values()
-                         for c0, d in parts)
-        ivs: list[list[int]] = []
-        for a, b in raw_ivs:
-            if ivs and a <= ivs[-1][1]:
-                ivs[-1][1] = max(ivs[-1][1], b)
-            else:
-                ivs.append([a, b])
+        nbytes = sum(len(o.get("data") or b"") for o in msg.ops)
+        with span("osd.ec.delta_plan", bytes=nbytes):
+            pool = self.osd.osdmap.pools[pg.pool_id]
+            codec = self.codec(pool)
+            matrix = getattr(codec, "matrix", None)
+            if (not matrix or getattr(codec, "w", 0) not in (8, 16, 32)
+                    or codec.get_chunk_mapping()):
+                return self._rmw_fallback(_WHY_CODEC)
+            # w=16/32: parity changes at word granularity (GF products
+            # mix bits across the word), so column intervals align to the
+            # word boundary below; the data-chunk writes themselves stay
+            # byte-granular
+            word = codec.w // 8
+            k = codec.get_data_chunk_count()
+            n = codec.get_chunk_count()
+            m = n - k
+            if msg.oid in pg.missing or any(
+                    msg.oid in pm for pm in pg.peer_missing.values()):
+                # a stale shard exists somewhere: the delta path cannot
+                # detect it (it never reads untouched shards) and must not
+                # re-stamp versions over old bytes — whole-object RMW
+                # rewrites every shard and heals instead
+                return self._rmw_fallback(_WHY_STALE)
+            local = self._local_shard(pg, hobject_t(msg.oid))
+            if local is None:
+                # primary degraded, or no object yet: RMW
+                return self._rmw_fallback(_WHY_DEGRADED)
+            _j, _buf, size, ver, lattrs = local
+            from . import snaps as snapmod
+            if lattrs.get(snapmod.WHITEOUT_ATTR) == b"1":
+                return self._rmw_fallback(_WHY_GROWTH)
+            hinfo_raw = lattrs.get(HINFO_XATTR)
+            if hinfo_raw is None:
+                return self._rmw_fallback(_WHY_NO_HINFO)
+            old_crcs = [int(x) for x in hinfo_raw.split(b",")]
+            if len(old_crcs) != n:
+                return self._rmw_fallback(_WHY_NO_HINFO)
+            writes = []
+            total = 0
+            for op in msg.ops:
+                off = int(op.get("offset", 0))
+                data = bytes(op["data"])
+                if off < 0 or off + len(data) > size or not data:
+                    # growth/degenerate: RMW
+                    return self._rmw_fallback(_WHY_GROWTH)
+                writes.append((off, data))
+                total += len(data)
+            if total * 4 > size:
+                # big span: full RMW wins
+                return self._rmw_fallback(_WHY_BIG)
+            cs = codec.get_chunk_size(size)
+            if cs % word:
+                # word-ragged chunk layout: full RMW
+                return self._rmw_fallback(_WHY_RAGGED)
+            # per-chunk parts: {j: [(c0, new_bytes), ...]} in column space
+            per_chunk: dict[int, list] = {}
+            for off, data in writes:
+                pos = off
+                while pos < off + len(data):
+                    j = pos // cs
+                    c0 = pos % cs
+                    take = min(cs - c0, off + len(data) - pos)
+                    per_chunk.setdefault(j, []).append(
+                        (c0, data[pos - off:pos - off + take]))
+                    pos += take
+            # merged column intervals (parity changes exactly there),
+            # floored/ceiled to the codec's word boundary — a sub-word
+            # overwrite dirties its whole containing parity word; a
+            # boundary-crossing write yields ranges at OPPOSITE chunk ends
+            # — they must stay separate reads, never one covering span
+            raw_ivs = sorted(((c0 // word) * word,
+                              min(cs, -(-(c0 + len(d)) // word) * word))
+                             for parts in per_chunk.values()
+                             for c0, d in parts)
+            ivs: list[list[int]] = []
+            for a, b in raw_ivs:
+                if ivs and a <= ivs[-1][1]:
+                    ivs[-1][1] = max(ivs[-1][1], b)
+                else:
+                    ivs.append([a, b])
 
         async def ranged(j, a, b):
             """Old shard bytes [a,b) of position j, or None."""
@@ -923,115 +993,121 @@ class ECPGBackend:
             for a, b in ivs:
                 keys.append(("p", i, a))
                 coros.append(ranged(i, a, b))
+        self.osd._op_event(msg, "ec_delta_read_sent")
         results = await asyncio.gather(*coros)
-        old_part: dict[tuple, bytes] = {}
-        old_par: dict[tuple, bytes] = {}
-        for (kind, x, y), ob in zip(keys, results):
-            if ob is None:
-                return None
-            if kind == "d":
-                old_part[(x, y)] = ob
-            else:
-                old_par[(x, y)] = ob
-        # deltas + incremental crcs (crc32 linearity over GF(2))
-        import numpy as _np
-        zeros_cs_crc = zlib.crc32(bytes(cs)) & 0xFFFFFFFF
-        new_crcs = list(old_crcs)
-        delta_part: dict[tuple, bytes] = {}
-        for j, parts in per_chunk.items():
-            dpad = bytearray(cs)
-            for c0, d in parts:
-                ob = old_part[(j, c0)]
-                delta = bytes(x ^ y for x, y in zip(ob, d))
-                delta_part[(j, c0)] = delta
-                dpad[c0:c0 + len(delta)] = delta
-            new_crcs[j] = (old_crcs[j] ^ zlib.crc32(bytes(dpad))
-                           ^ zeros_cs_crc) & 0xFFFFFFFF
-        # parity deltas: one device-batched GF product per interval
-        # (codec.delta_async — concurrent partial writes across
-        # PGs/objects batch their coefficient-column products into one
-        # dispatch on this OSD's chip, host numpy under
-        # DeviceBusy/poison), intervals issued concurrently so they
-        # share a flush; the op's ticket feeds op_ec_device_dispatch
-        top = getattr(msg, "_top", None)
-
-        def _iv_deltas(a: int, b: int) -> dict[int, bytes]:
-            out: dict[int, bytes] = {}
+        self.osd._op_event(msg, "ec_delta_read_done")
+        with span("osd.ec.delta_xor", bytes=nbytes):
+            old_part: dict[tuple, bytes] = {}
+            old_par: dict[tuple, bytes] = {}
+            for (kind, x, y), ob in zip(keys, results):
+                if ob is None:
+                    return self._rmw_fallback(_WHY_SHORT_READ)
+                if kind == "d":
+                    old_part[(x, y)] = ob
+                else:
+                    old_par[(x, y)] = ob
+            # deltas + incremental crcs (crc32 linearity over GF(2))
+            import numpy as _np
+            zeros_cs_crc = zlib.crc32(bytes(cs)) & 0xFFFFFFFF
+            new_crcs = list(old_crcs)
+            delta_part: dict[tuple, bytes] = {}
             for j, parts in per_chunk.items():
-                row = bytearray(b - a)
-                touched = False
+                dpad = bytearray(cs)
                 for c0, d in parts:
-                    if c0 >= b or c0 + len(d) <= a:
-                        continue
-                    dp = delta_part[(j, c0)]
-                    row[c0 - a:c0 - a + len(dp)] = dp
-                    touched = True
-                if touched:
-                    out[j] = bytes(row)
-            return out
-
-        pdeltas = await asyncio.gather(*[
-            codec.delta_async(_iv_deltas(a, b),
-                              on_ticket=self._on_dispatch_ticket(top),
-                              chip=self._chip(),
-                              tenant=(top.tenant if top is not None
-                                      else None))
-            for a, b in ivs])
-        new_par: dict[tuple, bytes] = {}
-        for i in range(m):
-            dpad = bytearray(cs)
-            for (a, b), pd in zip(ivs, pdeltas):
-                acc = _np.frombuffer(pd[i], _np.uint8)
-                ob = _np.frombuffer(old_par[(k + i, a)], _np.uint8)
-                new_par[(k + i, a)] = (ob[:b - a] ^ acc).tobytes()
-                dpad[a:b] = pd[i]
-            new_crcs[k + i] = (old_crcs[k + i]
-                               ^ zlib.crc32(bytes(dpad))
+                    ob = old_part[(j, c0)]
+                    delta = bytes(x ^ y for x, y in zip(ob, d))
+                    delta_part[(j, c0)] = delta
+                    dpad[c0:c0 + len(delta)] = delta
+                new_crcs[j] = (old_crcs[j] ^ zlib.crc32(bytes(dpad))
                                ^ zeros_cs_crc) & 0xFFFFFFFF
+            # parity deltas: one device-batched GF product per interval
+            # (codec.delta_async — concurrent partial writes across
+            # PGs/objects batch their coefficient-column products into one
+            # dispatch on this OSD's chip, host numpy under
+            # DeviceBusy/poison), intervals issued concurrently so they
+            # share a flush; the op's ticket feeds op_ec_device_dispatch
+            top = getattr(msg, "_top", None)
+
+            def _iv_deltas(a: int, b: int) -> dict[int, bytes]:
+                out: dict[int, bytes] = {}
+                for j, parts in per_chunk.items():
+                    row = bytearray(b - a)
+                    touched = False
+                    for c0, d in parts:
+                        if c0 >= b or c0 + len(d) <= a:
+                            continue
+                        dp = delta_part[(j, c0)]
+                        row[c0 - a:c0 - a + len(dp)] = dp
+                        touched = True
+                    if touched:
+                        out[j] = bytes(row)
+                return out
+
+            dcoros = [
+                codec.delta_async(_iv_deltas(a, b),
+                                  on_ticket=self._on_dispatch_ticket(top),
+                                  chip=self._chip(),
+                                  tenant=(top.tenant if top is not None
+                                          else None))
+                for a, b in ivs]
+        pdeltas = await asyncio.gather(*dcoros)
+        with span("osd.ec.delta_apply", shards=m):
+            new_par: dict[tuple, bytes] = {}
+            for i in range(m):
+                dpad = bytearray(cs)
+                for (a, b), pd in zip(ivs, pdeltas):
+                    acc = _np.frombuffer(pd[i], _np.uint8)
+                    ob = _np.frombuffer(old_par[(k + i, a)], _np.uint8)
+                    new_par[(k + i, a)] = (ob[:b - a] ^ acc).tobytes()
+                    dpad[a:b] = pd[i]
+                new_crcs[k + i] = (old_crcs[k + i]
+                                   ^ zlib.crc32(bytes(dpad))
+                                   ^ zeros_cs_crc) & 0xFFFFFFFF
         # snapshot bookkeeping shares the write path's semantics
         clone_to, snapset_b, sna_snaps, _wo = \
             await self._prepare_snapc(pg, msg)
-        epoch = self.osd.osdmap.epoch
-        version = (epoch, pg.info.last_update[1] + 1)
-        entry = LogEntry(LogEntry.MODIFY, msg.oid, version,
-                         pg.info.last_update)
-        pg.info.last_update = version
-        pg.log.append(entry)
-        ho = hobject_t(msg.oid)
-        hinfo_b = b",".join(b"%d" % c for c in new_crcs)
-        from . import snaps as _snapmod
-        from .pg import PGMETA_OID
-        txns: dict[int, Transaction] = {}
-        for j in range(min(n, len(pg.acting))):
-            t = Transaction()
-            if clone_to is not None:
-                t.clone(pg.cid, ho,
-                        hobject_t(msg.oid, snap=clone_to))
-            if j in per_chunk:
-                for c0, d in per_chunk[j]:
-                    t.write(pg.cid, ho, c0, len(d), bytes(d))
-            elif j >= k:
-                for a, b in ivs:
-                    t.write(pg.cid, ho, a, b - a,
-                            new_par[(j, a)])
-            t.setattr(pg.cid, ho, VER_XATTR, _ver_bytes(version))
-            t.setattr(pg.cid, ho, HINFO_XATTR, hinfo_b)
-            if snapset_b is not None:
-                t.setattr(pg.cid, ho, _snapmod.SNAPSET_ATTR,
-                          snapset_b)
-                t.setattr(pg.cid, ho, _snapmod.WHITEOUT_ATTR, b"0")
-            for s in (sna_snaps or ()):
-                t.omap_setkeys(pg.cid, PGMETA_OID,
-                               {_snapmod.sna_key(s, msg.oid): b"1"})
-            txns[j] = t
-        outs = [{} for _ in msg.ops]
-        # the reqid dup journal rides EVERY shard txn (replicated, not
-        # primary-local like the full-write path's own-txn journal):
-        # after a primary loss the promoted replica answers a client
-        # resend from its own store
-        pg.record_reqid(list(txns.values()), msg.src, msg.tid, 0,
-                        outs, version[1])
-        self.osd._op_event(msg, "ec_delta_rmw")
+        with span("osd.ec.delta_apply", shards=min(n, len(pg.acting))):
+            epoch = self.osd.osdmap.epoch
+            version = (epoch, pg.info.last_update[1] + 1)
+            entry = LogEntry(LogEntry.MODIFY, msg.oid, version,
+                             pg.info.last_update)
+            pg.info.last_update = version
+            pg.log.append(entry)
+            ho = hobject_t(msg.oid)
+            hinfo_b = b",".join(b"%d" % c for c in new_crcs)
+            from . import snaps as _snapmod
+            from .pg import PGMETA_OID
+            txns: dict[int, Transaction] = {}
+            for j in range(min(n, len(pg.acting))):
+                t = Transaction()
+                if clone_to is not None:
+                    t.clone(pg.cid, ho,
+                            hobject_t(msg.oid, snap=clone_to))
+                if j in per_chunk:
+                    for c0, d in per_chunk[j]:
+                        t.write(pg.cid, ho, c0, len(d), bytes(d))
+                elif j >= k:
+                    for a, b in ivs:
+                        t.write(pg.cid, ho, a, b - a,
+                                new_par[(j, a)])
+                t.setattr(pg.cid, ho, VER_XATTR, _ver_bytes(version))
+                t.setattr(pg.cid, ho, HINFO_XATTR, hinfo_b)
+                if snapset_b is not None:
+                    t.setattr(pg.cid, ho, _snapmod.SNAPSET_ATTR,
+                              snapset_b)
+                    t.setattr(pg.cid, ho, _snapmod.WHITEOUT_ATTR, b"0")
+                for s in (sna_snaps or ()):
+                    t.omap_setkeys(pg.cid, PGMETA_OID,
+                                   {_snapmod.sna_key(s, msg.oid): b"1"})
+                txns[j] = t
+            outs = [{} for _ in msg.ops]
+            # the reqid dup journal rides EVERY shard txn (replicated, not
+            # primary-local like the full-write path's own-txn journal):
+            # after a primary loss the promoted replica answers a client
+            # resend from its own store
+            pg.record_reqid(list(txns.values()), msg.src, msg.tid, 0,
+                            outs, version[1])
+            self.osd._op_event(msg, "ec_delta_rmw")
         ok = await self._commit_shard_txns(pg, msg.oid, entry, txns,
                                            top=top)
         if not ok:
@@ -1039,6 +1115,11 @@ class ECPGBackend:
             # in-place overwrite re-executes idempotently), not be
             # answered 0 from the pre-journaled row
             pg.forget_reqid(msg.src, msg.tid)
+        else:
+            self.delta_writes += 1
+            self.delta_write_bytes += nbytes
+            mark("osd.ec.delta_write", bytes=nbytes,
+                 chunks=len(per_chunk), intervals=len(ivs))
         # the log entry is appended either way: do NOT fall back to the
         # whole-object path after a commit attempt (same durability
         # contract as submit_write: ok = >= k shards persisted)

@@ -38,6 +38,11 @@ POOL_TYPE_REPLICATED = 1
 POOL_TYPE_ERASURE = 3
 
 FLAG_HASHPSPOOL = 1
+# pg_pool_t::FLAG_EC_OVERWRITES (`ceph osd pool set <pool>
+# allow_ec_overwrites true`, doc/rados/operations/erasure-code.rst,
+# "Erasure Coding with Overwrites"): an erasure pool takes partial
+# overwrites and truncates only with it, and it is never cleared
+FLAG_EC_OVERWRITES = 1 << 17
 
 # cluster-wide flags (OSDMap::flags, `ceph osd set <key>`); the one an
 # operator sets here is noout: a down osd stays in, CRUSH keeps its
@@ -129,6 +134,9 @@ class PGPool:
 
     def is_erasure(self) -> bool:
         return self.type == POOL_TYPE_ERASURE
+
+    def allows_ecoverwrites(self) -> bool:
+        return bool(self.flags & FLAG_EC_OVERWRITES)
 
     def can_shift_osds(self) -> bool:
         # replicated sets compact; erasure sets are positional

@@ -7,6 +7,10 @@ omap plays) plus data objects `rbd_data.<name>.<objectno>` addressed
 by Striper::file_to_extents — the same object-map shape librbd uses
 (`rbd_data.<image id>.<object no>`).  Reads of unwritten extents
 return zeros (sparse images); writes allocate objects on demand.
+`rbd create --data-pool`: the header and the directory stay in the
+pool the RBD handle was made on and `rbd_data.*` go to the data pool,
+which is how an image lives on an erasure-coded pool (an EC pool takes
+no object-class call; it needs `allow_ec_overwrites`).
 
 Surface: RBD.create/remove/list/open -> Image.read/write/size/resize +
 snapshots (snap_create/remove/list/set/rollback on RADOS selfmanaged
@@ -19,6 +23,7 @@ journaling / mirroring remain out of this slice."""
 from __future__ import annotations
 
 from ..client.striper import FileLayout, file_to_extents
+from ..trace.span import span
 from ..utils import denc
 
 HEADER_PREFIX = "rbd_header."
@@ -39,21 +44,40 @@ class RBD:
     def __init__(self, ioctx):
         self.io = ioctx
 
+    def _data_pool_id(self, data_pool: str) -> int:
+        """The id of the pool that will hold `rbd_data.*`; an erasure
+        pool has to allow overwrites (librbd: "data pool does not
+        support overwrites")."""
+        client = self.io.client
+        try:
+            pid = client.io_ctx(data_pool).pool_id
+        except ValueError:
+            raise RBDError("no data pool %r" % data_pool) from None
+        pool = client.osdmap.pools[pid]
+        if pool.is_erasure() and not pool.allows_ecoverwrites():
+            raise RBDError("data pool %r does not support overwrites: "
+                           "set allow_ec_overwrites" % data_pool)
+        return pid
+
     async def create(self, name: str, size: int,
-                     layout: FileLayout | None = None) -> None:
+                     layout: FileLayout | None = None,
+                     data_pool: str | None = None) -> None:
         """Header + directory registration ride cls_rbd methods: the
         exists check happens INSIDE the OSD, so two racing creates
-        cannot both win (the race src/cls/rbd exists to close)."""
+        cannot both win (the race src/cls/rbd exists to close).
+        `data_pool` names the pool of the data objects (`rbd create
+        --data-pool`); the header records its id."""
         from ..client.rados import RadosError
 
         layout = layout or FileLayout(stripe_unit=1 << 22,
                                       stripe_count=1,
                                       object_size=1 << 22)
         hdr = HEADER_PREFIX + name
+        args = {"size": size, "layout": layout.encode()}
+        if data_pool is not None:
+            args["data_pool"] = self._data_pool_id(data_pool)
         try:
-            await self.io.exec(hdr, "rbd", "create",
-                               {"size": size,
-                                "layout": layout.encode()})
+            await self.io.exec(hdr, "rbd", "create", args)
         except RadosError as e:
             if e.code == -17:
                 raise RBDError("image %r exists" % name) from None
@@ -89,7 +113,7 @@ class RBD:
 
         async def rm(o):
             try:
-                await self.io.remove(img._data_name(o))
+                await img.data_io.remove(img._data_name(o))
             except Exception:
                 pass
 
@@ -135,10 +159,11 @@ class RBD:
                            % (parent_snap, parent_name))
         sid, psize = int(rec["id"]), int(rec["size"])
         hdr = HEADER_PREFIX + clone_name
+        args = {"size": psize, "layout": parent.layout.encode()}
+        if parent.data_io is not parent.io:
+            args["data_pool"] = parent.data_io.pool_id
         try:
-            await self.io.exec(hdr, "rbd", "create",
-                               {"size": psize,
-                                "layout": parent.layout.encode()})
+            await self.io.exec(hdr, "rbd", "create", args)
         except RadosError as e:
             if e.code == -17:
                 raise RBDError("image %r exists"
@@ -188,7 +213,10 @@ class RBD:
         # _apply_snapc clobber another's write snapc)
         from ..client.rados import IoCtx
         img_io = IoCtx(self.io.client, self.io.pool_id)
-        img = Image(img_io, name, size, layout, snaps)
+        data_pool = meta.get("data_pool")
+        img = Image(img_io, name, size, layout, snaps,
+                    data_ioctx=(None if data_pool is None else
+                                IoCtx(self.io.client, int(data_pool))))
         if parent_meta:
             pimg = await self.open(parent_meta["image"])
             # route the parent handle's reads at the snapshot
@@ -208,8 +236,13 @@ class Image:
     """One open image (librbd::Image): offset/length block I/O."""
 
     def __init__(self, ioctx, name: str, size: int,
-                 layout: FileLayout, snaps: dict | None = None):
+                 layout: FileLayout, snaps: dict | None = None,
+                 data_ioctx=None):
         self.io = ioctx
+        # where rbd_data.* live, with the image's snap context and
+        # read snap: the header's pool unless the image was created
+        # with a data pool (librbd's data_ctx)
+        self.data_io = data_ioctx or ioctx
         self.name = name
         self._size = size
         self.layout = layout
@@ -233,7 +266,7 @@ class Image:
     def _apply_snapc(self) -> None:
         ids = sorted((int(s["id"]) for s in self.snaps.values()),
                      reverse=True)
-        self.io.set_selfmanaged_snapc(ids[0] if ids else 0, ids)
+        self.data_io.set_selfmanaged_snapc(ids[0] if ids else 0, ids)
 
     def snap_list(self) -> dict[str, dict]:
         return dict(self.snaps)
@@ -246,7 +279,7 @@ class Image:
 
         if snapname in self.snaps:
             raise RBDError("snap %r exists" % snapname)
-        sid = await self.io.selfmanaged_snap_create()
+        sid = await self.data_io.selfmanaged_snap_create()
         try:
             await self.io.exec(HEADER_PREFIX + self.name, "rbd",
                                "snap_add", {"name": snapname,
@@ -256,7 +289,7 @@ class Image:
             # losing a snap_add race must not leak the allocated
             # snapid into the pool's snap bookkeeping forever
             try:
-                await self.io.selfmanaged_snap_remove(sid)
+                await self.data_io.selfmanaged_snap_remove(sid)
             except Exception:
                 pass
             if e.code == -17:
@@ -283,7 +316,7 @@ class Image:
         # cluster-side removal next: if the mon command fails the
         # header still records the snapid and removal can be retried
         # (dropping the record first would leak the clones forever)
-        await self.io.selfmanaged_snap_remove(int(rec["id"]))
+        await self.data_io.selfmanaged_snap_remove(int(rec["id"]))
         try:
             await self.io.exec(HEADER_PREFIX + self.name, "rbd",
                                "snap_remove", {"name": snapname})
@@ -302,7 +335,7 @@ class Image:
         through a pinned handle are bounded by what existed AT the
         snap — a later head resize must not clamp (or extend) them."""
         if snapname is None:
-            self.io.set_read_snap(None)
+            self.data_io.set_read_snap(None)
             if getattr(self, "_head_size", None) is not None:
                 self._size = self._head_size
                 self._head_size = None
@@ -313,7 +346,7 @@ class Image:
         if getattr(self, "_head_size", None) is None:
             self._head_size = self._size
         self._size = int(rec["size"])
-        self.io.set_read_snap(int(rec["id"]))
+        self.data_io.set_read_snap(int(rec["id"]))
 
     async def snap_rollback(self, snapname: str) -> None:
         """Restore head contents from a snapshot
@@ -334,18 +367,18 @@ class Image:
 
         async def roll(o):
             name = self._data_name(o)
-            self.io.set_read_snap(sid)
+            self.data_io.set_read_snap(sid)
             try:
-                old = await self.io.read(name, osz, 0)
+                old = await self.data_io.read(name, osz, 0)
             except Exception:
                 old = b""
             finally:
-                self.io.set_read_snap(None)
+                self.data_io.set_read_snap(None)
             if old:
-                await self.io.write_full(name, old)
+                await self.data_io.write_full(name, old)
             else:
                 try:
-                    await self.io.remove(name)
+                    await self.data_io.remove(name)
                 except Exception:
                     pass
 
@@ -370,7 +403,7 @@ class Image:
 
             async def rm(o):
                 try:
-                    await self.io.remove(self._data_name(o))
+                    await self.data_io.remove(self._data_name(o))
                 except Exception:
                     pass
 
@@ -384,7 +417,7 @@ class Image:
                     cut[o] = min(cut.get(o, 1 << 62), oo)
             for o, off in cut.items():
                 try:
-                    await self.io.truncate(self._data_name(o), off)
+                    await self.data_io.truncate(self._data_name(o), off)
                 except Exception:
                     pass
         self._size = new_size
@@ -401,14 +434,14 @@ class Image:
         from ..client.rados import ObjectNotFound
 
         try:
-            block = await self.parent.io.read(
+            block = await self.parent.data_io.read(
                 self.parent._data_name(objectno),
                 self.layout.object_size, 0)
         except ObjectNotFound:
             return                      # parent never wrote it
         if block:
-            await self.io.write_full(self._data_name(objectno),
-                                     block)
+            await self.data_io.write_full(self._data_name(objectno),
+                                          block)
 
     async def write(self, offset: int, data: bytes) -> None:
         if offset + len(data) > self._size:
@@ -418,14 +451,16 @@ class Image:
 
         from ..client.rados import ObjectNotFound
 
-        exts = file_to_extents(self.layout, offset, len(data))
-        osz = self.layout.object_size
-        # group per object: one copy-up decision per object, and the
-        # object's extents apply IN ORDER after it (two concurrent
-        # copy-ups in one gather could clobber each other's writes)
-        by_obj: dict[int, list] = {}
-        for o, oo, ln, fo in exts:
-            by_obj.setdefault(o, []).append((oo, ln, fo))
+        with span("rbd.write", bytes=len(data)):
+            exts = file_to_extents(self.layout, offset, len(data))
+            osz = self.layout.object_size
+            # group per object: one copy-up decision per object, and
+            # the object's extents apply IN ORDER after it (two
+            # concurrent copy-ups in one gather could clobber each
+            # other's writes)
+            by_obj: dict[int, list] = {}
+            for o, oo, ln, fo in exts:
+                by_obj.setdefault(o, []).append((oo, ln, fo))
 
         async def put(o, pieces):
             whole = any(oo == 0 and ln == osz for oo, ln, _ in pieces)
@@ -435,11 +470,11 @@ class Image:
                 # offsets and object numbers interleave under
                 # striping — the object read is the exact unit)
                 try:
-                    await self.io.stat(self._data_name(o))
+                    await self.data_io.stat(self._data_name(o))
                 except ObjectNotFound:
                     await self._copy_up(o)
             for oo, ln, fo in pieces:
-                await self.io.write(
+                await self.data_io.write(
                     self._data_name(o),
                     data[fo - offset:fo - offset + ln], oo)
 
@@ -454,28 +489,30 @@ class Image:
 
         from ..client.rados import ObjectNotFound
 
-        exts = file_to_extents(self.layout, offset, length)
+        with span("rbd.read", bytes=length):
+            exts = file_to_extents(self.layout, offset, length)
 
         async def fetch(o, oo, ln, fo):
+            """An extent's bytes.  Only ENOENT means "never written"
+            (sparse zeros, or the parent's bytes below the overlap):
+            a read that failed or timed out raises."""
             try:
-                return await self.io.read(self._data_name(o), ln, oo)
+                return await self.data_io.read(self._data_name(o), ln,
+                                               oo)
             except ObjectNotFound:
-                # COW fall-through: below the overlap the parent's
-                # snapshot serves the bytes; past it, sparse zeros
                 if self.parent is not None and fo < self.overlap:
                     cov = min(ln, self.overlap - fo)
                     return await self.parent.read(fo, cov)
                 return b""
-            except Exception:
-                return b""     # unwritten extent: sparse zeros
 
         parts = await asyncio.gather(*[fetch(o, oo, ln, fo)
                                        for o, oo, ln, fo in exts])
-        buf = bytearray(length)
-        for (o, oo, ln, fo), part in zip(exts, parts):
-            part = part[:ln]
-            buf[fo - offset:fo - offset + len(part)] = part
-        return bytes(buf)
+        with span("rbd.read", bytes=length):
+            buf = bytearray(length)
+            for (o, oo, ln, fo), part in zip(exts, parts):
+                part = part[:ln]
+                buf[fo - offset:fo - offset + len(part)] = part
+            return bytes(buf)
 
     async def flatten(self) -> None:
         """Sever the parent link by materializing every still-COW
@@ -493,7 +530,7 @@ class Image:
 
         async def mat(o):
             try:
-                await self.io.stat(self._data_name(o))
+                await self.data_io.stat(self._data_name(o))
             except ObjectNotFound:
                 await self._copy_up(o)
 
@@ -527,7 +564,7 @@ class Image:
 
         async def rm(o):
             try:
-                await self.io.remove(self._data_name(o))
+                await self.data_io.remove(self._data_name(o))
             except Exception:
                 pass
 

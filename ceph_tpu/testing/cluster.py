@@ -343,6 +343,18 @@ class LocalCluster:
             await self.client.wait_for_epoch(leader.osdmap.epoch)
         return out["pool_id"]
 
+    async def allow_ec_overwrites(self, pool: str) -> None:
+        """`ceph osd pool set <pool> allow_ec_overwrites true`: from
+        the epoch this returns at, the erasure pool takes partial
+        overwrites and truncates (an OSD holds an op until it has the
+        client's epoch)."""
+        await self.client.mon_command("osd pool set", pool=pool,
+                                      var="allow_ec_overwrites",
+                                      val="true")
+        leader = self.leader()
+        if leader is not None:
+            await self.client.wait_for_epoch(leader.osdmap.epoch)
+
     # -- observability -----------------------------------------------------
 
     def set_clock_skew(self, entity: str, seconds: float) -> None:

@@ -35,10 +35,13 @@ _STAGE_SLOT = {"queued": 0, "reached_pg": 1,
                "ec_sub_write_timeout": 5,
                "ec_sub_read_sent": 6, "ec_sub_read_acked": 7,
                "ec_sub_read_timeout": 7,
-               "ec_decode_start": 8, "ec_decoded": 9}
+               "ec_decode_start": 8, "ec_decoded": 9,
+               "ec_delta_lock_wait": 10, "ec_delta_locked": 11,
+               "ec_delta_read_sent": 12, "ec_delta_read_done": 13}
 _STAGE_WAITS = (("queue_us", 0, 1), ("ec_batch_us", 2, 3),
                 ("subop_us", 4, 5), ("sub_read_us", 6, 7),
-                ("decode_us", 8, 9))
+                ("decode_us", 8, 9), ("delta_lock_us", 10, 11),
+                ("delta_read_us", 12, 13))
 
 
 class TrackedOp:

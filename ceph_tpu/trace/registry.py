@@ -59,6 +59,8 @@ OP_STAGES = frozenset({
     "ec_sub_read_sent", "ec_sub_read_acked", "ec_sub_read_timeout",
     "ec_decode_start", "ec_decoded",
     "ec_shard_applied", "ec_delta_rmw", "ec_delta_done",
+    "ec_delta_lock_wait", "ec_delta_locked",
+    "ec_delta_read_sent", "ec_delta_read_done",
     "ec_error_reply",
 })
 

@@ -85,6 +85,24 @@ SPANS: dict[str, tuple[str, str]] = {
     "osd.ec.reconstruct": (HOST, "mark: a client read rebuilt wanted "
                            "positions from the survivors; erased "
                            "positions, bytes of the object"),
+    "osd.ec.delta_plan": (HOST, "_try_delta_write: whether a partial "
+                          "write may take the parity-delta path, its "
+                          "parts per data chunk, the merged column "
+                          "intervals; bytes overwritten"),
+    "osd.ec.delta_xor": (HOST, "_try_delta_write after the ranged "
+                         "reads: old xor new, the touched data chunks' "
+                         "crcs, the rows handed to delta_async; bytes "
+                         "overwritten"),
+    "osd.ec.delta_apply": (HOST, "_try_delta_write after the device: "
+                           "old parity xor delta and the parity crcs "
+                           "(shards = m), then the shard transactions "
+                           "(shards = all)"),
+    "osd.ec.delta_write": (HOST, "mark: the parity-delta path committed "
+                           "a partial write; bytes overwritten, data "
+                           "chunks touched, column intervals"),
+    "osd.ec.rmw_fallback": (HOST, "mark: a partial write fell to the "
+                            "whole-object read-modify-write; why, an "
+                            "index into ecbackend.RMW_FALLBACK_WHY"),
     "osd.advance_pgs": (HOST, "OSD._advance_pgs: one new map epoch"),
     "heartbeat": (HOST, "one tick of OSD._heartbeat_loop: watchdogs, "
                   "reports, pings, failure reports"),
@@ -96,6 +114,11 @@ SPANS: dict[str, tuple[str, str]] = {
                     "kernel + readback; bytes_in, bytes_out"),
     "ec.deliver": (BATCHER, "parity slices to the waiting ops; items"),
     "ec.collect": (BATCHER, "encode_async: parity rows -> shard bytes"),
+    "ec.delta_prepare": (BATCHER, "delta_async: the touched chunks' "
+                         "deltas into a k x words array, zero rows for "
+                         "the rest"),
+    "ec.delta_collect": (BATCHER, "delta_async: parity-delta rows -> "
+                         "bytes"),
     "ec.decode_prepare": (BATCHER, "decode_async: the reconstruction "
                           "matrix (cached), k survivors stacked into "
                           "rows of words"),
@@ -107,8 +130,13 @@ SPANS: dict[str, tuple[str, str]] = {
     "op.retired": (HOST, "mark: an op left its tracker; stage waits in "
                    "us from the stamps it carried (queue_us, "
                    "ec_batch_us, subop_us; a read's sub_read_us, "
-                   "decode_us; total_us), client=1 for the client's "
-                   "own op"),
+                   "decode_us; a partial write's delta_lock_us, "
+                   "delta_read_us; total_us), client=1 for the "
+                   "client's own op"),
+    "rbd.write": (CLIENT, "Image.write: a block write cut into object "
+                  "extents, grouped per object; bytes"),
+    "rbd.read": (CLIENT, "Image.read: a block read cut into object "
+                 "extents, and the extents' bytes joined; bytes"),
     # -- the bulk remap ----------------------------------------------------
     "crush.build": (MAPPING, "OSDMapMapping._build, whole; pools"),
     "crush.upload": (MAPPING, "weights and state vectors to the device; "

@@ -344,6 +344,7 @@ async def phase_cluster(args, rng, rt, before) -> None:
                                       pool_type="erasure",
                                       erasure_code_profile=name)
             check(got == pid, "pool id %d, mapped ahead as %d" % (got, pid))
+            await c.allow_ec_overwrites("smoke")    # the overwrites below
             await c.wait_health(pid, timeout=300)
             # first sight of the profile starts each OSD's warmup_ec
             codec = [o.ec.codec(client.osdmap.pools[pid])

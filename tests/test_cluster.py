@@ -208,6 +208,7 @@ def test_ec_pool_put_get():
                 pool_type="erasure")
             pid = out["pool_id"]
             await c.client.wait_for_epoch(c.mon.osdmap.epoch)
+            await c.allow_ec_overwrites("ecpool")
             await c.wait_health(pid)
             io = c.client.io_ctx("ecpool")
             payloads = {}

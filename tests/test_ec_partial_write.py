@@ -16,6 +16,7 @@ async def _ec_pool(c, name="ecp"):
         "osd pool create", pool=name, pg_num=8, pool_type="erasure")
     pid = out["pool_id"]
     await c.client.wait_for_epoch(c.mon.osdmap.epoch)
+    await c.allow_ec_overwrites(name)
     await c.wait_health(pid)
     return pid
 

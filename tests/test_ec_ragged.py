@@ -319,6 +319,7 @@ def test_delta_write_device_route_and_amplification():
                 pool_type="erasure")
             pid = out["pool_id"]
             await c.client.wait_for_epoch(c.mons[0].osdmap.epoch)
+            await c.allow_ec_overwrites("ragdelta")
             await c.wait_health(pid)
             io = c.client.io_ctx("ragdelta")
             size = 128 * 1024
@@ -389,6 +390,7 @@ def test_delta_write_journal_replicated_and_promoted_dup():
                 pool_type="erasure")
             pid = out["pool_id"]
             await c.client.wait_for_epoch(c.mons[0].osdmap.epoch)
+            await c.allow_ec_overwrites("dupdelta")
             await c.wait_health(pid)
             io = c.client.io_ctx("dupdelta")
             await io.write_full("obj", b"\x5a" * 65536)
@@ -477,6 +479,7 @@ def test_mixed_rmw_thrash_round():
                 pool_type="erasure")
             pid = out["pool_id"]
             await c.client.wait_for_epoch(c.mons[0].osdmap.epoch)
+            await c.allow_ec_overwrites("mixrmw")
             await c.wait_health(pid)
             rt = DeviceRuntime.get()
             before = rt.dispatches

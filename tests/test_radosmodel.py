@@ -170,6 +170,7 @@ def test_radosmodel_stress_ec_pool():
                 pool_type="erasure")
             pid = out["pool_id"]
             await c.client.wait_for_epoch(c.mon.osdmap.epoch)
+            await c.allow_ec_overwrites("emodel")
             await c.wait_health(pid)
             io = c.client.io_ctx("emodel")
             model = Model()

@@ -5,8 +5,9 @@ may suspend (one thread runs every coroutine, so a span across an
 ``await`` would nest with other requests' spans); with no profiler a
 span costs nothing visible; under a profiler session on the CPU backend
 one EC write leaves every rados-path span, properly nested per thread,
-and a read with a data holder stopped under noout leaves the read
-path's.
+a read with a data holder stopped under noout leaves the read path's,
+and 4 KiB overwrites of an RBD image on an EC data pool leave the
+parity-delta path's.
 """
 
 import ast
@@ -38,6 +39,13 @@ READ_PATH = ("client.target_hit", "osd.ec.read", "osd.ec.sub_read",
              "osd.ec.sub_read_reply", "osd.ec.reconstruct",
              "ec.decode_prepare", "ec.decode_collect", "ec.dispatch",
              "op.retired")
+# what a 4 KiB overwrite of an image on an EC data pool leaves (the
+# parity-delta path), a block read, and one write that grows an object
+DELTA_PATH = ("rbd.write", "rbd.read", "osd.ec.delta_plan",
+              "osd.ec.delta_xor", "osd.ec.delta_apply",
+              "osd.ec.delta_write", "osd.ec.rmw_fallback",
+              "ec.delta_prepare", "ec.delta_collect", "ec.dispatch",
+              "osd.ec.sub_read", "osd.ec.submit", "op.retired")
 REMAP_PATH = ("crush.build", "crush.upload", "crush.launch", "crush.wait",
               "crush.readback", "crush.tables")
 # the first EC write of a process compiles on the loop every daemon
@@ -46,6 +54,22 @@ REMAP_PATH = ("crush.build", "crush.upload", "crush.launch", "crush.wait",
 # back -EAGAIN.  Nothing here is about failure detection: the fixtures'
 # clusters keep the shipped grace, and the one kill waits that long.
 SHIPPED_GRACE = {"heartbeat_grace": 6.0}
+
+
+async def warm_write(c, io, pid: int) -> None:
+    """The process's first EC write, outside every case: it compiles on
+    the loop every daemon shares, and on a loaded machine that can
+    outlast even the shipped grace, the OSDs re-boot under it and the
+    write comes back -EAGAIN (ROADMAP A0).  Asked again once the pool
+    is healthy; any other answer is raised."""
+    from ceph_tpu.client.rados import RadosError
+    for attempt in range(5):
+        try:
+            return await io.write_full("warm", b"\x5a" * 8192)
+        except RadosError as e:
+            if e.code != -11 or attempt == 4:
+                raise
+            await c.wait_health(pid)
 
 
 def _is_span_call(node, names=("span", "mark")) -> bool:
@@ -195,7 +219,7 @@ def traced_write(tmp_path_factory):
                                       pool_type="erasure")
             await c.wait_health(pid)
             io = c.client.io_ctx("spans")
-            await io.write_full("warm", b"\x5a" * 8192)
+            await warm_write(c, io, pid)
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(log_dir, profiler_options=opts)
@@ -234,6 +258,7 @@ def traced_degraded_reads(tmp_path_factory):
                                       pool_type="erasure")
             await c.wait_health(pid)
             io = c.client.io_ctx("reads")
+            await warm_write(c, io, pid)
             om = c.client.osdmap
             await c.client.mon_command("osd set", key="noout")
             victim = 1
@@ -401,3 +426,102 @@ def test_traced_degraded_read_retires_with_read_stages(
 
 def test_traced_degraded_read_spans_nest_per_thread(traced_degraded_reads):
     test_traced_write_spans_nest_per_thread(traced_degraded_reads[0])
+
+
+# -- traced overwrites of an image on an EC data pool ------------------------
+
+OVERWRITES = 3
+
+
+@pytest.fixture(scope="module")
+def traced_overwrites(tmp_path_factory):
+    """Three 4 KiB overwrites of a written 64 KiB object through
+    services/rbd.py, one block read, and one write that grows an object."""
+    import jax
+
+    from ceph_tpu.client.striper import FileLayout
+    from ceph_tpu.services.rbd import RBD
+    from ceph_tpu.testing import LocalCluster
+    log_dir = str(tmp_path_factory.mktemp("overwrites"))
+
+    async def main():
+        c = await LocalCluster(n_osds=3, conf=SHIPPED_GRACE).start()
+        try:
+            ec = await c.create_pool("ow", pg_num=4, pool_type="erasure")
+            rep = await c.create_pool("rbd", pg_num=4)
+            await c.allow_ec_overwrites("ow")
+            await c.wait_health(ec)
+            await c.wait_health(rep)
+            await warm_write(c, c.client.io_ctx("ow"), ec)
+            rbd = RBD(c.client.io_ctx("rbd"))
+            await rbd.create("disk", 1 << 16, FileLayout(
+                stripe_unit=1 << 16, stripe_count=1, object_size=1 << 16),
+                data_pool="ow")
+            img = await rbd.open("disk")
+            await img.write(0, b"\x5a" * (1 << 16))
+            await img.write(4096, b"\xa5" * 4096)        # warm
+            io = c.client.io_ctx("ow")
+            await io.write_full("grows", b"g" * 8192)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(log_dir, profiler_options=opts)
+            try:
+                for i in range(OVERWRITES):
+                    await img.write(8192 * (i + 1), bytes([i + 1]) * 4096)
+                assert await img.read(8192, 4096) == b"\x01" * 4096
+                await io.write("grows", b"h" * 8192, 4096)
+                await asyncio.sleep(0.2)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            await c.stop()
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("CEPH_TPU_EC_OFFLOAD", "1")
+        asyncio.run(asyncio.wait_for(main(), 240))
+    return _host_events(log_dir)
+
+
+@pytest.mark.parametrize("name", DELTA_PATH)
+def test_traced_overwrite_leaves_span(traced_overwrites, name):
+    assert _named(traced_overwrites, name), sorted(
+        {ev[0] for evs in traced_overwrites.values() for ev in evs})
+
+
+def test_traced_overwrite_marks_each_delta_write_and_the_fallback(
+        traced_overwrites):
+    from ceph_tpu.osd.ecbackend import RMW_FALLBACK_WHY
+    deltas = [ev[3] for ev in _named(traced_overwrites,
+                                     "osd.ec.delta_write")]
+    assert deltas == [{"bytes": 4096, "chunks": 1,
+                       "intervals": 1}] * OVERWRITES
+    fell = [ev[3] for ev in _named(traced_overwrites,
+                                   "osd.ec.rmw_fallback")]
+    assert [RMW_FALLBACK_WHY[s["why"]] for s in fell] == ["growth"]
+    plans = [ev[3]["bytes"] for ev in _named(traced_overwrites,
+                                             "osd.ec.delta_plan")]
+    assert sorted(plans) == [4096] * OVERWRITES + [8192]
+    # k2m1: one parity, then all three shards' transactions
+    applied = sorted(ev[3]["shards"] for ev in _named(
+        traced_overwrites, "osd.ec.delta_apply"))
+    assert applied == [1] * OVERWRITES + [3] * OVERWRITES
+
+
+def test_traced_overwrite_retires_with_delta_stages(traced_overwrites):
+    retired = [ev[3] for ev in _named(traced_overwrites, "op.retired")]
+    staged = [s for s in retired if "delta_read_us" in s]
+    assert len(staged) == OVERWRITES
+    assert all(s["delta_read_us"] > 0 and s["delta_lock_us"] >= 0
+               and s["subop_us"] > 0
+               and s["total_us"] >= s["delta_read_us"] + s["subop_us"]
+               for s in staged), staged
+    # the growing write waited for the lock too and read no delta
+    assert len([s for s in retired if "delta_lock_us" in s]) \
+        == OVERWRITES + 1
+    sizes = [ev[3]["bytes"] for ev in _named(traced_overwrites,
+                                             "rbd.write")]
+    assert sizes == [4096] * OVERWRITES
+
+
+def test_traced_overwrite_spans_nest_per_thread(traced_overwrites):
+    test_traced_write_spans_nest_per_thread(traced_overwrites)
