@@ -31,12 +31,18 @@ q = floor((2^48-crush_ln(u))//w) with first-index tie-break):
   (~1e-5 of visits) fall back to the scalar host engine.
 
 Retry control flow (collision/rejection retries, mapper.c:475-626) is
-restructured for SIMD: each replica gets one optimistic full-width
-"attempt" (the overwhelmingly common case), and the few lanes that
-collide or get rejected are compacted (jnp.nonzero + gather) into a
-small tail batch that replays the full retry semantics.  A first pass
-runs the f32 path flagging uncertain lanes; a second pass re-runs only
-flagged lanes (~0.5%) in resolve mode.
+restructured for SIMD.  Large batches run the "attempt" structure: per
+replica a fixed number of optimistic rounds (ftotal = 0, 1, ...) with no
+data-dependent loop; a lane still unplaced after them, or whose f32
+draw was uncertain, is flagged, and the device-resident resolve chain
+recomputes the flagged lanes exactly (top-3 resolution, then the full
+retry loops, then the all-integer draw).  In the dense pass of a whole
+pool (_compiled_pool) only the first round of each replica runs at full
+width: the lanes it leaves unplaced are compacted per chunk (the Pallas
+rowcompact kernel), replayed from scratch through the attempt structure
+at the compacted width, and their rows put back (the rowexpand kernel).
+Small batches and the resolve chain's later stages run the full retry
+loops.
 
 Device scope (the modern "optimal" tunables profile): straw2 buckets at
 every level, choose_local_tries == choose_local_fallback_tries == 0,
@@ -46,7 +52,9 @@ else falls back to the host interpreter, which remains the general spec.
 
 from __future__ import annotations
 
+import collections
 import functools
+import math
 
 import numpy as np
 
@@ -69,7 +77,7 @@ from ...models.crushmap import (
     TAKE,
     CrushMap,
 )
-from ...trace.span import span
+from ...trace.span import mark, span
 from ._ln_tables import LL_TBL, RH_LH_TBL
 
 S64_MAX = (1 << 63) - 1
@@ -798,14 +806,16 @@ def _is_out(dev_weights, item, x):
 # firstn / indep
 # ---------------------------------------------------------------------------
 
-# optimistic retries fused into the full-width attempt pass; lanes
-# still failing after these land in the pass-2 resolve set, which the
-# device-resident resolve chain settles cheaply — three rounds balance
-# full-width dense cost against resolve-set size
+# optimistic rounds per replica of the attempt structure (ftotal = 0, 1,
+# 2): a lane still unplaced after them is flagged for the resolve chain.
+# A whole pool's dense pass runs only the first of them at full width
+# and the rest on the compacted tail (_compiled_pool); the incremental
+# remap, the chain's stage A and the indep path, whose lanes are a
+# compacted set already, run all of them at their own width.
 _ATTEMPT_TRIES = 3
 
-# below this lane count the optimistic attempt + compacted tail isn't
-# worth its bookkeeping; run the full retry loops directly
+# below this lane count the attempt structure isn't worth its
+# bookkeeping; run the full retry loops directly
 _ATTEMPT_MIN_L = 16384
 
 
@@ -896,20 +906,38 @@ def _firstn_full(fm: FlatMap, take_bid, xs, out, leaves, outpos,
     return out, leaves, outpos, flag
 
 
+def _firstn_attempts(tries: int, recurse_to_leaf: bool,
+                     recurse_tries: int) -> int:
+    """Optimistic rounds per replica of the attempt structure.  An outer
+    retry (ftotal+1) after a leaf failure only matches the reference
+    when the inner loop is single-try (chooseleaf_descend_once, the
+    modern default); otherwise the inner retries first, so the
+    structure stops at one round and defers to the resolve chain."""
+    if recurse_to_leaf and recurse_tries > 1:
+        return 1
+    return min(_ATTEMPT_TRIES, tries)
+
+
 def _choose_firstn_vec(fm: FlatMap, take_bid_val: int, xs, numrep: int,
                        result_max: int, want_type: int,
                        recurse_to_leaf: bool, dev_weights,
                        tries: int, recurse_tries: int, vary_r: int,
                        stable: int, outer_ds: tuple, inner_ds: tuple,
                        resolve: bool, full: bool,
-                       rootc: _ConstRow | None):
-    """Fast-path firstn: _ATTEMPT_TRIES optimistic full-width rounds
-    per replica (ftotal = 0, 1, ...); a lane still unsatisfied after
-    them is flagged for the resolve pass instead of driving a masked
-    retry loop — data-dependent while loops, compaction gathers and
-    result scatters all cost more on TPU than recomputing the few
-    stragglers exactly in pass 2.  Resolve mode and small batches run
-    the full retry loops."""
+                       rootc: _ConstRow | None,
+                       first_only: bool = False):
+    """Fast-path firstn, the attempt structure: _firstn_attempts
+    optimistic rounds per replica (ftotal = 0, 1, ...), every round over
+    all L lanes; a lane still unplaced after them is left to the caller
+    instead of driving a masked retry loop.  Resolve mode and small
+    batches run the full retry loops.
+
+    Returns (rows, outpos, flag, unfinished): flag marks a lane with an
+    uncertain f32 draw, unfinished one whose replica is still unplaced;
+    either has to be recomputed.  first_only runs the first round of
+    each replica alone: the rows of its unfinished lanes are the state
+    at their first failure and are replaced whole (the dense pass of
+    _compiled_pool, which replays them on a compacted tail)."""
     L = xs.shape[0]
     slots = min(numrep, result_max)
     take_bid = jnp.full((L,), -1 - take_bid_val, jnp.int32)
@@ -921,60 +949,72 @@ def _choose_firstn_vec(fm: FlatMap, take_bid_val: int, xs, numrep: int,
             fm, take_bid, xs, out0, leaves0, pos0, numrep, result_max,
             want_type, recurse_to_leaf, dev_weights, tries, recurse_tries,
             vary_r, stable, outer_ds, inner_ds, resolve, rootc)
-        return (leaves if recurse_to_leaf else out), outpos, flag
+        return ((leaves if recurse_to_leaf else out), outpos, flag,
+                jnp.zeros((L,), bool))
 
-    out, leaves, outpos = out0, leaves0, pos0
-    flag = jnp.zeros((L,), bool)
-    clean = jnp.ones((L,), bool)
-    # an outer retry (ftotal+1) after a leaf failure only matches the
-    # reference when the inner loop is single-try (chooseleaf_descend_
-    # once, the modern default); otherwise the inner retries first, so
-    # the optimistic pass stops at one round and defers to pass 2
-    n_attempts = min(_ATTEMPT_TRIES, tries)
-    if recurse_to_leaf and recurse_tries > 1:
-        n_attempts = 1
-    for rep in range(numrep):
-        done_rep = jnp.zeros((L,), bool)
-        for ft in range(n_attempts):
-            r = jnp.full((L,), rep + ft, jnp.int32)
-            item, ok, perm, f1 = _descend(fm, take_bid, xs, r,
-                                          want_type, outpos, outer_ds,
-                                          resolve, rootc)
-            if recurse_to_leaf:
-                if vary_r:
-                    sub_r = r >> (vary_r - 1)
-                else:
-                    sub_r = jnp.zeros_like(r)
-                rep_i = (jnp.zeros_like(outpos) if stable else outpos)
-                bid_in = jnp.where(item < 0, -1 - item, 0)
-                r_in = rep_i + sub_r
-                cand, cok, _cp, f2 = _descend(fm, bid_in, xs, r_in, 0,
-                                              outpos, inner_ds, resolve,
-                                              None)
-                cok = cok & (item < 0)
-                cok = cok & ~jnp.any(leaves == cand[:, None], axis=1)
-                cok = cok & ~_is_out(dev_weights, cand, xs)
-                final, final_ok = cand, ok & cok
-                f1 = f1 | (f2 & ok & (item < 0))
+    n_attempts = (1 if first_only else
+                  _firstn_attempts(tries, recurse_to_leaf, recurse_tries))
+
+    def attempt(k, state):
+        """Round k: replica k // n_attempts at ftotal k % n_attempts.
+        The fast form runs the rounds as a loop, so that a program
+        holds each descent kernel once: the kernels are most of a pool
+        program's size."""
+        out, leaves, outpos, flag, clean, done_rep = state
+        rep = (k // n_attempts).astype(jnp.int32)
+        ft = (k % n_attempts).astype(jnp.int32)
+        # a replica's first round finds it unplaced on every lane
+        done_rep = done_rep & (ft > 0)
+        r = jnp.zeros((L,), jnp.int32) + rep + ft
+        item, ok, perm, f1 = _descend(fm, take_bid, xs, r, want_type,
+                                      outpos, outer_ds, resolve, rootc)
+        if recurse_to_leaf:
+            if vary_r:
+                sub_r = r >> (vary_r - 1)
             else:
-                final = item
-                final_ok = ok
-                if want_type == 0:
-                    final_ok = final_ok & ~_is_out(dev_weights, item,
-                                                   xs)
-            collide = jnp.any(out == item[:, None], axis=1) & ok
-            act = ~done_rep
-            success = act & final_ok & ~collide & (outpos < slots)
-            slot = jnp.arange(slots)[None, :] == outpos[:, None]
-            put = slot & success[:, None]
-            out = jnp.where(put, item[:, None], out)
-            leaves = jnp.where(put, final[:, None], leaves)
-            outpos = outpos + success.astype(jnp.int32)
-            flag = flag | (clean & act & f1)
-            done_rep = done_rep | success | (act & perm)
-        clean = clean & done_rep
-    flag = flag | ~clean
-    return (leaves if recurse_to_leaf else out), outpos, flag
+                sub_r = jnp.zeros_like(r)
+            rep_i = (jnp.zeros_like(outpos) if stable else outpos)
+            bid_in = jnp.where(item < 0, -1 - item, 0)
+            r_in = rep_i + sub_r
+            cand, cok, _cp, f2 = _descend(fm, bid_in, xs, r_in, 0,
+                                          outpos, inner_ds, resolve,
+                                          None)
+            cok = cok & (item < 0)
+            cok = cok & ~jnp.any(leaves == cand[:, None], axis=1)
+            cok = cok & ~_is_out(dev_weights, cand, xs)
+            final, final_ok = cand, ok & cok
+            f1 = f1 | (f2 & ok & (item < 0))
+        else:
+            final = item
+            final_ok = ok
+            if want_type == 0:
+                final_ok = final_ok & ~_is_out(dev_weights, item, xs)
+        collide = jnp.any(out == item[:, None], axis=1) & ok
+        act = ~done_rep
+        success = act & final_ok & ~collide & (outpos < slots)
+        slot = jnp.arange(slots)[None, :] == outpos[:, None]
+        put = slot & success[:, None]
+        out = jnp.where(put, item[:, None], out)
+        leaves = jnp.where(put, final[:, None], leaves)
+        outpos = outpos + success.astype(jnp.int32)
+        flag = flag | (clean & act & f1)
+        done_rep = done_rep | success | (act & perm)
+        # a replica unplaced after its last round leaves the lane dirty
+        clean = clean & (done_rep | (ft < n_attempts - 1))
+        return out, leaves, outpos, flag, clean, done_rep
+
+    none = jnp.zeros((L,), bool)
+    state = (out0, leaves0, pos0, none, ~none, none)
+    if resolve:
+        # the exact form stays a chain: with its rounds in a loop the
+        # 10M-PG resolve program's tables read back to the host at a
+        # quarter of the speed (PERF.md, PR 34; cause not found)
+        for k in range(numrep * n_attempts):
+            state = attempt(jnp.int32(k), state)
+    else:
+        state = jax.lax.fori_loop(0, numrep * n_attempts, attempt, state)
+    out, leaves, outpos, flag, clean, _ = state
+    return (leaves if recurse_to_leaf else out), outpos, flag, ~clean
 
 
 def _indep_round(fm: FlatMap, take_bid, xs, ftotal, out, leaves, flag,
@@ -1176,6 +1216,18 @@ def _post_process(raw, seeds, exists_b, isup_b, aff, can_shift: bool,
     return up, prim
 
 
+def _pps(ps, pgp_num: int, pgp_mask: int, pool_id: int, hashps: bool):
+    """pg -> placement seed on device (raw_pg_to_pps, osd_types.cc):
+    the stable mod of the pg number, mixed with the pool id."""
+    ps = ps.astype(jnp.uint32)
+    masked = jnp.where((ps & _u32(pgp_mask)) < _u32(pgp_num),
+                       ps & _u32(pgp_mask),
+                       ps & _u32(pgp_mask >> 1))
+    if hashps:
+        return hash32_2_j(masked, _u32(pool_id))
+    return masked + _u32(pool_id)
+
+
 # ---------------------------------------------------------------------------
 # rule driver
 # ---------------------------------------------------------------------------
@@ -1201,11 +1253,13 @@ class MapState:
     __slots__ = ("dm", "ruleno", "result_max", "pg_num", "pgp_num",
                  "pgp_mask", "pool_id", "hashps", "can_shift",
                  "use_aff", "raw", "up_full", "prim_full", "w_np",
-                 "ex_np", "iu_np", "af_np", "npg")
+                 "ex_np", "iu_np", "af_np", "npg", "lanes",
+                 "tail_lanes", "resolve_lanes")
 
     def __init__(self, dm, ruleno, result_max, pg_num, pgp_num,
                  pgp_mask, pool_id, hashps, can_shift, use_aff, raw,
-                 up_full, prim_full, w_np, ex_np, iu_np, af_np, npg):
+                 up_full, prim_full, w_np, ex_np, iu_np, af_np, npg,
+                 lanes=0, tail_lanes=0, resolve_lanes=0):
         self.dm = dm
         self.ruleno = ruleno
         self.result_max = result_max
@@ -1224,6 +1278,12 @@ class MapState:
         self.iu_np = iu_np
         self.af_np = af_np
         self.npg = npg
+        # the pass that made this state: lanes it computed, those of
+        # them replayed on the dense pass's compacted tail, those the
+        # resolve chain took (the mark crush.lanes carries the same)
+        self.lanes = lanes
+        self.tail_lanes = tail_lanes
+        self.resolve_lanes = resolve_lanes
 
     @property
     def up(self):
@@ -1319,7 +1379,12 @@ class MapState:
             self.dm, self.ruleno, self.result_max, self.pg_num,
             self.pgp_num, self.pgp_mask, self.pool_id, self.hashps,
             self.can_shift, self.use_aff, raw2, up2, prim2, w_np,
-            ex_np, iu_np, af_np, self.npg)
+            ex_np, iu_np, af_np, self.npg, lanes=nA, resolve_lanes=nf)
+
+
+_Plan = collections.namedtuple(
+    "_Plan", "firstn take_id numrep want_type leaf tries recurse vary_r "
+             "stable outer_ds inner_ds")
 
 
 class DeviceMapper:
@@ -1339,9 +1404,16 @@ class DeviceMapper:
         self.map = crushmap
         self._cargs = (crushmap.choose_args.get(choose_args_name)
                        if choose_args_name else None)
+        # (ruleno, result_max, chunk lanes) -> slots per row group a
+        # dense pass's tail was found to need; the passes thrown away
+        # to find it
+        self._tail_want: dict[tuple, int] = {}
+        self.tail_overflows = 0
 
-    def _compile(self, ruleno: int, result_max: int, resolve: bool,
-                 full: bool = True):
+    @functools.lru_cache(maxsize=None)
+    def _plan(self, ruleno: int, result_max: int) -> "_Plan":
+        """The rule's one choose step with the tunables that govern
+        it."""
         rule = self.fm.rules[ruleno]
         t = self.fm.tunables
         tries = t.choose_total_tries + 1     # historical off-by-one
@@ -1388,28 +1460,42 @@ class DeviceMapper:
                        else (1 if t.chooseleaf_descend_once else tries))
         else:
             recurse = leaf_tries if leaf_tries else 1
-        fm = self.fm
         outer_ds = self._depth_sizes([take_id], want_type)
-        rootc = fm.const_row(take_id, outer_ds[0])
         if leaf:
             starts = [b.id for b in self.map.buckets.values()
                       if b.type == want_type]
             inner_ds = self._depth_sizes(starts, 0)
         else:
             inner_ds = ()
+        return _Plan(firstn, take_id, numrep, want_type, leaf, tries,
+                     recurse, vary_r, stable, outer_ds, inner_ds)
+
+    def _compile(self, ruleno: int, result_max: int, resolve: bool,
+                 full: bool = True, first_only: bool = False):
+        """core(xs, dev_weights) -> (rows, flag): flag marks the lanes
+        that a more exact pass has to recompute.  first_only (firstn,
+        full=False): the first optimistic round of each replica alone,
+        -> (rows, flag, unfinished) with the unplaced lanes apart."""
+        plan = self._plan(ruleno, result_max)
+        fm = self.fm
+        rootc = fm.const_row(plan.take_id, plan.outer_ds[0])
 
         def core(xs, dev_weights):
-            if firstn:
-                res, _, flag = _choose_firstn_vec(
-                    fm, take_id, xs, numrep, result_max, want_type,
-                    leaf, dev_weights, tries, recurse, vary_r, stable,
-                    outer_ds, inner_ds, resolve, full, rootc)
-            else:
-                res, flag = _choose_indep_vec(
-                    fm, take_id, xs, numrep, result_max, want_type,
-                    leaf, dev_weights, tries, recurse,
-                    outer_ds, inner_ds, resolve, full, rootc)
-            return res, flag
+            if plan.firstn:
+                res, _, flag, unfinished = _choose_firstn_vec(
+                    fm, plan.take_id, xs, plan.numrep, result_max,
+                    plan.want_type, plan.leaf, dev_weights,
+                    plan.tries, plan.recurse, plan.vary_r,
+                    plan.stable, plan.outer_ds, plan.inner_ds,
+                    resolve, full, rootc, first_only)
+                if first_only:
+                    return res, flag, unfinished
+                return res, flag | unfinished
+            return _choose_indep_vec(
+                fm, plan.take_id, xs, plan.numrep, result_max,
+                plan.want_type, plan.leaf, dev_weights,
+                plan.tries, plan.recurse, plan.outer_ds,
+                plan.inner_ds, resolve, full, rootc)
 
         return core
 
@@ -1471,40 +1557,99 @@ class DeviceMapper:
     # per-dispatch PG cap: bounds live [L, S] f32/int32 temps in HBM
     CHUNK = 1 << 20
 
+    # the dense pass's tail: compaction slots per RC_ROW-lane row group
+    # it starts with, and the most it is widened to before a pool goes
+    # back to dense rounds (at 512 of 2048 the tail's nine rounds cost
+    # 2.25 full-width rounds of the 6 they replace, gathers aside)
+    TAIL_KT = 256
+    TAIL_KT_MAX = 512
+
+    def _tail_slots(self, ruleno: int, result_max: int, C: int,
+                    want: int) -> int:
+        """Slots per row group (>= want) for the tail of a dense pass
+        whose chunks are C lanes wide, or 0: no tail, every optimistic
+        round dense.  A tail needs a firstn rule with rounds to save,
+        the attempt structure (C >= _ATTEMPT_MIN_L), rowcompact's
+        alignment, and a width that keeps its descents in Pallas."""
+        from . import pallas_draw
+        plan = self._plan(ruleno, result_max)
+        if not (plan.firstn and C >= _ATTEMPT_MIN_L
+                and self._rc_ok(C)
+                and _firstn_attempts(plan.tries, plan.leaf,
+                                     plan.recurse) > 1):
+            return 0
+        nr = C // self.RC_ROW
+        step = max(128, pallas_draw.TL // math.gcd(nr, pallas_draw.TL))
+        kt = step * -(-want // step)
+        return kt if kt <= self.TAIL_KT_MAX else 0
+
     # -- whole-pool mapping with device-side pps -------------------------
 
     @functools.lru_cache(maxsize=None)
     def _compiled_pool(self, ruleno: int, result_max: int,
                        can_shift: bool, use_aff: bool, pgp_num: int,
                        pgp_mask: int, pool_id: int, hashps: bool,
-                       n: int, n_chunks: int):
+                       n: int, n_chunks: int, kt_tail: int = 0):
         """Whole pool in ONE dispatch: a lax.scan over fixed-size
         chunks (the chunking bounds the live [L,S] temps, the scan
-        removes per-chunk dispatch/readback latency).  full=False: the
-        dense pass runs the
-        bounded optimistic-attempt structure; lanes needing deeper
-        retries are flagged and settled by the resolve passes, so the
-        dense cost is fixed at numrep×_ATTEMPT_TRIES descents instead
-        of being dragged by the worst lane's retry count."""
+        removes per-chunk dispatch/readback latency).  The dense pass
+        runs the bounded attempt structure; lanes needing deeper
+        retries are flagged and settled by the resolve passes, so its
+        cost does not follow the worst lane's retry count.
+
+        kt_tail > 0 (_tail_slots): only the first optimistic round of
+        each replica runs over all n lanes of a chunk (numrep descents,
+        twice that for chooseleaf).  The lanes it leaves unplaced are
+        compacted into kt_tail slots per row group (rowcompact),
+        replayed from scratch through the whole attempt structure at
+        that width (n / RC_ROW * kt_tail lanes) and their rows and
+        flags put back (rowexpand).  A row group with more unplaced
+        lanes than slots keeps the rest flagged for the resolve chain;
+        the pass's counts (lanes seated, lanes left unseated, largest
+        group) tell the host when that is worth a wider tail.
+        kt_tail == 0: every round over all n lanes."""
         self._note_compile("pool", (ruleno, result_max, can_shift,
                                     use_aff, pgp_num, pgp_mask,
-                                    pool_id, hashps, n, n_chunks))
+                                    pool_id, hashps, n, n_chunks,
+                                    kt_tail))
+        from . import pallas_draw
         core = self._compile(ruleno, result_max, False, full=False)
+        if kt_tail:
+            first = self._compile(ruleno, result_max, False, full=False,
+                                  first_only=True)
+            rc = pallas_draw.make_rowcompact_kernel(n, self.RC_ROW,
+                                                    kt_tail, n)
 
-        def chunk(start):
-            ps = jnp.arange(n, dtype=jnp.uint32) + start
-            masked = jnp.where((ps & _u32(pgp_mask)) < _u32(pgp_num),
-                               ps & _u32(pgp_mask),
-                               ps & _u32(pgp_mask >> 1))
-            if hashps:
-                xs = hash32_2_j(masked, _u32(pool_id))
-            else:
-                xs = masked + _u32(pool_id)
-            return xs
+        def pps(ps):
+            return _pps(ps, pgp_num, pgp_mask, pool_id, hashps)
+
+        def descend(start, dev_weights):
+            xs = pps(jnp.arange(n, dtype=jnp.uint32) + start)
+            if not kt_tail:
+                raw, flag = core(xs, dev_weights)
+                return xs, raw, flag, jnp.zeros((3,), jnp.int32)
+            raw, flag, unfinished = first(xs, dev_weights)
+            idx, _valid, cnt = rc(unfinished)
+            raw_t, flag_t = core(pps(idx.astype(jnp.uint32) + start),
+                                 dev_weights)
+            # a seated lane takes its replayed row and flag; one that
+            # got no slot keeps its row, flagged for the resolve chain
+            expand = pallas_draw.make_rowexpand_kernel(
+                n, self.RC_ROW, kt_tail, raw.shape[1] + 1)
+            rows = expand(
+                unfinished,
+                jnp.concatenate(
+                    [raw, (flag | unfinished)[:, None].astype(jnp.int32)],
+                    axis=1),
+                jnp.concatenate(
+                    [raw_t, flag_t[:, None].astype(jnp.int32)], axis=1))
+            seated = jnp.minimum(cnt, kt_tail)
+            return (xs, rows[:, :-1], rows[:, -1] != 0,
+                    jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
+                               jnp.max(cnt)]))
 
         def post(raw, xs, exists_b, isup_b, aff):
             if not use_aff:
-                from . import pallas_draw
                 if (pallas_draw.pallas_enabled()
                         and raw.shape[0] % pallas_draw.TL == 0):
                     pk = self._post_kernel(int(exists_b.shape[0]),
@@ -1517,19 +1662,22 @@ class DeviceMapper:
         @jax.jit
         def run(dev_weights, exists_b, isup_b, aff):
             def body(_, start):
-                xs = chunk(start)
                 with jax.named_scope("crush_descend"):
-                    raw, flag = core(xs, dev_weights)
+                    xs, raw, flag, tail = descend(start, dev_weights)
                 with jax.named_scope("crush_post"):
                     up, prim = post(raw, xs, exists_b, isup_b, aff)
-                return 0, (raw, up, prim, flag)
+                return 0, (raw, up, prim, flag, tail)
 
             starts = (jnp.arange(n_chunks, dtype=jnp.uint32)
                       * _u32(n))
-            _, (raws, ups, prims, flags) = jax.lax.scan(body, 0, starts)
+            _, (raws, ups, prims, flags, tails) = jax.lax.scan(
+                body, 0, starts)
             S = ups.shape[2]
             return (raws.reshape(-1, S), ups.reshape(-1, S),
-                    prims.reshape(-1), flags.reshape(-1))
+                    prims.reshape(-1), flags.reshape(-1),
+                    jnp.stack([jnp.sum(tails[:, 0]),
+                               jnp.sum(tails[:, 1]),
+                               jnp.max(tails[:, 2])]))
 
         return run
 
@@ -1557,13 +1705,7 @@ class DeviceMapper:
         acore = self._compile(ruleno, result_max, "all", True)
 
         def pps(idx):
-            ps = idx.astype(jnp.uint32)
-            masked = jnp.where((ps & _u32(pgp_mask)) < _u32(pgp_num),
-                               ps & _u32(pgp_mask),
-                               ps & _u32(pgp_mask >> 1))
-            if hashps:
-                return hash32_2_j(masked, _u32(pool_id))
-            return masked + _u32(pool_id)
+            return _pps(idx, pgp_num, pgp_mask, pool_id, hashps)
 
         def settle(core_fn, raw_t, up, prim, lanes, w, ex, iu, af):
             xs = pps(lanes)
@@ -1626,7 +1768,8 @@ class DeviceMapper:
         """Device-resident resolve for the full-map pass: compact the
         flagged lanes, settle them through the three-stage chain, and
         scatter back — the only host traffic is the overflow-guard
-        counters (every readback is a host round trip).
+        counters (every readback is a host round trip); `tail`, the
+        dense pass's own three counters, rides along in them.
 
         kt > 0 uses the pallas rowcompact kernel for the first
         compaction: XLA's nonzero over the full PG axis is the single
@@ -1646,7 +1789,7 @@ class DeviceMapper:
 
         @jax.jit
         @jax.named_scope("crush_resolve")
-        def run(raw_t, up, prim, flag, w, ex, iu, af):
+        def run(raw_t, up, prim, flag, tail, w, ex, iu, af):
             if rc is not None:
                 idxp, validp, cnt = rc(flag)
                 nflag = jnp.sum(validp, dtype=jnp.int32)
@@ -1654,15 +1797,16 @@ class DeviceMapper:
                 raw_t, up, prim, n2, n3 = chain(
                     raw_t, up, prim, validp, nflag,
                     lambda p: idxp[p], w, ex, iu, af)
-                return raw_t, up, prim, jnp.stack(
-                    [nflag, n2, n3, rowmax])
-            flag2 = flag & (jnp.arange(npg, dtype=jnp.int32) < pg_num)
-            nflag = jnp.sum(flag2, dtype=jnp.int32)
-            raw_t, up, prim, n2, n3 = chain(
-                raw_t, up, prim, flag2, nflag, lambda p: p, w, ex, iu,
-                af)
-            return raw_t, up, prim, jnp.stack(
-                [nflag, n2, n3, jnp.int32(0)])
+            else:
+                flag2 = flag & (jnp.arange(npg, dtype=jnp.int32)
+                                < pg_num)
+                nflag = jnp.sum(flag2, dtype=jnp.int32)
+                rowmax = jnp.int32(0)
+                raw_t, up, prim, n2, n3 = chain(
+                    raw_t, up, prim, flag2, nflag, lambda p: p, w, ex,
+                    iu, af)
+            return raw_t, up, prim, jnp.concatenate(
+                [jnp.stack([nflag, n2, n3, rowmax]), tail])
 
         return run
 
@@ -1702,29 +1846,50 @@ class DeviceMapper:
         C = min(self.CHUNK, max(8, -(-pg_num // 8) * 8))
         n_chunks = -(-pg_num // C)
         npg = C * n_chunks
-        fn = self._compiled_pool(ruleno, result_max, bool(can_shift),
-                                 use_aff, int(pgp_num),
-                                 int(pgp_num_mask), int(pool_id),
-                                 bool(hashpspool), C, n_chunks)
-        with span("crush.launch", lanes=npg, pallas_lanes=(
-                npg if self.fm.descent_in_pallas.get(C) else 0)):
-            raw, up, prim, flag = fn(w, ex, iu, af)
         K1 = max(64, min(1 << 16,
                          1 << (max(1, pg_num - 1)).bit_length()))
         K2 = max(8, min(1 << 13, K1))
         K3 = max(8, min(2048, K1))
         kt = self.RC_KT if self._rc_ok(npg) else 0
+        tail_key = (ruleno, result_max, C)
+        dense = None
         while True:
+            if dense is None:
+                kt_tail = self._tail_slots(
+                    ruleno, result_max, C,
+                    self._tail_want.get(tail_key, self.TAIL_KT))
+                fn = self._compiled_pool(
+                    ruleno, result_max, bool(can_shift), use_aff,
+                    int(pgp_num), int(pgp_num_mask), int(pool_id),
+                    bool(hashpspool), C, n_chunks, kt_tail)
+                in_pallas = self.fm.descent_in_pallas
+                with span("crush.launch", lanes=npg, pallas_lanes=(
+                        npg if in_pallas.get(C) and (
+                            not kt_tail
+                            or in_pallas.get(C // self.RC_ROW * kt_tail))
+                        else 0)):
+                    dense = fn(w, ex, iu, af)
             res = self._compiled_device_resolve(
                 ruleno, result_max, bool(can_shift), use_aff,
                 int(pgp_num), int(pgp_num_mask), int(pool_id),
                 bool(hashpspool), K1, K2, K3, npg, pg_num, kt)
             with span("crush.launch"):
-                raw2, up2, prim2, counts = res(raw, up, prim, flag,
-                                               w, ex, iu, af)
+                raw2, up2, prim2, counts = res(*dense, w, ex, iu, af)
             with span("crush.wait"):
-                nflag, n2, ndust, rowmax = (
-                    int(v) for v in np.asarray(counts))
+                (nflag, n2, ndust, rowmax, tail_lanes, unseated,
+                 tail_max) = (int(v) for v in np.asarray(counts))
+            if unseated * 16 > tail_lanes:
+                # row groups with more unplaced lanes than the tail has
+                # slots left the rest to the resolve chain, flagged: a
+                # few cost nothing and change no program, but past a
+                # sixteenth of the tail the pass is thrown away and
+                # this pool's passes run a wider tail from here on, or
+                # dense rounds past TAIL_KT_MAX (until the crush map,
+                # and with it this mapper, is replaced)
+                self.tail_overflows += 1
+                self._tail_want[tail_key] = tail_max + tail_max // 4
+                dense = None
+                continue
             if kt and rowmax > kt:
                 # a row group overflowed its compaction slots: widen
                 kt = 128 * (-(-int(rowmax * 2) // 128))
@@ -1737,10 +1902,13 @@ class DeviceMapper:
             K2 = max(K2, min(1 << (max(1, n2 - 1)).bit_length(), K1))
             K3 = max(K3, min(1 << (max(1, ndust - 1)).bit_length(),
                              K1))
+        mark("crush.lanes", lanes=npg, tail_lanes=tail_lanes,
+             resolve_lanes=nflag)
         return MapState(
             self, ruleno, result_max, pg_num, pgp_num, pgp_num_mask,
             pool_id, bool(hashpspool), bool(can_shift), use_aff,
-            raw2, up2, prim2, w_np, ex_np, iu_np, af_np, npg)
+            raw2, up2, prim2, w_np, ex_np, iu_np, af_np, npg,
+            lanes=npg, tail_lanes=tail_lanes, resolve_lanes=nflag)
 
     @functools.lru_cache(maxsize=None)
     def _compiled_remap(self, ruleno: int, result_max: int,

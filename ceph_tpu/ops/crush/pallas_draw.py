@@ -732,3 +732,74 @@ def make_rowcompact_kernel(n_lanes: int, row: int, kt: int,
         return (idx.reshape(-1), val.reshape(-1) != 0, cnt[:, 0])
 
     return run
+
+
+def make_rowexpand_kernel(n_lanes: int, row: int, kt: int, words: int):
+    """rowcompact's inverse, for the rows computed on its compacted
+    lanes: a hit lane takes the row at its group's slot number rank(l),
+    the count of hits before it in its row group, which is where
+    rowcompact seated it; a lane that is no hit, or whose group had no
+    slot left for it (rank >= kt), keeps its old row.  Pad slots are
+    never read, so nothing depends on what was computed there.
+
+    No gather and no scatter (both run at scalar rate on TPU): per
+    group one int8 one-hot [kt, row] of slot against rank, and one MXU
+    matmul of the new rows' 8-bit limb planes [4*words, kt] with it;
+    one slot is hot per seated lane, so each product IS a limb.
+
+    Returns fn(hit [n_lanes] bool, old [n_lanes, words] i32,
+               new [n_lanes/row*kt, words] i32) -> [n_lanes, words] i32.
+    """
+    from jax.experimental import pallas as pl
+
+    if n_lanes % (8 * row) or row % 128 or kt % 128:
+        raise ValueError("rowexpand: n_lanes %d / row %d / kt %d "
+                         "misaligned" % (n_lanes, row, kt))
+    nr = n_lanes // row
+    interp = _interpret()
+    i8, i32, u32 = jnp.int8, jnp.int32, jnp.uint32
+    c32 = np.int32
+
+    def kern(hit_ref, rank_ref, new_ref, *refs):
+        old_refs, out_refs = refs[:words], refs[words:]
+        iota_k = jax.lax.broadcasted_iota(i32, (kt, row), 0)
+        for s in range(8):
+            rk = rank_ref[s:s + 1, :]                    # [1, row]
+            seated = (hit_ref[s:s + 1, :] != 0) & (rk < c32(kt))
+            oh = ((iota_k == rk) & seated).astype(i8)    # [kt, row]
+            f = jax.lax.dot_general(
+                new_ref[s], oh, (((1,), (0,)), ((), ())),
+                preferred_element_type=i32)              # [4*words, row]
+            for w in range(words):
+                val = _unpack_rows(f, 1, 4, 4 * w)
+                out_refs[w][s:s + 1, :] = jnp.where(
+                    seated, val, old_refs[w][s:s + 1, :])
+
+    @jax.jit
+    def run(hit, old, new):
+        h2 = hit.astype(i32).reshape(nr, row)
+        rank = jnp.cumsum(h2, axis=1, dtype=i32) - 1
+        # [nr, 4*words, kt] int8: word-major, then limb, biased by -128
+        nu = new.astype(i32).astype(u32).reshape(nr, kt, words)
+        nu = jnp.transpose(nu, (0, 2, 1))[:, :, None, :]
+        shifts = (jnp.arange(4, dtype=u32) * u32(8))[None, None, :, None]
+        limbs = (((nu >> shifts) & u32(0xFF)).astype(i32) - 128).astype(i8)
+        limbs = limbs.reshape(nr, 4 * words, kt)
+        lane = pl.BlockSpec((8, row), lambda i: (i32(i), i32(0)))
+        shp = jax.ShapeDtypeStruct((nr, row), i32)
+        outs = pl.pallas_call(
+            kern,
+            grid=(nr // 8,),
+            in_specs=[lane, lane,
+                      pl.BlockSpec((8, 4 * words, kt),
+                                   lambda i: (i32(i), i32(0), i32(0)))]
+                     + [lane] * words,
+            out_specs=tuple([lane] * words),
+            out_shape=tuple([shp] * words),
+            interpret=interp,
+            name="crush_rowexpand",
+        )(h2, rank, limbs,
+          *[old[:, w].astype(i32).reshape(nr, row) for w in range(words)])
+        return jnp.stack([o.reshape(n_lanes) for o in outs], axis=1)
+
+    return run

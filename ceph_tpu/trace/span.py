@@ -145,6 +145,11 @@ SPANS: dict[str, tuple[str, str]] = {
                      "before the device ends); on the pool program lanes, "
                      "and pallas_lanes whose descent was built in Pallas "
                      "(0 on the call that first traces it)"),
+    "crush.lanes": (MAPPING, "mark: one whole-pool pass counted, after "
+                    "its one blocking read: lanes of the dense pass, "
+                    "tail_lanes of them left unplaced by the first "
+                    "optimistic rounds and replayed on the compacted "
+                    "tail, resolve_lanes flagged to the resolve chain"),
     "crush.wait": (MAPPING, "the first blocking read: the host waits, "
                    "the device works"),
     "crush.readback": (MAPPING, "up/acting tables device -> host; bytes"),

@@ -286,9 +286,10 @@ class TestF32Draw:
 
 
 class TestLargeBatch:
-    """Exercises the optimistic-attempt + compacted-tail path
-    (L >= _ATTEMPT_MIN_L) and the pass-2 resolve flow, sampled against
-    the host engine."""
+    """Exercises the attempt structure (L >= _ATTEMPT_MIN_L: a fixed
+    number of optimistic rounds per replica, every one over all lanes)
+    and the pass-2 resolve flow, sampled against the host engine.  The
+    dense pass's compacted tail is TestDenseTail's."""
 
     @pytest.mark.parametrize("ruleno", [0, 1])
     def test_attempt_path_parity(self, ruleno):
@@ -313,6 +314,165 @@ class TestLargeBatch:
                 assert got[i].tolist() == expect, (i, int(xs[i]))
         finally:
             D._ATTEMPT_MIN_L = old
+
+
+class TestDenseTail:
+    """A whole pool's dense pass (map_pool_state) with the tail: only
+    the first optimistic round of each replica over all lanes, the
+    lanes it leaves unplaced compacted (Pallas rowcompact, interpret
+    mode here), replayed at that width and scattered back.  Sampled
+    lanes bit-equal to the host engine whatever share of the OSDs is
+    out or reweighted, and the counters say which way a pass went."""
+
+    HOSTS, PER_HOST, PG_NUM = 40, 5, 16384
+    # rule 0: chooseleaf firstn 0 type host; rule 1: choose firstn 0
+    # type osd (a two-level descent, reweights reject at the leaf)
+    RULES = {0: (CHOOSELEAF_FIRSTN, 1), 1: (CHOOSE_FIRSTN, 0)}
+    _dm: dict = {}
+
+    @classmethod
+    def _mapper(cls):
+        """One DeviceMapper for every case: reweights are inputs of the
+        programs, so the cases share what interpret mode compiles."""
+        if "dm" not in cls._dm:
+            m = CrushMap()
+            host_ids = []
+            for h in range(cls.HOSTS):
+                items = list(range(h * cls.PER_HOST,
+                                   (h + 1) * cls.PER_HOST))
+                b = m.add_bucket(STRAW2, 1, items,
+                                 [0x10000] * cls.PER_HOST, id=-(h + 2))
+                host_ids.append(b.id)
+            m.add_bucket(STRAW2, 2, host_ids,
+                         [m.buckets[h].weight for h in host_ids], id=-1)
+            for ruleno, (op, want) in cls.RULES.items():
+                m.add_rule([(TAKE, -1, 0), (op, 0, want), (EMIT, 0, 0)],
+                           id=ruleno)
+            cls._dm["dm"] = DeviceMapper(m)
+            cls._dm["map"] = m
+        return cls._dm["dm"], cls._dm["map"]
+
+    @classmethod
+    def _weights(cls, share):
+        """`share` of the OSDs touched, whole hosts first: two of three
+        out, the third reweighted to 0.375."""
+        n = cls.HOSTS * cls.PER_HOST
+        w = np.full(n, 0x10000, np.int32)
+        for i, o in enumerate(range(int(round(share * n)))):
+            w[o] = 0x6000 if i % 3 == 2 else 0
+        return w
+
+    def _pass(self, dm, ruleno, result_max, w):
+        n = len(w)
+        return dm.map_pool_state(
+            ruleno, result_max, self.PG_NUM, self.PG_NUM,
+            self.PG_NUM - 1, 1, True, w, np.ones(n, bool), w > 0, None,
+            True)
+
+    def _assert_rows(self, m, ruleno, result_max, w, raw, lanes):
+        from ceph_tpu.ops.crush.hashes import hash32_2
+        host = Mapper(m)
+        weights = [int(v) for v in w]
+        for pg in lanes:
+            expect = host.do_rule(ruleno, hash32_2(pg, 1), result_max,
+                                  weights)
+            expect = expect + [0x7FFFFFFF] * (result_max - len(expect))
+            assert raw[pg].tolist() == expect, (pg, ruleno)
+
+    @pytest.fixture(autouse=True)
+    def _interpret(self, monkeypatch):
+        from ceph_tpu.ops.crush import device as D
+        monkeypatch.setenv("CEPH_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(D, "_ATTEMPT_MIN_L", 4096)
+
+    @pytest.mark.parametrize("share", [0.0, 0.01, 0.10, 0.40])
+    @pytest.mark.parametrize("ruleno", [0, 1])
+    def test_tail_parity_and_counters(self, ruleno, share):
+        dm, m = self._mapper()
+        dm._tail_want.clear()
+        w = self._weights(share)
+        slots = dm._tail_slots(ruleno, 2, self.PG_NUM, dm.TAIL_KT)
+        assert slots and (self.PG_NUM // dm.RC_ROW * slots
+                          ) % 4096 == 0, "the tail must stay in Pallas"
+        overflows = dm.tail_overflows
+        st = self._pass(dm, ruleno, 2, w)
+        raw = np.asarray(st.raw)
+        lanes = random.Random(ruleno * 7 + 1).sample(
+            range(self.PG_NUM), 300)
+        self._assert_rows(m, ruleno, 2, w, raw, lanes)
+        assert st.lanes == self.PG_NUM
+        assert dm.fm.descent_in_pallas[self.PG_NUM]
+        if share < 0.40:
+            # collisions alone (1 host in 40) leave lanes unplaced
+            assert dm.tail_overflows == overflows
+            assert 0 < st.tail_lanes < self.PG_NUM // 4
+            assert st.resolve_lanes < st.tail_lanes
+            return
+        # more unplaced lanes than slots: the pass was thrown away and
+        # the pool went back to dense rounds, with no tail
+        assert dm.tail_overflows == overflows + 1
+        assert st.tail_lanes == 0
+        assert dm._tail_slots(ruleno, 2, self.PG_NUM, dm._tail_want[
+            (ruleno, 2, self.PG_NUM)]) == 0
+        # and the overflowing program itself flagged what it could not
+        # seat, rather than cut it off: every lane it leaves unflagged
+        # is exact
+        import jax.numpy as jnp
+        fn = dm._compiled_pool(ruleno, 2, True, False, self.PG_NUM,
+                               self.PG_NUM - 1, 1, True, self.PG_NUM,
+                               1, slots)
+        raw1, _up, _prim, flag, tail = fn(
+            jnp.asarray(w), jnp.ones(len(w), bool), jnp.asarray(w > 0),
+            jnp.zeros(len(w), jnp.int32))
+        flag = np.asarray(flag)
+        seated, unseated, largest = (int(v) for v in np.asarray(tail))
+        assert largest > slots
+        assert seated == self.PG_NUM // dm.RC_ROW * slots
+        assert unseated * 16 > seated and flag.sum() >= unseated
+        self._assert_rows(m, ruleno, 2, w, np.asarray(raw1),
+                          [pg for pg in lanes if not flag[pg]])
+
+    @pytest.mark.parametrize("lanes,want,slots", [
+        (1 << 20, 256, 256),    # DeviceMapper.CHUNK: 512 groups
+        (1 << 20, 300, 384),    # widened by a pass that ran over
+        (1 << 20, 513, 0),      # past TAIL_KT_MAX: dense rounds
+        (32768, 256, 256),      # 16 groups x 256 = one Pallas tile
+        (16384, 256, 512),      # 8 groups need 512 to fill a tile
+        (49152, 256, 512),      # 24 groups: gcd with the tile is 8
+        (20000, 256, 0),        # no multiple of 8 row groups
+        (8192, 256, 0),         # under _ATTEMPT_MIN_L (16384 here)
+    ])
+    def test_tail_slots(self, monkeypatch, lanes, want, slots):
+        """The tail's width follows from what the code can see: the
+        chunk's lanes, rowcompact's alignment, the Pallas tile and the
+        slots a pass was found to need."""
+        from ceph_tpu.ops.crush import device as D
+        monkeypatch.setattr(D, "_ATTEMPT_MIN_L", 16384)
+        dm, _m = self._mapper()
+        assert dm._tail_slots(0, 2, lanes, want) == slots
+        if slots:
+            assert (lanes // dm.RC_ROW * slots) % 4096 == 0
+
+    def test_no_tail_for_indep_or_without_pallas(self, monkeypatch):
+        m = _two_level_map()
+        dm = DeviceMapper(m)
+        assert dm._tail_slots(0, 3, 1 << 20, dm.TAIL_KT) == 256
+        assert dm._tail_slots(1, 3, 1 << 20, dm.TAIL_KT) == 0    # indep
+        monkeypatch.delenv("CEPH_TPU_PALLAS_INTERPRET")
+        assert dm._tail_slots(0, 3, 1 << 20, dm.TAIL_KT) == 0
+
+    def test_no_tail_lanes_when_nothing_can_fail(self):
+        """One replica and nothing out: no collision, no rejection, so
+        the first rounds place every lane and the tail seats none."""
+        dm, m = self._mapper()
+        dm._tail_want.clear()
+        w = self._weights(0.0)
+        st = self._pass(dm, 1, 1, w)
+        assert st.tail_lanes == 0 and dm.tail_overflows >= 0
+        assert st.lanes == self.PG_NUM
+        self._assert_rows(m, 1, 1, w, np.asarray(st.raw),
+                          random.Random(3).sample(range(self.PG_NUM),
+                                                  100))
 
 
 class TestMapStateRemap:

@@ -222,3 +222,54 @@ def test_rowcompact_remap_parity_padded_pgnum():
                                   np.asarray(ref.up))
     np.testing.assert_array_equal(np.asarray(st2.prim),
                                   np.asarray(ref.prim))
+
+
+@pytest.mark.parametrize("density", [0.0, 0.087, 0.2])
+def test_rowcompact_tail_geometry(density):
+    """rowcompact as the dense pass's tail calls it: one chunk's own
+    index space (no pg_num cut), 2048-lane row groups, 256 slots.  Each
+    group's hits land in its first slots in lane order, pad slots carry
+    the group's first lane, and a group with more hits than slots seats
+    the first 256 and says so in its count."""
+    n, row, kt = 32768, dev.DeviceMapper.RC_ROW, dev.DeviceMapper.TAIL_KT
+    rng = np.random.default_rng(int(density * 1000) + 5)
+    hit = rng.random(n) < density
+    rc = pd.make_rowcompact_kernel(n, row, kt, n)
+    idx, valid, cnt = (np.asarray(a) for a in rc(jnp.asarray(hit)))
+    idx, valid = idx.reshape(n // row, kt), valid.reshape(n // row, kt)
+    overflowed = 0
+    for g in range(n // row):
+        lanes = np.nonzero(hit[g * row:(g + 1) * row])[0] + g * row
+        assert cnt[g] == len(lanes)
+        seated = min(len(lanes), kt)
+        overflowed += len(lanes) > kt
+        np.testing.assert_array_equal(idx[g, :seated], lanes[:seated])
+        assert valid[g, :seated].all() and not valid[g, seated:].any()
+        assert (idx[g, seated:] == g * row).all()
+    assert overflowed == (n // row if density == 0.2 else 0)
+
+
+@pytest.mark.parametrize("density", [0.0, 0.087, 0.2])
+def test_rowexpand_puts_compacted_rows_back(density):
+    """rowexpand after rowcompact, at the tail's geometry: a seated
+    lane gets the row computed at its slot (negative ids and ITEM_NONE
+    survive the 8-bit limbs), every other lane keeps its old row, a
+    lane its full group could not seat among them, and nothing is read
+    from a pad slot."""
+    n, row, kt, words = 32768, dev.DeviceMapper.RC_ROW, 256, 4
+    rng = np.random.default_rng(int(density * 1000) + 11)
+    hit = rng.random(n) < density
+    old = rng.integers(-2 ** 31, 2 ** 31, (n, words)).astype(np.int32)
+    new = rng.integers(-2 ** 31, 2 ** 31,
+                       (n // row * kt, words)).astype(np.int32)
+    new[::7, 1] = 0x7FFFFFFF
+    idx, valid, _cnt = (np.asarray(a) for a in pd.make_rowcompact_kernel(
+        n, row, kt, n)(jnp.asarray(hit)))
+    got = np.asarray(pd.make_rowexpand_kernel(n, row, kt, words)(
+        jnp.asarray(hit), jnp.asarray(old), jnp.asarray(new)))
+    want = old.copy()
+    want[idx[valid]] = new[valid]
+    np.testing.assert_array_equal(got, want)
+    assert (got[~hit] == old[~hit]).all()
+    assert int(valid.sum()) == (n // row * kt if density == 0.2
+                                else int(hit.sum()))

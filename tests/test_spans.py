@@ -47,7 +47,7 @@ DELTA_PATH = ("rbd.write", "rbd.read", "osd.ec.delta_plan",
               "ec.delta_prepare", "ec.delta_collect", "ec.dispatch",
               "osd.ec.sub_read", "osd.ec.submit", "op.retired")
 REMAP_PATH = ("crush.build", "crush.upload", "crush.launch", "crush.wait",
-              "crush.readback", "crush.tables")
+              "crush.lanes", "crush.readback", "crush.tables")
 # the first EC write of a process compiles on the loop every daemon
 # shares; at FAST_CONF's 0.6 s grace that stall gets all three OSDs
 # marked down at once (ROADMAP A-first) and the write in flight can come
@@ -334,6 +334,10 @@ def test_traced_remap_counts_lanes_and_bytes(traced_remap):
     lanes = [s for _a, _b, s in by["crush.launch"] if "lanes" in s]
     # 256 PGs is no multiple of the Pallas tile: the XLA descent, counted
     assert [(s["lanes"], s["pallas_lanes"]) for s in lanes] == [(256, 0)]
+    # one pass counted: too small a pool for a tail, a few lanes flagged
+    (_a, _b, counted), = by["crush.lanes"]
+    assert counted["lanes"] == 256 and counted["tail_lanes"] == 0
+    assert 0 <= counted["resolve_lanes"] < 256
     (_a, _b, back), = by["crush.readback"]
     assert back["bytes"] == pm.up.nbytes + pm.up_primary.nbytes
 

@@ -100,13 +100,17 @@ def test_crush_descend_compiles(one_chip, mosaic, depth_sizes,
     assert fn is not None, "map outside the kernel's table budget"
     lanes = ((1 << 20,), jnp.int32)     # DeviceMapper.CHUNK
     _compiled_text(fn, one_chip, lanes, lanes, lanes, lanes)
+    # the dense pass's tail: CHUNK / RC_ROW row groups of TAIL_KT slots
+    tail = ((1 << 17,), jnp.int32)
+    _compiled_text(fn, one_chip, tail, tail, tail, tail)
 
 
 # the 10M-PG pool of the smoke: ten DeviceMapper.CHUNK-sized chunks
 NPG = 10 << 20
 
 
-@pytest.mark.parametrize("kernel", ["post", "hitscan", "rowcompact"])
+@pytest.mark.parametrize("kernel", ["post", "hitscan", "rowcompact",
+                                    "rowcompact-tail", "rowexpand-tail"])
 def test_crush_lane_kernels_compile(one_chip, mosaic, kernel):
     from ceph_tpu.ops.crush import pallas_draw
     from ceph_tpu.ops.crush.device import DeviceMapper
@@ -118,7 +122,21 @@ def test_crush_lane_kernels_compile(one_chip, mosaic, kernel):
     elif kernel == "hitscan":
         fn = pallas_draw.make_hitscan_kernel(1000, 3)
         _compiled_text(fn, one_chip, raw, osds)
-    else:
+    elif kernel == "rowcompact":
         fn = pallas_draw.make_rowcompact_kernel(
             NPG, DeviceMapper.RC_ROW, DeviceMapper.RC_KT, 10_000_000)
         _compiled_text(fn, one_chip, ((NPG,), np.bool_))
+    elif kernel == "rowcompact-tail":
+        # one chunk of the dense pass, its own index space
+        chunk = DeviceMapper.CHUNK
+        fn = pallas_draw.make_rowcompact_kernel(
+            chunk, DeviceMapper.RC_ROW, DeviceMapper.TAIL_KT, chunk)
+        _compiled_text(fn, one_chip, ((chunk,), np.bool_))
+    else:
+        # three replicas and the flag, at the widest tail there is
+        chunk, kt = DeviceMapper.CHUNK, DeviceMapper.TAIL_KT_MAX
+        fn = pallas_draw.make_rowexpand_kernel(
+            chunk, DeviceMapper.RC_ROW, kt, 4)
+        _compiled_text(fn, one_chip, ((chunk,), np.bool_),
+                       ((chunk, 4), jnp.int32),
+                       ((chunk // DeviceMapper.RC_ROW * kt, 4), jnp.int32))
