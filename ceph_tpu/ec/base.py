@@ -63,6 +63,44 @@ class ErasureCode(ErasureCodeInterface):
         if self.m < 1:
             raise ValueError("m=%d must be >= 1" % self.m)
 
+    # -- placement ---------------------------------------------------------
+
+    def _rule_steps(self) -> list[tuple[str, str, int]]:
+        """(op, type name, n) of the rule's choose steps, "choose" or
+        "chooseleaf", all indep."""
+        return [("chooseleaf",
+                 self._profile.get("crush-failure-domain") or "host", 0)]
+
+    def _rule_prologue(self) -> list[tuple[int, int, int]]:
+        """The set_* steps that open the rule."""
+        return []
+
+    def create_rule(self, name: str, crush) -> int:
+        """`take <crush-root>; chooseleaf indep 0 type
+        <crush-failure-domain>; emit` (ErasureCode::create_rule's
+        add_simple_rule(..., "indep", TYPE_ERASURE)); subclasses give
+        other steps.  A name that no type or bucket of the map carries
+        is an error, never a default."""
+        from ..models.crushmap import (CHOOSE_INDEP, CHOOSELEAF_INDEP,
+                                       EMIT, TAKE)
+        for rule in crush.rules.values():
+            if rule.name == name:
+                return rule.id
+        root = self._profile.get("crush-root") or "default"
+        roots = [b.id for b in crush.buckets.values() if b.name == root]
+        if not roots:
+            raise ValueError("crush-root %r: the map has no bucket of "
+                             "that name" % root)
+        type_ids = {tname: tid for tid, tname in crush.types.items()}
+        steps = self._rule_prologue() + [(TAKE, roots[0], 0)]
+        for op, tname, n in self._rule_steps():
+            if tname not in type_ids:
+                raise ValueError("the map has no type %r" % tname)
+            steps.append((CHOOSE_INDEP if op == "choose"
+                          else CHOOSELEAF_INDEP, n, type_ids[tname]))
+        steps.append((EMIT, 0, 0))
+        return crush.add_rule(steps, name=name).id
+
     # -- interface basics --------------------------------------------------
 
     def get_profile(self) -> ErasureCodeProfile:

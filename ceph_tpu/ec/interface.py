@@ -31,6 +31,14 @@ class ErasureCodeInterface(ABC):
         """The profile as completed by init (defaults filled in)."""
 
     @abstractmethod
+    def create_rule(self, name: str, crush) -> int:
+        """Add to `crush` (a CrushMap) the rule that places this codec's
+        chunks, under `name`, and return its id; an existing rule of
+        that name is returned as it is.  Reads the profile's crush-*
+        keys (ErasureCodeInterface::create_rule).  Raises ValueError
+        for a root or type the map does not name."""
+
+    @abstractmethod
     def get_chunk_count(self) -> int:
         """k + m: total chunks an object is encoded into."""
 

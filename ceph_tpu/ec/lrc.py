@@ -99,6 +99,30 @@ class ErasureCodeLrc(ErasureCode):
             layers.append([row, ""])
         profile["layers"] = json.dumps(layers)
 
+    # -- placement ---------------------------------------------------------
+
+    def _rule_steps(self) -> list[tuple[str, str, int]]:
+        """With crush-locality (and k, m, l): one local group to a
+        bucket of that type, each of its l + 1 chunks under another
+        failure domain (ErasureCodeLrc::parse_rule); without it the
+        base's single chooseleaf."""
+        locality = self._profile.get("crush-locality")
+        if not locality:
+            return super()._rule_steps()
+        lv = int(self._profile.get("l", 0))
+        if lv <= 0:
+            raise LrcError("crush-locality needs k, m and l")
+        return [("choose", locality, len(self.mapping) // (lv + 1)),
+                ("chooseleaf",
+                 self._profile.get("crush-failure-domain") or "host",
+                 lv + 1)]
+
+    def _rule_prologue(self) -> list[tuple[int, int, int]]:
+        """ErasureCodeLrc::create_rule opens with these two."""
+        from ..models.crushmap import (SET_CHOOSE_TRIES,
+                                       SET_CHOOSELEAF_TRIES)
+        return [(SET_CHOOSELEAF_TRIES, 5, 0), (SET_CHOOSE_TRIES, 100, 0)]
+
     def _layers_parse(self, description) -> None:
         if isinstance(description, str):
             if not description:

@@ -1352,6 +1352,20 @@ class Monitor:
                 cmd.get("profile", {}))
             self._propose_pending()
             return {}
+        if prefix == "osd setcrushmap":
+            # an operator's own hierarchy (racks, rooms) in place of
+            # the map the mon builds; `crush` is CrushMap.to_dict().
+            # An OSD that boots without a place in it still makes the
+            # mon rebuild its default shape (_ensure_in_crush)
+            inc = self._pending()
+            inc.new_crush = CrushMap.from_dict(cmd["crush"])
+            self._pending_crush_set = set(self._crush_members(
+                inc.new_crush))
+            self._propose_pending()
+            self.log_mon.append(
+                "INF", "crush map set (%d buckets, %d rules)"
+                % (len(inc.new_crush.buckets), len(inc.new_crush.rules)))
+            return {}
         if prefix == "osd out":
             inc = self._pending()
             inc.new_weight[int(cmd["id"])] = CEPH_OSD_OUT
@@ -1725,6 +1739,25 @@ class Monitor:
                 return pid
         raise ValueError("pool %r does not exist" % name)
 
+    def _ec_rule(self, pool_name: str, profile: dict, codec) -> int:
+        """The crush rule of a new erasure pool: the codec's own
+        (create_rule, from the profile's crush-* keys) on a map that
+        names its types, where a type or root the profile asks for and
+        the map lacks fails the command; rule 1, the mon's
+        erasure_rule, on the flat and host-only maps it builds itself,
+        which name no type but `osd`."""
+        inc = self._pending()
+        crush = inc.new_crush or self.osdmap.crush
+        if codec is None or set(crush.types.values()) <= {"osd"}:
+            return 1
+        if inc.new_crush is None:
+            crush = CrushMap.from_dict(crush.to_dict())
+        ruleno = codec.create_rule(pool_name, crush)
+        if inc.new_crush is None and ruleno not in self.osdmap.crush.rules:
+            inc.new_crush = crush
+            self._pending_crush_set = set(self._crush_members(crush))
+        return ruleno
+
     def _cmd_pool_create(self, cmd: dict) -> dict:
         name = cmd["pool"]
         for pool in self.osdmap.pools.values():
@@ -1749,6 +1782,7 @@ class Monitor:
             k = int(profile.get("k", 2))
             m = int(profile.get("m", 1))
             n = k + m
+            codec = None
             try:
                 # the codec is the authority on shard count: LRC's
                 # mapping adds local parities beyond k+m, so sizing
@@ -1761,9 +1795,12 @@ class Monitor:
                 n = codec.get_chunk_count()
             except Exception:
                 pass
+            rule = cmd.get("crush_rule")
+            if rule is None:
+                rule = self._ec_rule(name, profile, codec)
             pool = PGPool(id=pid, name=name, type=POOL_TYPE_ERASURE,
                           size=n, min_size=k, pg_num=pg_num,
-                          crush_rule=int(cmd.get("crush_rule", 1)),
+                          crush_rule=int(rule),
                           erasure_code_profile=pname)
         else:
             pool = PGPool(id=pid, name=name,

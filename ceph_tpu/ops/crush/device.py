@@ -46,8 +46,14 @@ loops.
 
 Device scope (the modern "optimal" tunables profile): straw2 buckets at
 every level, choose_local_tries == choose_local_fallback_tries == 0,
-rules of shape TAKE -> one CHOOSE/CHOOSELEAF step -> EMIT.  Anything
-else falls back to the host interpreter, which remains the general spec.
+rules of shape TAKE -> one or more CHOOSE/CHOOSELEAF steps -> EMIT, the
+steps all firstn or all indep (root -> rack -> host: `choose indep 2
+type rack; chooseleaf indep 4 type host`).  A later step takes per lane
+from the step before it, as crush_do_rule chains its working vector
+(_chain_step).  Anything else -- a rule that mixes firstn and indep
+steps, a second TAKE/EMIT pair, a step below a chooseleaf, a non-straw2
+bucket, local retries -- raises ValueError and falls back to the host
+interpreter, which remains the general spec.
 """
 
 from __future__ import annotations
@@ -818,17 +824,26 @@ _ATTEMPT_TRIES = 3
 # bookkeeping; run the full retry loops directly
 _ATTEMPT_MIN_L = 16384
 
+# chooseleaf retries an optimistic indep round runs at full width: with
+# set_chooseleaf_tries 5 and ten OSDs out of 10,000 a handful of lanes
+# in two million need a third, fourth and fifth try, and each was a
+# descent over all of them (PERF.md, PR 35); those lanes go to the
+# resolve chain instead
+_INDEP_LEAF_TRIES = 2
+
 
 def _firstn_full(fm: FlatMap, take_bid, xs, out, leaves, outpos,
                  numrep: int, result_max: int, want_type: int,
                  recurse_to_leaf: bool, dev_weights,
                  tries: int, recurse_tries: int, vary_r: int,
                  stable: int, outer_ds: tuple, inner_ds: tuple,
-                 resolve: bool, rootc: _ConstRow | None):
+                 resolve: bool, rootc: _ConstRow | None, limit=None):
     """crush_choose_firstn (mapper.c:438-626) for local-tries==0: per
     replica, retry whole descents while collided/rejected (masked
     lanes); chooseleaf recursion selects one leaf per chosen bucket.
-    Full retry semantics; every lane replays from ftotal=0."""
+    Full retry semantics; every lane replays from ftotal=0.  limit [L],
+    when given, is the lane's out_size: a lane that has placed as many
+    draws no more (0: the lane takes no part)."""
     L = xs.shape[0]
     result_slots = out.shape[1]
     flag0 = jnp.zeros((L,), bool)
@@ -895,7 +910,8 @@ def _firstn_full(fm: FlatMap, take_bid, xs, out, leaves, outpos,
             return ftotal, active, out, leaves, outpos, flag
 
         z = jnp.zeros((L,), jnp.int32)
-        act = jnp.ones((L,), bool)
+        act = (jnp.ones((L,), bool) if limit is None
+               else outpos < limit)
         _, _, out, leaves, outpos, flag = jax.lax.while_loop(
             lambda s: jnp.any(s[1]), body,
             (z, act, out, leaves, outpos, flag))
@@ -918,14 +934,22 @@ def _firstn_attempts(tries: int, recurse_to_leaf: bool,
     return min(_ATTEMPT_TRIES, tries)
 
 
-def _choose_firstn_vec(fm: FlatMap, take_bid_val: int, xs, numrep: int,
+def _take_lanes(take, L: int):
+    """The start bucket of every lane as bucket indices [L]: a rule's
+    TAKE is one bucket id, a later step's take is per lane already."""
+    if isinstance(take, int):
+        return jnp.full((L,), -1 - take, jnp.int32)
+    return take
+
+
+def _choose_firstn_vec(fm: FlatMap, take, xs, numrep: int,
                        result_max: int, want_type: int,
                        recurse_to_leaf: bool, dev_weights,
                        tries: int, recurse_tries: int, vary_r: int,
                        stable: int, outer_ds: tuple, inner_ds: tuple,
                        resolve: bool, full: bool,
                        rootc: _ConstRow | None,
-                       first_only: bool = False):
+                       first_only: bool = False, limit=None):
     """Fast-path firstn, the attempt structure: _firstn_attempts
     optimistic rounds per replica (ftotal = 0, 1, ...), every round over
     all L lanes; a lane still unplaced after them is left to the caller
@@ -937,10 +961,14 @@ def _choose_firstn_vec(fm: FlatMap, take_bid_val: int, xs, numrep: int,
     either has to be recomputed.  first_only runs the first round of
     each replica alone: the rows of its unfinished lanes are the state
     at their first failure and are replaced whole (the dense pass of
-    _compiled_pool, which replays them on a compacted tail)."""
+    _compiled_pool, which replays them on a compacted tail).
+
+    take: the rule's TAKE bucket id, or bucket indices [L] for a later
+    step (_chain_step); limit [L] then bounds what a lane places (0: the
+    lane's entry of the working vector was no bucket)."""
     L = xs.shape[0]
     slots = min(numrep, result_max)
-    take_bid = jnp.full((L,), -1 - take_bid_val, jnp.int32)
+    take_bid = _take_lanes(take, L)
     out0 = jnp.full((L, slots), ITEM_NONE, jnp.int32)
     leaves0 = jnp.full((L, slots), ITEM_NONE, jnp.int32)
     pos0 = jnp.zeros((L,), jnp.int32)
@@ -948,7 +976,7 @@ def _choose_firstn_vec(fm: FlatMap, take_bid_val: int, xs, numrep: int,
         out, leaves, outpos, flag = _firstn_full(
             fm, take_bid, xs, out0, leaves0, pos0, numrep, result_max,
             want_type, recurse_to_leaf, dev_weights, tries, recurse_tries,
-            vary_r, stable, outer_ds, inner_ds, resolve, rootc)
+            vary_r, stable, outer_ds, inner_ds, resolve, rootc, limit)
         return ((leaves if recurse_to_leaf else out), outpos, flag,
                 jnp.zeros((L,), bool))
 
@@ -965,6 +993,8 @@ def _choose_firstn_vec(fm: FlatMap, take_bid_val: int, xs, numrep: int,
         ft = (k % n_attempts).astype(jnp.int32)
         # a replica's first round finds it unplaced on every lane
         done_rep = done_rep & (ft > 0)
+        if limit is not None:
+            done_rep = done_rep | (outpos >= limit)
         r = jnp.zeros((L,), jnp.int32) + rep + ft
         item, ok, perm, f1 = _descend(fm, take_bid, xs, r, want_type,
                                       outpos, outer_ds, resolve, rootc)
@@ -1021,11 +1051,20 @@ def _indep_round(fm: FlatMap, take_bid, xs, ftotal, out, leaves, flag,
                  numrep: int, slots: int, want_type: int,
                  recurse_to_leaf: bool, dev_weights,
                  recurse_tries: int, outer_ds: tuple, inner_ds: tuple,
-                 resolve: bool, rootc: _ConstRow | None):
+                 resolve: bool, rootc: _ConstRow | None,
+                 leaf_cap: int | None = None):
     """One crush_choose_indep round (mapper.c:633-821): all UNDEF slots
-    draw with r = rep + numrep*ftotal."""
+    draw with r = rep + numrep*ftotal.
+
+    leaf_cap bounds the chooseleaf retries a round runs at its full
+    width (every one is a descent over all L lanes, for as long as one
+    lane still looks for its leaf): a lane that has tries left when the
+    cap is reached is flagged and recomputed by a more exact pass, as a
+    lane with an uncertain draw is.  None: all recurse_tries."""
     L = xs.shape[0]
     pos0 = jnp.zeros((L,), jnp.int32)
+    cap = recurse_tries if leaf_cap is None else min(leaf_cap,
+                                                     recurse_tries)
 
     def rep_body(rep, carry):
         out, leaves, flag = carry
@@ -1056,12 +1095,13 @@ def _indep_round(fm: FlatMap, take_bid, xs, ftotal, out, leaves, flag,
 
             izero = jnp.zeros((L,), jnp.int32)
             leaf0 = jnp.full((L,), ITEM_NONE, jnp.int32)
-            _, _, leaf, leaf_ok, iflag = jax.lax.while_loop(
-                lambda s: jnp.any(s[1]), inner_body,
+            ift, cut, leaf, leaf_ok, iflag = jax.lax.while_loop(
+                lambda s: jnp.any(s[1]) & (s[0][0] < cap), inner_body,
                 (izero, undecided & ok & ~collide, leaf0,
                  jnp.zeros((L,), bool), jnp.zeros((L,), bool)))
             final, final_ok = leaf, ok & leaf_ok
-            flag = flag | iflag
+            # cut: still looking, with tries left, when the cap came
+            flag = flag | iflag | cut
         else:
             final = item
             final_ok = ok
@@ -1080,16 +1120,25 @@ def _indep_round(fm: FlatMap, take_bid, xs, ftotal, out, leaves, flag,
     return jax.lax.fori_loop(0, slots, rep_body, (out, leaves, flag))
 
 
+def _indep_start(L: int, slots: int, nslots):
+    """The out vector before the first round: UNDEF where a slot is to
+    be drawn.  nslots [L], when given, is the lane's out_size: the
+    slots past it are never drawn and end as NONE."""
+    if nslots is None:
+        return jnp.full((L, slots), ITEM_UNDEF, jnp.int32)
+    return jnp.where(jnp.arange(slots)[None, :] < nslots[:, None],
+                     jnp.int32(ITEM_UNDEF), jnp.int32(ITEM_NONE))
+
+
 def _indep_full(fm: FlatMap, take_bid, xs, numrep: int, slots: int,
                 want_type: int, recurse_to_leaf: bool, dev_weights,
                 tries: int, recurse_tries: int, outer_ds: tuple,
                 inner_ds: tuple, resolve: bool,
-                rootc: _ConstRow | None):
+                rootc: _ConstRow | None, nslots=None):
     """Full positionally-stable retry loop: slots left UNDEF retry with
     r advanced by numrep per round."""
     L = xs.shape[0]
-    out = jnp.full((L, slots), ITEM_UNDEF, jnp.int32)
-    leaves = jnp.full((L, slots), ITEM_UNDEF, jnp.int32)
+    out = leaves = _indep_start(L, slots, nslots)
     flag = jnp.zeros((L,), bool)
 
     def body(state):
@@ -1111,39 +1160,106 @@ def _indep_full(fm: FlatMap, take_bid, xs, numrep: int, slots: int,
     return jnp.where(res == ITEM_UNDEF, ITEM_NONE, res), flag
 
 
-def _choose_indep_vec(fm: FlatMap, take_bid_val: int, xs, numrep: int,
+def _choose_indep_vec(fm: FlatMap, take, xs, numrep: int,
                       result_max: int, want_type: int,
                       recurse_to_leaf: bool, dev_weights,
                       tries: int, recurse_tries: int,
                       outer_ds: tuple, inner_ds: tuple,
                       resolve: bool, full: bool,
-                      rootc: _ConstRow | None):
+                      rootc: _ConstRow | None, nslots=None):
     """Fast-path indep: _ATTEMPT_TRIES optimistic full-width rounds
     (each an exact crush_choose_indep round, so chaining them is the
     reference retry semantics verbatim); lanes with UNDEF slots left
-    after them are flagged for the resolve pass."""
+    after them are flagged for the resolve pass.
+
+    Returns (rows, flag, retry): retry marks the lanes that still had
+    an undefined slot after the first full-width round (what a tail for
+    indep would replay; none where the full loops run).  take and nslots
+    as _choose_firstn_vec's take and limit."""
     L = xs.shape[0]
     slots = min(numrep, result_max)
-    take_bid = jnp.full((L,), -1 - take_bid_val, jnp.int32)
+    take_bid = _take_lanes(take, L)
     if full or L < _ATTEMPT_MIN_L:
         res, flag = _indep_full(fm, take_bid, xs, numrep, slots,
                                 want_type, recurse_to_leaf, dev_weights,
                                 tries, recurse_tries, outer_ds, inner_ds,
-                                resolve, rootc)
-        return res, flag
+                                resolve, rootc, nslots)
+        return res, flag, jnp.zeros((L,), bool)
 
-    out = jnp.full((L, slots), ITEM_UNDEF, jnp.int32)
-    leaves = jnp.full((L, slots), ITEM_UNDEF, jnp.int32)
+    out = leaves = _indep_start(L, slots, nslots)
     flag = jnp.zeros((L,), bool)
+    retry = flag
     for ft in range(min(_ATTEMPT_TRIES, tries)):
         out, leaves, flag = _indep_round(
             fm, take_bid, xs, jnp.full((), ft, jnp.int32), out, leaves,
             flag, numrep, slots, want_type, recurse_to_leaf,
             dev_weights, recurse_tries, outer_ds, inner_ds, resolve,
-            rootc)
+            rootc, _INDEP_LEAF_TRIES)
+        if ft == 0:
+            retry = jnp.any(out == ITEM_UNDEF, axis=1)
     res = leaves if recurse_to_leaf else out
     flag = flag | jnp.any(out == ITEM_UNDEF, axis=1)
-    return jnp.where(res == ITEM_UNDEF, ITEM_NONE, res), flag
+    return jnp.where(res == ITEM_UNDEF, ITEM_NONE, res), flag, retry
+
+
+def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
+                resolve, full: bool):
+    """One choose step after a rule's first, as crush_do_rule chains
+    them (mapper.c:878-1083): every entry of the working vector w
+    [L, n_in] that is a bucket is a take of its own, and what it
+    chooses goes to the lane's next free positions; an entry that is
+    none (ITEM_NONE, a device) is skipped and takes up no room.
+
+    Each take draws into a window of its own (outpos 0, parent_r 0,
+    collisions within the window only), so the n_in windows of a lane
+    are independent and run as n_in * L lanes of one choose: the
+    program holds a step's descents once, whatever n_in is.  An indep
+    window holds min(numrep, result_max - osize) slots, known before
+    any draw from which entries are buckets; a firstn window is filled
+    in order, so cutting it to result_max - osize afterwards is what
+    running it with that count gives.
+
+    Returns (rows [L, st.width] with NONE past a lane's osize, flag,
+    retry)."""
+    L, n_in = w.shape
+    slots = min(st.numrep, result_max)
+    valid = (w < 0).T                                      # [n_in, L]
+    take = jnp.where(valid, -1 - w.T, 0).reshape(-1)
+    xs_t = jnp.tile(xs, n_in)
+    if st.firstn:
+        rows, placed, flag, unfinished = _choose_firstn_vec(
+            fm, take, xs_t, st.numrep, result_max, st.want_type, st.leaf,
+            dev_weights, st.tries, st.recurse, st.vary_r, st.stable,
+            st.outer_ds, st.inner_ds, resolve, full, None,
+            limit=jnp.where(valid, slots, 0).reshape(-1))
+        flag = flag | unfinished
+        retry = jnp.zeros_like(flag)
+        placed = placed.reshape(n_in, L)
+    else:
+        osize = jnp.zeros((L,), jnp.int32)
+        placed = []
+        for i in range(n_in):
+            placed.append(jnp.where(
+                valid[i], jnp.minimum(slots, result_max - osize), 0))
+            osize = osize + placed[i]
+        placed = jnp.stack(placed)
+        rows, flag, retry = _choose_indep_vec(
+            fm, take, xs_t, st.numrep, result_max, st.want_type, st.leaf,
+            dev_weights, st.tries, st.recurse, st.outer_ds, st.inner_ds,
+            resolve, full, None, nslots=placed.reshape(-1))
+    rows = rows.reshape(n_in, L, slots)
+    flag = jnp.any(flag.reshape(n_in, L) & valid, axis=0)
+    retry = jnp.any(retry.reshape(n_in, L) & valid, axis=0)
+    out = jnp.full((L, st.width), ITEM_NONE, jnp.int32)
+    cols = jnp.arange(st.width)[None, :]
+    osize = jnp.zeros((L,), jnp.int32)
+    for i in range(n_in):
+        n_i = jnp.minimum(placed[i], result_max - osize)
+        for k in range(slots):
+            put = (cols == (osize + k)[:, None]) & (k < n_i)[:, None]
+            out = jnp.where(put, rows[i, :, k:k + 1], out)
+        osize = osize + n_i
+    return out, flag, retry
 
 
 # ---------------------------------------------------------------------------
@@ -1254,12 +1370,14 @@ class MapState:
                  "pgp_mask", "pool_id", "hashps", "can_shift",
                  "use_aff", "raw", "up_full", "prim_full", "w_np",
                  "ex_np", "iu_np", "af_np", "npg", "lanes",
-                 "tail_lanes", "resolve_lanes")
+                 "tail_lanes", "resolve_lanes", "steps", "retry_lanes",
+                 "none_slots")
 
     def __init__(self, dm, ruleno, result_max, pg_num, pgp_num,
                  pgp_mask, pool_id, hashps, can_shift, use_aff, raw,
                  up_full, prim_full, w_np, ex_np, iu_np, af_np, npg,
-                 lanes=0, tail_lanes=0, resolve_lanes=0):
+                 lanes=0, tail_lanes=0, resolve_lanes=0, steps=0,
+                 retry_lanes=0, none_slots=0):
         self.dm = dm
         self.ruleno = ruleno
         self.result_max = result_max
@@ -1284,6 +1402,13 @@ class MapState:
         self.lanes = lanes
         self.tail_lanes = tail_lanes
         self.resolve_lanes = resolve_lanes
+        # a whole-pool pass only: the rule's choose steps it ran on the
+        # device, the lanes an indep rule's first full-width round left
+        # with an undefined slot (what a tail for indep would replay),
+        # the up slots that ended ITEM_NONE
+        self.steps = steps
+        self.retry_lanes = retry_lanes
+        self.none_slots = none_slots
 
     @property
     def up(self):
@@ -1382,13 +1507,33 @@ class MapState:
             ex_np, iu_np, af_np, self.npg, lanes=nA, resolve_lanes=nf)
 
 
-_Plan = collections.namedtuple(
-    "_Plan", "firstn take_id numrep want_type leaf tries recurse vary_r "
-             "stable outer_ds inner_ds")
+# one choose step with the tunables in force where it stands in the rule;
+# width: entries of the working vector after it (static; a lane's own
+# osize may be less)
+_Step = collections.namedtuple(
+    "_Step", "firstn numrep want_type leaf tries recurse vary_r stable "
+             "outer_ds inner_ds width")
+
+
+class _Plan(collections.namedtuple("_Plan", "take_id steps")):
+    """A rule in device scope: TAKE, its choose steps in order, EMIT."""
+
+    __slots__ = ()
+
+    @property
+    def firstn(self) -> bool:
+        return self.steps[0].firstn
+
+    @property
+    def lane_factors(self) -> tuple:
+        """Per step, the lanes its choose runs on over the lanes of the
+        pass: the entries of the working vector it takes from."""
+        return (1,) + tuple(st.width for st in self.steps[:-1])
 
 
 class DeviceMapper:
-    """Bulk do_rule on device for straw2 maps with single-choose rules.
+    """Bulk do_rule on device for straw2 maps and rules of one TAKE, one
+    or more choose steps of one kind (firstn or indep) and one EMIT.
 
     do_rule_batch(ruleno, xs, result_max, dev_weights) mirrors
     CrushWrapper::do_rule over a whole batch of inputs; results carry
@@ -1409,11 +1554,15 @@ class DeviceMapper:
         # to find it
         self._tail_want: dict[tuple, int] = {}
         self.tail_overflows = 0
+        # (ruleno, result_max, lanes) -> the resolve chain's capacities
+        # (K1, K2, K3) a pass of the pool outgrew its first ones to: the
+        # next pass starts there and runs the chain once
+        self._chain_want: dict[tuple, tuple] = {}
 
     @functools.lru_cache(maxsize=None)
     def _plan(self, ruleno: int, result_max: int) -> "_Plan":
-        """The rule's one choose step with the tunables that govern
-        it."""
+        """The rule's choose steps, each with the tunables that govern
+        it; ValueError for a rule outside device scope."""
         rule = self.fm.rules[ruleno]
         t = self.fm.tunables
         tries = t.choose_total_tries + 1     # historical off-by-one
@@ -1421,9 +1570,13 @@ class DeviceMapper:
         vary_r = t.chooseleaf_vary_r
         stable = t.chooseleaf_stable
         take_id = None
-        plan = None
+        steps: list[_Step] = []
+        emitted = False
         for op, arg1, arg2 in rule.steps:
             if op == TAKE:
+                if steps:
+                    raise ValueError(
+                        "device mapper supports one TAKE/EMIT pair")
                 take_id = arg1
             elif op == SET_CHOOSE_TRIES:
                 if arg1 > 0:
@@ -1439,63 +1592,97 @@ class DeviceMapper:
                     stable = arg1
             elif op in (CHOOSE_FIRSTN, CHOOSELEAF_FIRSTN,
                         CHOOSE_INDEP, CHOOSELEAF_INDEP):
-                if plan is not None:
+                if emitted:
                     raise ValueError(
-                        "device mapper supports a single choose step")
+                        "device mapper supports one TAKE/EMIT pair")
                 if take_id is None or take_id >= 0:
                     raise ValueError("choose without a bucket take")
                 numrep = arg1
                 if numrep <= 0:
                     numrep += result_max
+                if numrep <= 0:
+                    raise ValueError("choose step of no replicas")
                 firstn = op in (CHOOSE_FIRSTN, CHOOSELEAF_FIRSTN)
                 leaf = op in (CHOOSELEAF_FIRSTN, CHOOSELEAF_INDEP)
-                plan = (take_id, numrep, arg2, firstn, leaf)
+                if firstn:
+                    recurse = (leaf_tries if leaf_tries else
+                               (1 if t.chooseleaf_descend_once else tries))
+                else:
+                    recurse = leaf_tries if leaf_tries else 1
+                if not steps:
+                    starts, n_in = [take_id], 1
+                else:
+                    prev = steps[-1]
+                    if prev.firstn != firstn:
+                        raise ValueError(
+                            "device mapper supports steps of one kind, "
+                            "firstn or indep")
+                    if prev.leaf or prev.want_type == 0:
+                        raise ValueError("choose step below devices")
+                    starts = [b.id for b in self.map.buckets.values()
+                              if b.type == prev.want_type]
+                    n_in = prev.width
+                outer_ds = self._depth_sizes(starts, arg2)
+                if leaf:
+                    inner_ds = self._depth_sizes(
+                        [b.id for b in self.map.buckets.values()
+                         if b.type == arg2], 0)
+                else:
+                    inner_ds = ()
+                steps.append(_Step(
+                    firstn, numrep, arg2, leaf, tries, recurse, vary_r,
+                    stable, outer_ds, inner_ds,
+                    min(result_max, n_in * min(numrep, result_max))))
             elif op == EMIT:
-                pass
-        if plan is None:
+                emitted = bool(steps)
+        if not steps:
             raise ValueError("rule has no choose step")
-        take_id, numrep, want_type, firstn, leaf = plan
-        if firstn:
-            recurse = (leaf_tries if leaf_tries
-                       else (1 if t.chooseleaf_descend_once else tries))
-        else:
-            recurse = leaf_tries if leaf_tries else 1
-        outer_ds = self._depth_sizes([take_id], want_type)
-        if leaf:
-            starts = [b.id for b in self.map.buckets.values()
-                      if b.type == want_type]
-            inner_ds = self._depth_sizes(starts, 0)
-        else:
-            inner_ds = ()
-        return _Plan(firstn, take_id, numrep, want_type, leaf, tries,
-                     recurse, vary_r, stable, outer_ds, inner_ds)
+        if len(steps) > 1 and any(
+                c < 0 and c not in self.map.buckets
+                for b in self.map.buckets.values() for c in b.items):
+            # the host skips a working-vector entry that names no
+            # bucket; the flat map cannot tell it from an empty one
+            raise ValueError("bucket item names no bucket")
+        return _Plan(take_id, tuple(steps))
 
     def _compile(self, ruleno: int, result_max: int, resolve: bool,
-                 full: bool = True, first_only: bool = False):
+                 full: bool = True, first_only: bool = False,
+                 stats: bool = False):
         """core(xs, dev_weights) -> (rows, flag): flag marks the lanes
-        that a more exact pass has to recompute.  first_only (firstn,
-        full=False): the first optimistic round of each replica alone,
-        -> (rows, flag, unfinished) with the unplaced lanes apart."""
+        that a more exact pass has to recompute.  first_only (one firstn
+        step, full=False): the first optimistic round of each replica
+        alone, -> (rows, flag, unfinished) with the unplaced lanes
+        apart.  stats: -> (rows, flag, retry), retry the lanes an indep
+        step's first full-width round left with an undefined slot."""
         plan = self._plan(ruleno, result_max)
         fm = self.fm
-        rootc = fm.const_row(plan.take_id, plan.outer_ds[0])
+        head = plan.steps[0]
+        rootc = fm.const_row(plan.take_id, head.outer_ds[0])
+        assert not first_only or len(plan.steps) == 1
 
         def core(xs, dev_weights):
-            if plan.firstn:
+            if head.firstn:
                 res, _, flag, unfinished = _choose_firstn_vec(
-                    fm, plan.take_id, xs, plan.numrep, result_max,
-                    plan.want_type, plan.leaf, dev_weights,
-                    plan.tries, plan.recurse, plan.vary_r,
-                    plan.stable, plan.outer_ds, plan.inner_ds,
+                    fm, plan.take_id, xs, head.numrep, result_max,
+                    head.want_type, head.leaf, dev_weights,
+                    head.tries, head.recurse, head.vary_r,
+                    head.stable, head.outer_ds, head.inner_ds,
                     resolve, full, rootc, first_only)
                 if first_only:
                     return res, flag, unfinished
-                return res, flag | unfinished
-            return _choose_indep_vec(
-                fm, plan.take_id, xs, plan.numrep, result_max,
-                plan.want_type, plan.leaf, dev_weights,
-                plan.tries, plan.recurse, plan.outer_ds,
-                plan.inner_ds, resolve, full, rootc)
+                flag = flag | unfinished
+                retry = jnp.zeros_like(flag)
+            else:
+                res, flag, retry = _choose_indep_vec(
+                    fm, plan.take_id, xs, head.numrep, result_max,
+                    head.want_type, head.leaf, dev_weights,
+                    head.tries, head.recurse, head.outer_ds,
+                    head.inner_ds, resolve, full, rootc)
+            for st in plan.steps[1:]:
+                res, f, rt = _chain_step(fm, st, res, xs, result_max,
+                                         dev_weights, resolve, full)
+                flag, retry = flag | f, retry | rt
+            return (res, flag, retry) if stats else (res, flag)
 
         return core
 
@@ -1568,15 +1755,17 @@ class DeviceMapper:
                     want: int) -> int:
         """Slots per row group (>= want) for the tail of a dense pass
         whose chunks are C lanes wide, or 0: no tail, every optimistic
-        round dense.  A tail needs a firstn rule with rounds to save,
-        the attempt structure (C >= _ATTEMPT_MIN_L), rowcompact's
-        alignment, and a width that keeps its descents in Pallas."""
+        round dense.  A tail needs a rule of one firstn step with
+        rounds to save, the attempt structure (C >= _ATTEMPT_MIN_L),
+        rowcompact's alignment, and a width that keeps its descents in
+        Pallas."""
         from . import pallas_draw
         plan = self._plan(ruleno, result_max)
-        if not (plan.firstn and C >= _ATTEMPT_MIN_L
-                and self._rc_ok(C)
-                and _firstn_attempts(plan.tries, plan.leaf,
-                                     plan.recurse) > 1):
+        head = plan.steps[0]
+        if not (plan.firstn and len(plan.steps) == 1
+                and C >= _ATTEMPT_MIN_L and self._rc_ok(C)
+                and _firstn_attempts(head.tries, head.leaf,
+                                     head.recurse) > 1):
             return 0
         nr = C // self.RC_ROW
         step = max(128, pallas_draw.TL // math.gcd(nr, pallas_draw.TL))
@@ -1607,13 +1796,17 @@ class DeviceMapper:
         lanes than slots keeps the rest flagged for the resolve chain;
         the pass's counts (lanes seated, lanes left unseated, largest
         group) tell the host when that is worth a wider tail.
-        kt_tail == 0: every round over all n lanes."""
+        kt_tail == 0: every round over all n lanes.
+
+        The pass's fourth count is the lanes an indep rule's first
+        full-width round left with an undefined slot (0 for firstn)."""
         self._note_compile("pool", (ruleno, result_max, can_shift,
                                     use_aff, pgp_num, pgp_mask,
                                     pool_id, hashps, n, n_chunks,
                                     kt_tail))
         from . import pallas_draw
-        core = self._compile(ruleno, result_max, False, full=False)
+        core = self._compile(ruleno, result_max, False, full=False,
+                             stats=not kt_tail)
         if kt_tail:
             first = self._compile(ruleno, result_max, False, full=False,
                                   first_only=True)
@@ -1626,8 +1819,10 @@ class DeviceMapper:
         def descend(start, dev_weights):
             xs = pps(jnp.arange(n, dtype=jnp.uint32) + start)
             if not kt_tail:
-                raw, flag = core(xs, dev_weights)
-                return xs, raw, flag, jnp.zeros((3,), jnp.int32)
+                raw, flag, retry = core(xs, dev_weights)
+                return xs, raw, flag, jnp.stack(
+                    [jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                     jnp.sum(retry, dtype=jnp.int32)])
             raw, flag, unfinished = first(xs, dev_weights)
             idx, _valid, cnt = rc(unfinished)
             raw_t, flag_t = core(pps(idx.astype(jnp.uint32) + start),
@@ -1646,7 +1841,7 @@ class DeviceMapper:
             seated = jnp.minimum(cnt, kt_tail)
             return (xs, rows[:, :-1], rows[:, -1] != 0,
                     jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
-                               jnp.max(cnt)]))
+                               jnp.max(cnt), jnp.int32(0)]))
 
         def post(raw, xs, exists_b, isup_b, aff):
             if not use_aff:
@@ -1677,7 +1872,8 @@ class DeviceMapper:
                     prims.reshape(-1), flags.reshape(-1),
                     jnp.stack([jnp.sum(tails[:, 0]),
                                jnp.sum(tails[:, 1]),
-                               jnp.max(tails[:, 2])]))
+                               jnp.max(tails[:, 2]),
+                               jnp.sum(tails[:, 3])]))
 
         return run
 
@@ -1769,7 +1965,8 @@ class DeviceMapper:
         flagged lanes, settle them through the three-stage chain, and
         scatter back — the only host traffic is the overflow-guard
         counters (every readback is a host round trip); `tail`, the
-        dense pass's own three counters, rides along in them.
+        dense pass's own four counters, rides along in them, and so
+        does the count of up slots that end ITEM_NONE.
 
         kt > 0 uses the pallas rowcompact kernel for the first
         compaction: XLA's nonzero over the full PG axis is the single
@@ -1805,8 +2002,10 @@ class DeviceMapper:
                 raw_t, up, prim, n2, n3 = chain(
                     raw_t, up, prim, flag2, nflag, lambda p: p, w, ex,
                     iu, af)
+            none = jnp.sum((up == ITEM_NONE) & (jnp.arange(
+                npg, dtype=jnp.int32) < pg_num)[:, None], dtype=jnp.int32)
             return raw_t, up, prim, jnp.concatenate(
-                [jnp.stack([nflag, n2, n3, rowmax]), tail])
+                [jnp.stack([nflag, n2, n3, rowmax]), tail, none[None]])
 
         return run
 
@@ -1818,10 +2017,14 @@ class DeviceMapper:
         wrapper over map_pool_state (which keeps everything
         device-resident for consumers that chain incremental
         remaps)."""
-        state = self.map_pool_state(
+        return self.read_tables(self.map_pool_state(
             ruleno, result_max, pg_num, pgp_num, pgp_num_mask, pool_id,
-            hashpspool, dev_weights, exists, isup, aff, can_shift)
-        with span("crush.readback", bytes=pg_num * (
+            hashpspool, dev_weights, exists, isup, aff, can_shift))
+
+    @staticmethod
+    def read_tables(state: "MapState"):
+        """A pass's up rows and primaries as numpy arrays on the host."""
+        with span("crush.readback", bytes=state.pg_num * (
                 state.up_full.nbytes + state.prim_full.nbytes)
                 // state.npg):
             return np.array(state.up), np.array(state.prim)
@@ -1850,8 +2053,11 @@ class DeviceMapper:
                          1 << (max(1, pg_num - 1)).bit_length()))
         K2 = max(8, min(1 << 13, K1))
         K3 = max(8, min(2048, K1))
+        chain_key = (ruleno, result_max, npg)
+        K1, K2, K3 = self._chain_want.get(chain_key, (K1, K2, K3))
         kt = self.RC_KT if self._rc_ok(npg) else 0
         tail_key = (ruleno, result_max, C)
+        plan = self._plan(ruleno, result_max)
         dense = None
         while True:
             if dense is None:
@@ -1863,11 +2069,12 @@ class DeviceMapper:
                     int(pgp_num), int(pgp_num_mask), int(pool_id),
                     bool(hashpspool), C, n_chunks, kt_tail)
                 in_pallas = self.fm.descent_in_pallas
+                widths = [C * f for f in plan.lane_factors]
+                if kt_tail:
+                    widths.append(C // self.RC_ROW * kt_tail)
                 with span("crush.launch", lanes=npg, pallas_lanes=(
-                        npg if in_pallas.get(C) and (
-                            not kt_tail
-                            or in_pallas.get(C // self.RC_ROW * kt_tail))
-                        else 0)):
+                        npg if all(in_pallas.get(n) for n in widths)
+                        else 0), steps=len(plan.steps)):
                     dense = fn(w, ex, iu, af)
             res = self._compiled_device_resolve(
                 ruleno, result_max, bool(can_shift), use_aff,
@@ -1877,7 +2084,8 @@ class DeviceMapper:
                 raw2, up2, prim2, counts = res(*dense, w, ex, iu, af)
             with span("crush.wait"):
                 (nflag, n2, ndust, rowmax, tail_lanes, unseated,
-                 tail_max) = (int(v) for v in np.asarray(counts))
+                 tail_max, retry_lanes, none_slots) = (
+                    int(v) for v in np.asarray(counts))
             if unseated * 16 > tail_lanes:
                 # row groups with more unplaced lanes than the tail has
                 # slots left the rest to the resolve chain, flagged: a
@@ -1902,13 +2110,17 @@ class DeviceMapper:
             K2 = max(K2, min(1 << (max(1, n2 - 1)).bit_length(), K1))
             K3 = max(K3, min(1 << (max(1, ndust - 1)).bit_length(),
                              K1))
+            self._chain_want[chain_key] = (K1, K2, K3)
         mark("crush.lanes", lanes=npg, tail_lanes=tail_lanes,
-             resolve_lanes=nflag)
+             resolve_lanes=nflag, retry_lanes=retry_lanes,
+             none_slots=none_slots)
         return MapState(
             self, ruleno, result_max, pg_num, pgp_num, pgp_num_mask,
             pool_id, bool(hashpspool), bool(can_shift), use_aff,
             raw2, up2, prim2, w_np, ex_np, iu_np, af_np, npg,
-            lanes=npg, tail_lanes=tail_lanes, resolve_lanes=nflag)
+            lanes=npg, tail_lanes=tail_lanes, resolve_lanes=nflag,
+            steps=len(plan.steps), retry_lanes=retry_lanes,
+            none_slots=none_slots)
 
     @functools.lru_cache(maxsize=None)
     def _compiled_remap(self, ruleno: int, result_max: int,

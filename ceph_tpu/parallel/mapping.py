@@ -11,7 +11,8 @@ sparse exception tables (pg_upmap*, pg_temp, primary_temp) are applied
 by recomputing only the excepted PGs through the host scalar pipeline.
 
 Falls back to the scalar pipeline per-PG when the crush map is outside
-the device scope (non-straw2 buckets, multi-choose rules).
+the device scope (non-straw2 buckets, local retries, a rule that mixes
+firstn and indep steps or has more than one TAKE/EMIT pair).
 
 Device dispatches route through the shared device runtime
 (ceph_tpu.device.runtime) onto one mesh chip — the caller's affinity
@@ -73,6 +74,9 @@ class OSDMapMapping:
         self.pools: dict[int, PoolMapping] = {}
         self.device_pools = 0      # pools mapped on device this build
         self.scalar_pools = 0      # pools that fell back to host
+        # pool id -> choose steps of its rule that the device pass ran
+        # (MapState.steps); a pool the host mapped has no entry
+        self.rule_steps: dict[int, int] = {}
         with span("crush.build", pools=len(osdmap.pools)):
             self._build(osdmap, device_mapper, runtime, chip)
 
@@ -144,11 +148,13 @@ class OSDMapMapping:
     def _map_pool_device(self, osdmap: OSDMap, pool: PGPool, dm,
                          exists, isup, aff):
         from ..osd.osdmap import FLAG_HASHPSPOOL
-        return dm.map_pool_batch(
+        state = dm.map_pool_state(
             pool.crush_rule, pool.size, pool.pg_num, pool.pgp_num,
             pool.pgp_num_mask, pool.id,
             bool(pool.flags & FLAG_HASHPSPOOL), osdmap.osd_weight,
             exists, isup, aff, can_shift=pool.can_shift_osds())
+        self.rule_steps[pool.id] = state.steps
+        return dm.read_tables(state)
 
     # -- scalar fallback ---------------------------------------------------
 

@@ -144,12 +144,16 @@ SPANS: dict[str, tuple[str, str]] = {
     "crush.launch": (MAPPING, "the call into a compiled program (returns "
                      "before the device ends); on the pool program lanes, "
                      "and pallas_lanes whose descent was built in Pallas "
-                     "(0 on the call that first traces it)"),
+                     "(0 on the call that first traces it), steps: the "
+                     "rule's choose steps that ran on the device"),
     "crush.lanes": (MAPPING, "mark: one whole-pool pass counted, after "
                     "its one blocking read: lanes of the dense pass, "
                     "tail_lanes of them left unplaced by the first "
                     "optimistic rounds and replayed on the compacted "
-                    "tail, resolve_lanes flagged to the resolve chain"),
+                    "tail, resolve_lanes flagged to the resolve chain, "
+                    "retry_lanes an indep rule's first full-width round "
+                    "left with an undefined slot (0 for firstn), "
+                    "none_slots of the up table that end ITEM_NONE"),
     "crush.wait": (MAPPING, "the first blocking read: the host waits, "
                    "the device works"),
     "crush.readback": (MAPPING, "up/acting tables device -> host; bytes"),

@@ -425,7 +425,9 @@ class TestDenseTail:
             jnp.asarray(w), jnp.ones(len(w), bool), jnp.asarray(w > 0),
             jnp.zeros(len(w), jnp.int32))
         flag = np.asarray(flag)
-        seated, unseated, largest = (int(v) for v in np.asarray(tail))
+        seated, unseated, largest, retry = (
+            int(v) for v in np.asarray(tail))
+        assert retry == 0      # indep's count; a firstn pass has none
         assert largest > slots
         assert seated == self.PG_NUM // dm.RC_ROW * slots
         assert unseated * 16 > seated and flag.sum() >= unseated

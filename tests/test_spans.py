@@ -334,10 +334,16 @@ def test_traced_remap_counts_lanes_and_bytes(traced_remap):
     lanes = [s for _a, _b, s in by["crush.launch"] if "lanes" in s]
     # 256 PGs is no multiple of the Pallas tile: the XLA descent, counted
     assert [(s["lanes"], s["pallas_lanes"]) for s in lanes] == [(256, 0)]
+    # a rule of one choose step, all of it on the device
+    assert [s["steps"] for s in lanes] == [1]
     # one pass counted: too small a pool for a tail, a few lanes flagged
     (_a, _b, counted), = by["crush.lanes"]
     assert counted["lanes"] == 256 and counted["tail_lanes"] == 0
     assert 0 <= counted["resolve_lanes"] < 256
+    # firstn: no indep round to leave a slot undefined; the holes of the
+    # up table (two hosts cannot seat three replicas) are counted
+    assert counted["retry_lanes"] == 0
+    assert counted["none_slots"] == int((pm.up == 0x7FFFFFFF).sum()) > 0
     (_a, _b, back), = by["crush.readback"]
     assert back["bytes"] == pm.up.nbytes + pm.up_primary.nbytes
 

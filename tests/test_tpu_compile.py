@@ -105,6 +105,43 @@ def test_crush_descend_compiles(one_chip, mosaic, depth_sizes,
     _compiled_text(fn, one_chip, tail, tail, tail, tail)
 
 
+def _flat_map_10k():
+    """A fresh FlatMap of the benchmark's three-level map: 20 racks of
+    25 hosts of 20 OSDs, 521 buckets."""
+    import json
+    import os
+    from benchmark.drivers.crush_churn_rules import build_crush
+    from ceph_tpu.ops.crush.device import FlatMap
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "crush-10kosd-lrc-4m.json")) as f:
+        return FlatMap(build_crush(json.load(f)))
+
+
+@pytest.mark.parametrize("depth_sizes,want_type,chunks",
+                         [((20,), 2, 1), ((25,), 1, 2), ((20,), 0, 2)],
+                         ids=["root-rack", "rack-host", "host-osd"])
+def test_crush_descend_compiles_for_the_two_step_rule(
+        one_chip, mosaic, depth_sizes, want_type, chunks):
+    """`choose indep 2 type rack; chooseleaf indep 4 type host`: the
+    first step's descent at one chunk's lanes, the second step's two
+    at two chunks' (its two takes run as lanes of one choose)."""
+    from ceph_tpu.ops.crush import pallas_draw
+    fn = pallas_draw.make_descend_kernel(_flat_map_10k(), depth_sizes,
+                                         want_type)
+    assert fn is not None, "map outside the kernel's table budget"
+    lanes = ((chunks << 20,), jnp.int32)
+    _compiled_text(fn, one_chip, lanes, lanes, lanes, lanes)
+
+
+def test_crush_post_compiles_for_an_erasure_pool(one_chip, mosaic):
+    """Eight positional slots, nothing shifts, one chunk of a pass."""
+    from ceph_tpu.ops.crush import pallas_draw
+    fn = pallas_draw.make_post_kernel(10000, 8, False)
+    _compiled_text(fn, one_chip, ((1 << 20, 8), jnp.int32),
+                   ((10000,), np.bool_))
+
+
 # the 10M-PG pool of the smoke: ten DeviceMapper.CHUNK-sized chunks
 NPG = 10 << 20
 
