@@ -812,11 +812,13 @@ def _is_out(dev_weights, item, x):
 # firstn / indep
 # ---------------------------------------------------------------------------
 
-# optimistic rounds per replica of the attempt structure (ftotal = 0, 1,
-# 2): a lane still unplaced after them is flagged for the resolve chain.
-# A whole pool's dense pass runs only the first of them at full width
-# and the rest on the compacted tail (_compiled_pool); the incremental
-# remap, the chain's stage A and the indep path, whose lanes are a
+# optimistic rounds of the attempt structure (ftotal = 0, 1, 2; per
+# replica for firstn, per choose step for indep): a lane still unplaced
+# after them is flagged for the resolve chain.  A whole pool's dense
+# pass runs only the first of them at full width and the rest on a
+# compacted tail: firstn around the whole rule (_compiled_pool), indep
+# inside each choose step, on the step's own takes (_choose_indep_vec).
+# The incremental remap and the chain's stage A, whose lanes are a
 # compacted set already, run all of them at their own width.
 _ATTEMPT_TRIES = 3
 
@@ -1160,22 +1162,44 @@ def _indep_full(fm: FlatMap, take_bid, xs, numrep: int, slots: int,
     return jnp.where(res == ITEM_UNDEF, ITEM_NONE, res), flag
 
 
+class _Tail(collections.namedtuple("_Tail", "kt seeds")):
+    """The compacted tail of one indep choose step in a whole pool's
+    dense pass: kt slots per RC_ROW-lane row group, and seeds(lanes) ->
+    the xs of the step's lanes by number (recomputed from the lane
+    numbers: a gather runs at scalar rate)."""
+
+    __slots__ = ()
+
+
 def _choose_indep_vec(fm: FlatMap, take, xs, numrep: int,
                       result_max: int, want_type: int,
                       recurse_to_leaf: bool, dev_weights,
                       tries: int, recurse_tries: int,
                       outer_ds: tuple, inner_ds: tuple,
                       resolve: bool, full: bool,
-                      rootc: _ConstRow | None, nslots=None):
-    """Fast-path indep: _ATTEMPT_TRIES optimistic full-width rounds
-    (each an exact crush_choose_indep round, so chaining them is the
-    reference retry semantics verbatim); lanes with UNDEF slots left
-    after them are flagged for the resolve pass.
+                      rootc: _ConstRow | None, nslots=None,
+                      tail: _Tail | None = None):
+    """Fast-path indep: _ATTEMPT_TRIES optimistic rounds (each an exact
+    crush_choose_indep round, so chaining them is the reference retry
+    semantics verbatim); lanes with UNDEF slots left after them are
+    flagged for the resolve pass.
 
-    Returns (rows, flag, retry): retry marks the lanes that still had
-    an undefined slot after the first full-width round (what a tail for
-    indep would replay; none where the full loops run).  take and nslots
-    as _choose_firstn_vec's take and limit."""
+    Without a tail every round runs over all L lanes.  With one (a
+    whole pool's dense pass, DeviceMapper._tail_slots) only the first
+    does: the lanes it leaves with an undefined slot are compacted into
+    tail.kt slots per row group (rowcompact), their out and leaves
+    vectors fetched (rowgather) and the later rounds run at that width
+    (L / RC_ROW * kt lanes, a loop, so that the program holds each
+    descent once), and their rows and flags are put back (rowexpand).
+    A lane whose row group had no slot left for it keeps its row and
+    stays flagged.
+
+    Returns (rows, flag, retry, seats): retry marks the lanes that
+    still had an undefined slot after the first full-width round (none
+    where the full loops run); seats is None without a tail, else the
+    int32 counts [lanes seated in the tail, lanes left unseated, largest
+    row group].  take and nslots as _choose_firstn_vec's take and
+    limit."""
     L = xs.shape[0]
     slots = min(numrep, result_max)
     take_bid = _take_lanes(take, L)
@@ -1184,26 +1208,74 @@ def _choose_indep_vec(fm: FlatMap, take, xs, numrep: int,
                                 want_type, recurse_to_leaf, dev_weights,
                                 tries, recurse_tries, outer_ds, inner_ds,
                                 resolve, rootc, nslots)
-        return res, flag, jnp.zeros((L,), bool)
+        return res, flag, jnp.zeros((L,), bool), None
 
+    def round_(ft, take_bid, xs, out, leaves, flag):
+        return _indep_round(
+            fm, take_bid, xs, ft, out, leaves, flag, numrep, slots,
+            want_type, recurse_to_leaf, dev_weights, recurse_tries,
+            outer_ds, inner_ds, resolve, rootc, _INDEP_LEAF_TRIES)
+
+    def rows_of(out, leaves, flag):
+        res = leaves if recurse_to_leaf else out
+        flag = flag | jnp.any(out == ITEM_UNDEF, axis=1)
+        return jnp.where(res == ITEM_UNDEF, ITEM_NONE, res), flag
+
+    n_rounds = min(_ATTEMPT_TRIES, tries)
     out = leaves = _indep_start(L, slots, nslots)
-    flag = jnp.zeros((L,), bool)
-    retry = flag
-    for ft in range(min(_ATTEMPT_TRIES, tries)):
-        out, leaves, flag = _indep_round(
-            fm, take_bid, xs, jnp.full((), ft, jnp.int32), out, leaves,
-            flag, numrep, slots, want_type, recurse_to_leaf,
-            dev_weights, recurse_tries, outer_ds, inner_ds, resolve,
-            rootc, _INDEP_LEAF_TRIES)
-        if ft == 0:
-            retry = jnp.any(out == ITEM_UNDEF, axis=1)
-    res = leaves if recurse_to_leaf else out
-    flag = flag | jnp.any(out == ITEM_UNDEF, axis=1)
-    return jnp.where(res == ITEM_UNDEF, ITEM_NONE, res), flag, retry
+    out, leaves, flag = round_(jnp.int32(0), take_bid, xs, out, leaves,
+                               jnp.zeros((L,), bool))
+    retry = jnp.any(out == ITEM_UNDEF, axis=1)
+    if tail is None or n_rounds < 2:
+        for ft in range(1, n_rounds):
+            out, leaves, flag = round_(jnp.int32(ft), take_bid, xs, out,
+                                       leaves, flag)
+        return rows_of(out, leaves, flag) + (retry, None)
+
+    from . import pallas_draw
+    with jax.named_scope("crush_indep_tail"):
+        row, kt = DeviceMapper.RC_ROW, tail.kt
+        idx, _valid, cnt = pallas_draw.make_rowcompact_kernel(
+            L, row, kt, L)(retry)
+        # what a seated lane's later rounds start from: its take and, per
+        # slot, what the first round placed (a pad slot holds its group's
+        # first lane: computed, never read back)
+        state = [out] + ([leaves] if recurse_to_leaf else [])
+        if not isinstance(take, int):
+            state.append(take_bid[:, None])
+        state = jnp.concatenate(state, axis=1)
+        state_t = pallas_draw.make_rowgather_kernel(
+            L, row, kt, state.shape[1])(retry, state)
+        out_t = state_t[:, :slots]
+        leaves_t = (state_t[:, slots:2 * slots] if recurse_to_leaf
+                    else out_t)
+        take_t = (_take_lanes(take, idx.shape[0]) if isinstance(take, int)
+                  else state_t[:, -1])
+        xs_t = tail.seeds(idx)
+
+        def later(ft, st):
+            return round_(ft.astype(jnp.int32), take_t, xs_t, *st)
+
+        out_t, leaves_t, flag_t = jax.lax.fori_loop(
+            1, n_rounds, later,
+            (out_t, leaves_t, jnp.zeros(idx.shape, bool)))
+        res, _ = rows_of(out, leaves, flag)
+        res_t, flag_t = rows_of(out_t, leaves_t, flag_t)
+        # a seated lane takes its tail row and adds the tail's flag to its
+        # first round's; one that got no slot keeps its row, flagged
+        rows = pallas_draw.make_rowexpand_kernel(L, row, kt, slots + 1)(
+            retry,
+            jnp.concatenate([res, retry[:, None].astype(jnp.int32)], axis=1),
+            jnp.concatenate([res_t, flag_t[:, None].astype(jnp.int32)],
+                            axis=1))
+    seated = jnp.minimum(cnt, kt)
+    return (rows[:, :-1], flag | (rows[:, -1] != 0), retry,
+            jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
+                       jnp.max(cnt)]))
 
 
 def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
-                resolve, full: bool):
+                resolve, full: bool, tail: _Tail | None = None):
     """One choose step after a rule's first, as crush_do_rule chains
     them (mapper.c:878-1083): every entry of the working vector w
     [L, n_in] that is a bucket is a take of its own, and what it
@@ -1219,8 +1291,11 @@ def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
     in order, so cutting it to result_max - osize afterwards is what
     running it with that count gives.
 
+    tail (an indep step of a whole pool's dense pass): the step's
+    compacted tail, its seeds by PG lane; take-lane j is PG lane j mod L.
+
     Returns (rows [L, st.width] with NONE past a lane's osize, flag,
-    retry)."""
+    retry, seats): seats as _choose_indep_vec's."""
     L, n_in = w.shape
     slots = min(st.numrep, result_max)
     valid = (w < 0).T                                      # [n_in, L]
@@ -1233,7 +1308,7 @@ def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
             st.outer_ds, st.inner_ds, resolve, full, None,
             limit=jnp.where(valid, slots, 0).reshape(-1))
         flag = flag | unfinished
-        retry = jnp.zeros_like(flag)
+        retry, seats = jnp.zeros_like(flag), None
         placed = placed.reshape(n_in, L)
     else:
         osize = jnp.zeros((L,), jnp.int32)
@@ -1243,10 +1318,13 @@ def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
                 valid[i], jnp.minimum(slots, result_max - osize), 0))
             osize = osize + placed[i]
         placed = jnp.stack(placed)
-        rows, flag, retry = _choose_indep_vec(
+        if tail is not None:
+            pg_seeds = tail.seeds
+            tail = tail._replace(seeds=lambda j: pg_seeds(j % L))
+        rows, flag, retry, seats = _choose_indep_vec(
             fm, take, xs_t, st.numrep, result_max, st.want_type, st.leaf,
             dev_weights, st.tries, st.recurse, st.outer_ds, st.inner_ds,
-            resolve, full, None, nslots=placed.reshape(-1))
+            resolve, full, None, nslots=placed.reshape(-1), tail=tail)
     rows = rows.reshape(n_in, L, slots)
     flag = jnp.any(flag.reshape(n_in, L) & valid, axis=0)
     retry = jnp.any(retry.reshape(n_in, L) & valid, axis=0)
@@ -1259,7 +1337,7 @@ def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
             put = (cols == (osize + k)[:, None]) & (k < n_i)[:, None]
             out = jnp.where(put, rows[i, :, k:k + 1], out)
         osize = osize + n_i
-    return out, flag, retry
+    return out, flag, retry, seats
 
 
 # ---------------------------------------------------------------------------
@@ -1371,13 +1449,13 @@ class MapState:
                  "use_aff", "raw", "up_full", "prim_full", "w_np",
                  "ex_np", "iu_np", "af_np", "npg", "lanes",
                  "tail_lanes", "resolve_lanes", "steps", "retry_lanes",
-                 "none_slots")
+                 "none_slots", "indep_tail_lanes")
 
     def __init__(self, dm, ruleno, result_max, pg_num, pgp_num,
                  pgp_mask, pool_id, hashps, can_shift, use_aff, raw,
                  up_full, prim_full, w_np, ex_np, iu_np, af_np, npg,
                  lanes=0, tail_lanes=0, resolve_lanes=0, steps=0,
-                 retry_lanes=0, none_slots=0):
+                 retry_lanes=0, none_slots=0, indep_tail_lanes=0):
         self.dm = dm
         self.ruleno = ruleno
         self.result_max = result_max
@@ -1404,10 +1482,12 @@ class MapState:
         self.resolve_lanes = resolve_lanes
         # a whole-pool pass only: the rule's choose steps it ran on the
         # device, the lanes an indep rule's first full-width round left
-        # with an undefined slot (what a tail for indep would replay),
-        # the up slots that ended ITEM_NONE
+        # with an undefined slot in some step, the take-lanes of its
+        # steps that sat in a tail for their later rounds, the up slots
+        # that ended ITEM_NONE
         self.steps = steps
         self.retry_lanes = retry_lanes
+        self.indep_tail_lanes = indep_tail_lanes
         self.none_slots = none_slots
 
     @property
@@ -1509,10 +1589,11 @@ class MapState:
 
 # one choose step with the tunables in force where it stands in the rule;
 # width: entries of the working vector after it (static; a lane's own
-# osize may be less)
+# osize may be less); collide: the share of an indep step's takes whose
+# numrep draws are expected to collide (what its tail starts from)
 _Step = collections.namedtuple(
     "_Step", "firstn numrep want_type leaf tries recurse vary_r stable "
-             "outer_ds inner_ds width")
+             "outer_ds inner_ds width collide")
 
 
 class _Plan(collections.namedtuple("_Plan", "take_id steps")):
@@ -1549,9 +1630,9 @@ class DeviceMapper:
         self.map = crushmap
         self._cargs = (crushmap.choose_args.get(choose_args_name)
                        if choose_args_name else None)
-        # (ruleno, result_max, chunk lanes) -> slots per row group a
-        # dense pass's tail was found to need; the passes thrown away
-        # to find it
+        # (ruleno, result_max, chunk lanes, choose step) -> slots per
+        # row group a dense pass's tail was found to need; the passes
+        # thrown away to find it
         self._tail_want: dict[tuple, int] = {}
         self.tail_overflows = 0
         # (ruleno, result_max, lanes) -> the resolve chain's capacities
@@ -1632,7 +1713,9 @@ class DeviceMapper:
                 steps.append(_Step(
                     firstn, numrep, arg2, leaf, tries, recurse, vary_r,
                     stable, outer_ds, inner_ds,
-                    min(result_max, n_in * min(numrep, result_max))))
+                    min(result_max, n_in * min(numrep, result_max)),
+                    0.0 if firstn else self._collide_share(
+                        starts, arg2, min(numrep, result_max))))
             elif op == EMIT:
                 emitted = bool(steps)
         if not steps:
@@ -1647,20 +1730,26 @@ class DeviceMapper:
 
     def _compile(self, ruleno: int, result_max: int, resolve: bool,
                  full: bool = True, first_only: bool = False,
-                 stats: bool = False):
+                 tails: tuple | None = None):
         """core(xs, dev_weights) -> (rows, flag): flag marks the lanes
         that a more exact pass has to recompute.  first_only (one firstn
         step, full=False): the first optimistic round of each replica
         alone, -> (rows, flag, unfinished) with the unplaced lanes
-        apart.  stats: -> (rows, flag, retry), retry the lanes an indep
-        step's first full-width round left with an undefined slot."""
+        apart.  tails (a whole pool's dense pass: per choose step the
+        slots of its compacted tail, 0 for none): core(xs, dev_weights,
+        seeds) -> (rows, flag, counts), seeds as _Tail's by PG lane and
+        counts int32: the lanes an indep step's first full-width round
+        left with an undefined slot, then _choose_indep_vec's three
+        seats of each step that has a tail."""
         plan = self._plan(ruleno, result_max)
         fm = self.fm
         head = plan.steps[0]
         rootc = fm.const_row(plan.take_id, head.outer_ds[0])
         assert not first_only or len(plan.steps) == 1
 
-        def core(xs, dev_weights):
+        def core(xs, dev_weights, seeds=None):
+            tail = [_Tail(kt, seeds) if kt else None
+                    for kt in tails or (0,) * len(plan.steps)]
             if head.firstn:
                 res, _, flag, unfinished = _choose_firstn_vec(
                     fm, plan.take_id, xs, head.numrep, result_max,
@@ -1671,18 +1760,25 @@ class DeviceMapper:
                 if first_only:
                     return res, flag, unfinished
                 flag = flag | unfinished
-                retry = jnp.zeros_like(flag)
+                retry, seats = jnp.zeros_like(flag), [None]
             else:
-                res, flag, retry = _choose_indep_vec(
+                res, flag, retry, seat = _choose_indep_vec(
                     fm, plan.take_id, xs, head.numrep, result_max,
                     head.want_type, head.leaf, dev_weights,
                     head.tries, head.recurse, head.outer_ds,
-                    head.inner_ds, resolve, full, rootc)
-            for st in plan.steps[1:]:
-                res, f, rt = _chain_step(fm, st, res, xs, result_max,
-                                         dev_weights, resolve, full)
+                    head.inner_ds, resolve, full, rootc, tail=tail[0])
+                seats = [seat]
+            for st, tl in zip(plan.steps[1:], tail[1:]):
+                res, f, rt, seat = _chain_step(
+                    fm, st, res, xs, result_max, dev_weights, resolve,
+                    full, tl)
                 flag, retry = flag | f, retry | rt
-            return (res, flag, retry) if stats else (res, flag)
+                seats.append(seat)
+            if tails is None:
+                return res, flag
+            return res, flag, jnp.concatenate(
+                [jnp.sum(retry, dtype=jnp.int32)[None]]
+                + [seat for seat in seats if seat is not None])
 
         return core
 
@@ -1706,6 +1802,33 @@ class DeviceMapper:
                      and m.buckets[c].type != want_type}
             seen_levels += 1
         return tuple(sizes) if sizes else (1,)
+
+    def _collide_share(self, starts: list, want_type: int,
+                       n: int) -> float:
+        """The share of an indep step's takes whose n draws are expected
+        to collide in their first round: the birthday collision among
+        the items of the wanted type below a start bucket, at even
+        weights, averaged over the start buckets.  Uneven weights and
+        reweights add to it; a pass that finds more widens its tail
+        (map_pool_state)."""
+        m = self.map
+
+        def below(bid: int) -> int:
+            k = 0
+            for c in m.buckets[bid].items:
+                if c >= 0:
+                    k += want_type == 0
+                elif c in m.buckets:
+                    k += (1 if m.buckets[c].type == want_type
+                          else below(c))
+            return k
+
+        shares = []
+        for b in starts:
+            k = below(b) if b in m.buckets else 0
+            shares.append(1.0 - math.prod(
+                max(0.0, 1.0 - i / k) if k else 0.0 for i in range(n)))
+        return sum(shares) / len(shares) if shares else 0.0
 
     @staticmethod
     def _note_compile(what: str, key: tuple) -> None:
@@ -1745,32 +1868,59 @@ class DeviceMapper:
     CHUNK = 1 << 20
 
     # the dense pass's tail: compaction slots per RC_ROW-lane row group
-    # it starts with, and the most it is widened to before a pool goes
-    # back to dense rounds (at 512 of 2048 the tail's nine rounds cost
-    # 2.25 full-width rounds of the 6 they replace, gathers aside)
+    # a firstn rule starts with, and the most a tail is widened to
+    # before its rounds go back to dense.  firstn's tail replays all
+    # nine rounds of three replicas (at 512 of 2048 they cost 2.25
+    # full-width rounds of the 6 they replace, gathers aside); an indep
+    # step's runs the two later rounds of the step's three (at 1024 a
+    # full-width round of the two they replace, and moving the lanes in
+    # and out an eighth of one more)
     TAIL_KT = 256
     TAIL_KT_MAX = 512
+    INDEP_TAIL_KT_MAX = 1024
+
+    def _tail_start(self, ruleno: int, result_max: int, step: int) -> int:
+        """Slots per row group a step's tail starts with, before any
+        pass has been counted: firstn TAIL_KT; an indep step the hits
+        its geometry lets expect in a row group (_collide_share) and two
+        standard deviations (a group that runs over leaves a few lanes
+        to the resolve chain, flagged)."""
+        plan = self._plan(ruleno, result_max)
+        if plan.firstn:
+            return self.TAIL_KT
+        p = plan.steps[step].collide
+        mu = self.RC_ROW * p
+        return math.ceil(mu + 2.0 * math.sqrt(mu * (1.0 - p))) or 1
 
     def _tail_slots(self, ruleno: int, result_max: int, C: int,
-                    want: int) -> int:
-        """Slots per row group (>= want) for the tail of a dense pass
-        whose chunks are C lanes wide, or 0: no tail, every optimistic
-        round dense.  A tail needs a rule of one firstn step with
-        rounds to save, the attempt structure (C >= _ATTEMPT_MIN_L),
+                    want: int, step: int = 0) -> int:
+        """Slots per row group (>= want) for the tail of choose step
+        `step` in a dense pass whose chunks are C PG lanes wide, or 0:
+        no tail, every optimistic round of the step dense.  A firstn
+        tail is the whole rule's, and needs a rule of one step; an
+        indep step has its own, on the step's takes (C lanes times the
+        entries of the working vector before it).  Either needs rounds
+        to save, the attempt structure (lanes >= _ATTEMPT_MIN_L),
         rowcompact's alignment, and a width that keeps its descents in
         Pallas."""
         from . import pallas_draw
         plan = self._plan(ruleno, result_max)
-        head = plan.steps[0]
-        if not (plan.firstn and len(plan.steps) == 1
-                and C >= _ATTEMPT_MIN_L and self._rc_ok(C)
-                and _firstn_attempts(head.tries, head.leaf,
-                                     head.recurse) > 1):
+        st = plan.steps[step]
+        lanes = C * plan.lane_factors[step]
+        if plan.firstn:
+            rounds = (_firstn_attempts(st.tries, st.leaf, st.recurse)
+                      if len(plan.steps) == 1 else 1)
+            most = self.TAIL_KT_MAX
+        else:
+            rounds = min(_ATTEMPT_TRIES, st.tries)
+            most = self.INDEP_TAIL_KT_MAX
+        if not (rounds > 1 and lanes >= _ATTEMPT_MIN_L
+                and self._rc_ok(lanes)):
             return 0
-        nr = C // self.RC_ROW
-        step = max(128, pallas_draw.TL // math.gcd(nr, pallas_draw.TL))
-        kt = step * -(-want // step)
-        return kt if kt <= self.TAIL_KT_MAX else 0
+        nr = lanes // self.RC_ROW
+        unit = max(128, pallas_draw.TL // math.gcd(nr, pallas_draw.TL))
+        kt = unit * -(-want // unit)
+        return kt if kt <= most else 0
 
     # -- whole-pool mapping with device-side pps -------------------------
 
@@ -1778,7 +1928,7 @@ class DeviceMapper:
     def _compiled_pool(self, ruleno: int, result_max: int,
                        can_shift: bool, use_aff: bool, pgp_num: int,
                        pgp_mask: int, pool_id: int, hashps: bool,
-                       n: int, n_chunks: int, kt_tail: int = 0):
+                       n: int, n_chunks: int, tails: tuple = ()):
         """Whole pool in ONE dispatch: a lax.scan over fixed-size
         chunks (the chunking bounds the live [L,S] temps, the scan
         removes per-chunk dispatch/readback latency).  The dense pass
@@ -1786,27 +1936,34 @@ class DeviceMapper:
         retries are flagged and settled by the resolve passes, so its
         cost does not follow the worst lane's retry count.
 
-        kt_tail > 0 (_tail_slots): only the first optimistic round of
+        tails: per choose step the slots of its tail (_tail_slots), 0
+        or nothing for every round over all n lanes.  A firstn rule's
+        (one step, kt_tail slots): only the first optimistic round of
         each replica runs over all n lanes of a chunk (numrep descents,
         twice that for chooseleaf).  The lanes it leaves unplaced are
         compacted into kt_tail slots per row group (rowcompact),
         replayed from scratch through the whole attempt structure at
         that width (n / RC_ROW * kt_tail lanes) and their rows and
-        flags put back (rowexpand).  A row group with more unplaced
-        lanes than slots keeps the rest flagged for the resolve chain;
-        the pass's counts (lanes seated, lanes left unseated, largest
-        group) tell the host when that is worth a wider tail.
-        kt_tail == 0: every round over all n lanes.
+        flags put back (rowexpand).  An indep rule's are inside its
+        steps (_choose_indep_vec).  Either way a row group with more
+        unplaced lanes than slots keeps the rest flagged for the
+        resolve chain; the pass's counts (lanes seated, lanes left
+        unseated, largest group) tell the host when that is worth a
+        wider tail.
 
-        The pass's fourth count is the lanes an indep rule's first
-        full-width round left with an undefined slot (0 for firstn)."""
+        The pass's counts: a firstn tail's three, the lanes an indep
+        rule's first full-width round left with an undefined slot (0
+        for firstn), then three for each indep step that has a tail."""
         self._note_compile("pool", (ruleno, result_max, can_shift,
                                     use_aff, pgp_num, pgp_mask,
                                     pool_id, hashps, n, n_chunks,
-                                    kt_tail))
+                                    tails))
         from . import pallas_draw
-        core = self._compile(ruleno, result_max, False, full=False,
-                             stats=not kt_tail)
+        firstn = self._plan(ruleno, result_max).firstn
+        kt_tail = tails[0] if firstn and tails else 0
+        core = self._compile(
+            ruleno, result_max, False, full=False,
+            tails=None if kt_tail else tuple(tails))
         if kt_tail:
             first = self._compile(ruleno, result_max, False, full=False,
                                   first_only=True)
@@ -1819,10 +1976,11 @@ class DeviceMapper:
         def descend(start, dev_weights):
             xs = pps(jnp.arange(n, dtype=jnp.uint32) + start)
             if not kt_tail:
-                raw, flag, retry = core(xs, dev_weights)
-                return xs, raw, flag, jnp.stack(
-                    [jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                     jnp.sum(retry, dtype=jnp.int32)])
+                raw, flag, counts = core(
+                    xs, dev_weights,
+                    lambda lane: pps(lane.astype(jnp.uint32) + start))
+                return xs, raw, flag, jnp.concatenate(
+                    [jnp.zeros((3,), jnp.int32), counts])
             raw, flag, unfinished = first(xs, dev_weights)
             idx, _valid, cnt = rc(unfinished)
             raw_t, flag_t = core(pps(idx.astype(jnp.uint32) + start),
@@ -1865,15 +2023,17 @@ class DeviceMapper:
 
             starts = (jnp.arange(n_chunks, dtype=jnp.uint32)
                       * _u32(n))
-            _, (raws, ups, prims, flags, tails) = jax.lax.scan(
+            _, (raws, ups, prims, flags, counts) = jax.lax.scan(
                 body, 0, starts)
             S = ups.shape[2]
+            # per chunk: seated, unseated, largest group; the retry
+            # count; then the same three per indep step with a tail
             return (raws.reshape(-1, S), ups.reshape(-1, S),
                     prims.reshape(-1), flags.reshape(-1),
-                    jnp.stack([jnp.sum(tails[:, 0]),
-                               jnp.sum(tails[:, 1]),
-                               jnp.max(tails[:, 2]),
-                               jnp.sum(tails[:, 3])]))
+                    jnp.stack([
+                        (jnp.max if i == 2 or (i > 3 and i % 3 == 0)
+                         else jnp.sum)(counts[:, i])
+                        for i in range(counts.shape[1])]))
 
         return run
 
@@ -1965,8 +2125,8 @@ class DeviceMapper:
         flagged lanes, settle them through the three-stage chain, and
         scatter back — the only host traffic is the overflow-guard
         counters (every readback is a host round trip); `tail`, the
-        dense pass's own four counters, rides along in them, and so
-        does the count of up slots that end ITEM_NONE.
+        dense pass's own counters, rides along in them, and so does the
+        count of up slots that end ITEM_NONE.
 
         kt > 0 uses the pallas rowcompact kernel for the first
         compaction: XLA's nonzero over the full PG axis is the single
@@ -2056,22 +2216,24 @@ class DeviceMapper:
         chain_key = (ruleno, result_max, npg)
         K1, K2, K3 = self._chain_want.get(chain_key, (K1, K2, K3))
         kt = self.RC_KT if self._rc_ok(npg) else 0
-        tail_key = (ruleno, result_max, C)
         plan = self._plan(ruleno, result_max)
         dense = None
         while True:
             if dense is None:
-                kt_tail = self._tail_slots(
-                    ruleno, result_max, C,
-                    self._tail_want.get(tail_key, self.TAIL_KT))
+                tails = tuple(
+                    self._tail_slots(
+                        ruleno, result_max, C, self._tail_want.get(
+                            (ruleno, result_max, C, i),
+                            self._tail_start(ruleno, result_max, i)), i)
+                    for i in range(len(plan.steps)))
                 fn = self._compiled_pool(
                     ruleno, result_max, bool(can_shift), use_aff,
                     int(pgp_num), int(pgp_num_mask), int(pool_id),
-                    bool(hashpspool), C, n_chunks, kt_tail)
+                    bool(hashpspool), C, n_chunks, tails)
                 in_pallas = self.fm.descent_in_pallas
                 widths = [C * f for f in plan.lane_factors]
-                if kt_tail:
-                    widths.append(C // self.RC_ROW * kt_tail)
+                widths += [n // self.RC_ROW * t
+                           for n, t in zip(widths, tails) if t]
                 with span("crush.launch", lanes=npg, pallas_lanes=(
                         npg if all(in_pallas.get(n) for n in widths)
                         else 0), steps=len(plan.steps)):
@@ -2084,18 +2246,29 @@ class DeviceMapper:
                 raw2, up2, prim2, counts = res(*dense, w, ex, iu, af)
             with span("crush.wait"):
                 (nflag, n2, ndust, rowmax, tail_lanes, unseated,
-                 tail_max, retry_lanes, none_slots) = (
+                 tail_max, retry_lanes, *indep, none_slots) = (
                     int(v) for v in np.asarray(counts))
-            if unseated * 16 > tail_lanes:
+            # per tail (step, lanes seated, left unseated, largest row
+            # group): a firstn rule's one, or an indep rule's steps'
+            tailed = [i for i, t in enumerate(tails) if t]
+            seats = ([(0, tail_lanes, unseated, tail_max)] if plan.firstn
+                     else [(i, *indep[3 * k:3 * k + 3])
+                           for k, i in enumerate(tailed)])
+            over = [(i, most) for i, seated, left, most in seats
+                    if left * 16 > seated]
+            if over:
                 # row groups with more unplaced lanes than the tail has
                 # slots left the rest to the resolve chain, flagged: a
                 # few cost nothing and change no program, but past a
                 # sixteenth of the tail the pass is thrown away and
-                # this pool's passes run a wider tail from here on, or
-                # dense rounds past TAIL_KT_MAX (until the crush map,
-                # and with it this mapper, is replaced)
+                # this pool's passes run that step's tail wider from
+                # here on, or its rounds dense past the most a tail may
+                # have (until the crush map, and with it this mapper,
+                # is replaced)
                 self.tail_overflows += 1
-                self._tail_want[tail_key] = tail_max + tail_max // 4
+                for i, most in over:
+                    self._tail_want[(ruleno, result_max, C, i)] = (
+                        most + most // 4)
                 dense = None
                 continue
             if kt and rowmax > kt:
@@ -2111,16 +2284,18 @@ class DeviceMapper:
             K3 = max(K3, min(1 << (max(1, ndust - 1)).bit_length(),
                              K1))
             self._chain_want[chain_key] = (K1, K2, K3)
+        indep_tail_lanes = (0 if plan.firstn
+                            else sum(seated for _, seated, _, _ in seats))
         mark("crush.lanes", lanes=npg, tail_lanes=tail_lanes,
              resolve_lanes=nflag, retry_lanes=retry_lanes,
-             none_slots=none_slots)
+             indep_tail_lanes=indep_tail_lanes, none_slots=none_slots)
         return MapState(
             self, ruleno, result_max, pg_num, pgp_num, pgp_num_mask,
             pool_id, bool(hashpspool), bool(can_shift), use_aff,
             raw2, up2, prim2, w_np, ex_np, iu_np, af_np, npg,
             lanes=npg, tail_lanes=tail_lanes, resolve_lanes=nflag,
             steps=len(plan.steps), retry_lanes=retry_lanes,
-            none_slots=none_slots)
+            none_slots=none_slots, indep_tail_lanes=indep_tail_lanes)
 
     @functools.lru_cache(maxsize=None)
     def _compiled_remap(self, ruleno: int, result_max: int,

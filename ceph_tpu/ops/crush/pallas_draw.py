@@ -734,6 +734,86 @@ def make_rowcompact_kernel(n_lanes: int, row: int, kt: int,
     return run
 
 
+def _group_limbs(vals, nr: int):
+    """[nr * per, words] i32 rows of nr row groups -> [nr, 4*words, per]
+    int8 limb planes for a one-hot matmul over a group's rows:
+    word-major, then limb, biased by -128."""
+    u32 = jnp.uint32
+    per, words = vals.shape[0] // nr, vals.shape[1]
+    vu = vals.astype(jnp.int32).astype(u32).reshape(nr, per, words)
+    vu = jnp.transpose(vu, (0, 2, 1))[:, :, None, :]
+    shifts = (jnp.arange(4, dtype=u32) * u32(8))[None, None, :, None]
+    limbs = ((vu >> shifts) & u32(0xFF)).astype(jnp.int32) - 128
+    return limbs.astype(jnp.int8).reshape(nr, 4 * words, per)
+
+
+def make_rowgather_kernel(n_lanes: int, row: int, kt: int, words: int):
+    """rowcompact with the lanes' data: slot j of row group g takes the
+    `words` int32 of the group's j-th hit lane, for j below the group's
+    count and kt; a slot past that reads 0.  The seats are rowcompact's
+    and rowexpand's (slot = rank, the hits before the lane in its
+    group).
+
+    No gather (scalar rate on TPU): per group the int8 one-hot
+    [kt, row] of slot against rank that rowexpand builds, contracted
+    over the lanes with the values' 8-bit limb planes [4*words, row] on
+    the MXU; one lane is hot per seated slot, so each product IS a limb.
+
+    Returns fn(hit [n_lanes] bool, vals [n_lanes, words] i32)
+      -> [n_lanes/row*kt, words] i32.
+    """
+    from jax.experimental import pallas as pl
+
+    if n_lanes % (8 * row) or row % 128 or kt % 128:
+        raise ValueError("rowgather: n_lanes %d / row %d / kt %d "
+                         "misaligned" % (n_lanes, row, kt))
+    nr = n_lanes // row
+    interp = _interpret()
+    i8, i32 = jnp.int8, jnp.int32
+    c32 = np.int32
+    kb = 128     # slots per matmul: bounds the one-hot held in VMEM
+
+    def kern(hit_ref, rank_ref, val_ref, *out_refs):
+        iota_k = jax.lax.broadcasted_iota(i32, (kb, row), 0)
+        slot = jax.lax.broadcasted_iota(i32, (1, kb), 1)
+        for s in range(8):
+            rk = rank_ref[s:s + 1, :]                    # [1, row]
+            hit = hit_ref[s:s + 1, :] != 0
+            total = rk[:, row - 1:row] + c32(1)          # [1, 1]
+            for k0 in range(0, kt, kb):
+                oh = ((iota_k + c32(k0) == rk) & hit).astype(i8)
+                f = jax.lax.dot_general(
+                    val_ref[s], oh, (((1,), (1,)), ((), ())),
+                    preferred_element_type=i32)          # [4*words, kb]
+                filled = slot + c32(k0) < total
+                for w in range(words):
+                    out_refs[w][s:s + 1, k0:k0 + kb] = jnp.where(
+                        filled, _unpack_rows(f, 1, 4, 4 * w), c32(0))
+
+    @jax.jit
+    def run(hit, vals):
+        h2 = hit.astype(i32).reshape(nr, row)
+        rank = jnp.cumsum(h2, axis=1, dtype=i32) - 1
+        limbs = _group_limbs(vals, nr)
+        lane = pl.BlockSpec((8, row), lambda i: (i32(i), i32(0)))
+        slots = pl.BlockSpec((8, kt), lambda i: (i32(i), i32(0)))
+        outs = pl.pallas_call(
+            kern,
+            grid=(nr // 8,),
+            in_specs=[lane, lane,
+                      pl.BlockSpec((8, 4 * words, row),
+                                   lambda i: (i32(i), i32(0), i32(0)))],
+            out_specs=tuple([slots] * words),
+            out_shape=tuple(
+                [jax.ShapeDtypeStruct((nr, kt), i32)] * words),
+            interpret=interp,
+            name="crush_rowgather",
+        )(h2, rank, limbs)
+        return jnp.stack([o.reshape(nr * kt) for o in outs], axis=1)
+
+    return run
+
+
 def make_rowexpand_kernel(n_lanes: int, row: int, kt: int, words: int):
     """rowcompact's inverse, for the rows computed on its compacted
     lanes: a hit lane takes the row at its group's slot number rank(l),
@@ -757,7 +837,7 @@ def make_rowexpand_kernel(n_lanes: int, row: int, kt: int, words: int):
                          "misaligned" % (n_lanes, row, kt))
     nr = n_lanes // row
     interp = _interpret()
-    i8, i32, u32 = jnp.int8, jnp.int32, jnp.uint32
+    i8, i32 = jnp.int8, jnp.int32
     c32 = np.int32
 
     def kern(hit_ref, rank_ref, new_ref, *refs):
@@ -779,12 +859,7 @@ def make_rowexpand_kernel(n_lanes: int, row: int, kt: int, words: int):
     def run(hit, old, new):
         h2 = hit.astype(i32).reshape(nr, row)
         rank = jnp.cumsum(h2, axis=1, dtype=i32) - 1
-        # [nr, 4*words, kt] int8: word-major, then limb, biased by -128
-        nu = new.astype(i32).astype(u32).reshape(nr, kt, words)
-        nu = jnp.transpose(nu, (0, 2, 1))[:, :, None, :]
-        shifts = (jnp.arange(4, dtype=u32) * u32(8))[None, None, :, None]
-        limbs = (((nu >> shifts) & u32(0xFF)).astype(i32) - 128).astype(i8)
-        limbs = limbs.reshape(nr, 4 * words, kt)
+        limbs = _group_limbs(new, nr)
         lane = pl.BlockSpec((8, row), lambda i: (i32(i), i32(0)))
         shp = jax.ShapeDtypeStruct((nr, row), i32)
         outs = pl.pallas_call(

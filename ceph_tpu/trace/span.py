@@ -153,7 +153,11 @@ SPANS: dict[str, tuple[str, str]] = {
                     "tail, resolve_lanes flagged to the resolve chain, "
                     "retry_lanes an indep rule's first full-width round "
                     "left with an undefined slot (0 for firstn), "
-                    "none_slots of the up table that end ITEM_NONE"),
+                    "indep_tail_lanes the take-lanes of an indep rule's "
+                    "choose steps that ran their later rounds on the "
+                    "step's compacted tail (0 for firstn and without a "
+                    "tail), none_slots of the up table that end "
+                    "ITEM_NONE"),
     "crush.wait": (MAPPING, "the first blocking read: the host waits, "
                    "the device works"),
     "crush.readback": (MAPPING, "up/acting tables device -> host; bytes"),

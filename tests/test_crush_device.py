@@ -326,8 +326,10 @@ class TestDenseTail:
 
     HOSTS, PER_HOST, PG_NUM = 40, 5, 16384
     # rule 0: chooseleaf firstn 0 type host; rule 1: choose firstn 0
-    # type osd (a two-level descent, reweights reject at the leaf)
-    RULES = {0: (CHOOSELEAF_FIRSTN, 1), 1: (CHOOSE_FIRSTN, 0)}
+    # type osd (a two-level descent, reweights reject at the leaf);
+    # rule 2: chooseleaf indep 0 type host, whose tail is the step's own
+    RULES = {0: (CHOOSELEAF_FIRSTN, 1), 1: (CHOOSE_FIRSTN, 0),
+             2: (CHOOSELEAF_INDEP, 1)}
     _dm: dict = {}
 
     @classmethod
@@ -413,14 +415,14 @@ class TestDenseTail:
         assert dm.tail_overflows == overflows + 1
         assert st.tail_lanes == 0
         assert dm._tail_slots(ruleno, 2, self.PG_NUM, dm._tail_want[
-            (ruleno, 2, self.PG_NUM)]) == 0
+            (ruleno, 2, self.PG_NUM, 0)]) == 0
         # and the overflowing program itself flagged what it could not
         # seat, rather than cut it off: every lane it leaves unflagged
         # is exact
         import jax.numpy as jnp
         fn = dm._compiled_pool(ruleno, 2, True, False, self.PG_NUM,
                                self.PG_NUM - 1, 1, True, self.PG_NUM,
-                               1, slots)
+                               1, (slots,))
         raw1, _up, _prim, flag, tail = fn(
             jnp.asarray(w), jnp.ones(len(w), bool), jnp.asarray(w > 0),
             jnp.zeros(len(w), jnp.int32))
@@ -432,6 +434,60 @@ class TestDenseTail:
         assert seated == self.PG_NUM // dm.RC_ROW * slots
         assert unseated * 16 > seated and flag.sum() >= unseated
         self._assert_rows(m, ruleno, 2, w, np.asarray(raw1),
+                          [pg for pg in lanes if not flag[pg]])
+
+    @pytest.mark.parametrize("share", [0.0, 0.10, 0.13, 0.40])
+    def test_indep_tail_parity_and_counters(self, share):
+        """The indep twin: the step's first round over all lanes, its
+        later rounds on the takes that still had an undefined slot."""
+        dm, m = self._mapper()
+        dm._tail_want.clear()
+        w = self._weights(share)
+        start = dm._tail_start(2, 2, 0)
+        slots = dm._tail_slots(2, 2, self.PG_NUM, start)
+        # one collision in forty: 51 hits a row group expected, and a
+        # width that fills a Pallas tile
+        assert 51 < start < 128 and slots == 512
+        overflows = dm.tail_overflows
+        st = self._pass(dm, 2, 2, w)
+        lanes = random.Random(23).sample(range(self.PG_NUM), 300)
+        self._assert_rows(m, 2, 2, w, np.asarray(st.raw), lanes)
+        assert st.tail_lanes == 0      # firstn's count
+        assert dm.fm.descent_in_pallas[self.PG_NUM // dm.RC_ROW * slots]
+        if share < 0.40:
+            assert dm.tail_overflows == overflows
+            assert 0 < st.indep_tail_lanes <= st.retry_lanes
+            # every hit lane sat in the tail, or (0.13: row groups of
+            # over 512 hits) a few were left to the resolve chain
+            left = st.retry_lanes - st.indep_tail_lanes
+            assert (left > 0) == (share == 0.13)
+            assert left * 16 <= st.indep_tail_lanes
+            assert left <= st.resolve_lanes < st.retry_lanes
+            return
+        # more hits than slots: the pass was thrown away, the step's
+        # want is past what a tail may have, its rounds are dense again
+        assert dm.tail_overflows == overflows + 1
+        assert st.indep_tail_lanes == 0 and st.retry_lanes > 0
+        assert dm._tail_want[(2, 2, self.PG_NUM, 0)] > dm.INDEP_TAIL_KT_MAX
+        assert dm._tail_slots(2, 2, self.PG_NUM, dm._tail_want[
+            (2, 2, self.PG_NUM, 0)]) == 0
+        # and the program that ran over flagged what it could not seat:
+        # every lane it leaves unflagged is exact
+        import jax.numpy as jnp
+        fn = dm._compiled_pool(2, 2, True, False, self.PG_NUM,
+                               self.PG_NUM - 1, 1, True, self.PG_NUM,
+                               1, (slots,))
+        raw1, _up, _prim, flag, counts = fn(
+            jnp.asarray(w), jnp.ones(len(w), bool), jnp.asarray(w > 0),
+            jnp.zeros(len(w), jnp.int32))
+        flag = np.asarray(flag)
+        *firstn, retry, seated, unseated, largest = (
+            int(v) for v in np.asarray(counts))
+        assert firstn == [0, 0, 0]
+        assert largest > slots and retry == seated + unseated
+        assert seated == self.PG_NUM // dm.RC_ROW * slots
+        assert unseated * 16 > seated and flag.sum() >= unseated
+        self._assert_rows(m, 2, 2, w, np.asarray(raw1),
                           [pg for pg in lanes if not flag[pg]])
 
     @pytest.mark.parametrize("lanes,want,slots", [
@@ -456,23 +512,38 @@ class TestDenseTail:
             assert (lanes // dm.RC_ROW * slots) % 4096 == 0
 
     def test_no_tail_for_indep_or_without_pallas(self, monkeypatch):
+        """(The name is PR 34's: since PR 36 an indep step has a tail
+        of its own, sized from the step's geometry.)"""
         m = _two_level_map()
         dm = DeviceMapper(m)
         assert dm._tail_slots(0, 3, 1 << 20, dm.TAIL_KT) == 256
-        assert dm._tail_slots(1, 3, 1 << 20, dm.TAIL_KT) == 0    # indep
+        # indep: three draws among the map's hosts collide in
+        # collide * 2048 lanes of a row group
+        (step,) = dm._plan(1, 3).steps
+        assert 0 < step.collide < 1
+        start = dm._tail_start(1, 3, 0)
+        assert 2048 * step.collide < start < 2048
+        assert dm._tail_slots(1, 3, 1 << 20, start) == 128 * -(-start // 128)
+        assert dm._tail_slots(1, 3, 1 << 20, 1025) == 0  # past its most
+        assert dm._tail_slots(1, 3, 8192, start) == 0    # the full loops
         monkeypatch.delenv("CEPH_TPU_PALLAS_INTERPRET")
         assert dm._tail_slots(0, 3, 1 << 20, dm.TAIL_KT) == 0
+        assert dm._tail_slots(1, 3, 1 << 20, start) == 0
 
-    def test_no_tail_lanes_when_nothing_can_fail(self):
+    @pytest.mark.parametrize("ruleno", [1, 2])
+    def test_no_tail_lanes_when_nothing_can_fail(self, ruleno):
         """One replica and nothing out: no collision, no rejection, so
         the first rounds place every lane and the tail seats none."""
         dm, m = self._mapper()
         dm._tail_want.clear()
         w = self._weights(0.0)
-        st = self._pass(dm, 1, 1, w)
+        assert dm._tail_slots(ruleno, 1, self.PG_NUM,
+                              dm._tail_start(ruleno, 1, 0))
+        st = self._pass(dm, ruleno, 1, w)
         assert st.tail_lanes == 0 and dm.tail_overflows >= 0
+        assert st.indep_tail_lanes == 0 and st.retry_lanes == 0
         assert st.lanes == self.PG_NUM
-        self._assert_rows(m, 1, 1, w, np.asarray(st.raw),
+        self._assert_rows(m, ruleno, 1, w, np.asarray(st.raw),
                           random.Random(3).sample(range(self.PG_NUM),
                                                   100))
 

@@ -35,16 +35,16 @@ RULES = {
 }
 RULE_IDS = {name: i for i, name in enumerate(RULES)}
 
-def build(uneven: bool) -> tuple:
+def build(uneven: bool, racks: int = 3, hosts: int = 4) -> tuple:
     """3 racks x 4 hosts x 3 OSDs; uneven: rack 0 has two hosts (so an
     LRC group there has two holes), one host has five OSDs, weights
     differ."""
     m = CrushMap()
     m.types = {0: "osd", 1: "host", 2: "rack", 3: "root"}
     osd, bid, rack_ids = 0, -2, []
-    for r in range(3):
+    for r in range(racks):
         host_ids = []
-        for h in range(2 if uneven and r == 0 else 4):
+        for h in range(2 if uneven and r == 0 else hosts):
             n = 5 if uneven and (r, h) == (1, 1) else 3
             ws = [0x10000 + (0x3000 * ((osd + i) % 3) if uneven else 0)
                   for i in range(n)]
@@ -75,8 +75,8 @@ def weights_of(n: int) -> list:
 
 
 class Fixture:
-    def __init__(self, uneven: bool):
-        self.map, self.n = build(uneven)
+    def __init__(self, uneven: bool, **shape):
+        self.map, self.n = build(uneven, **shape)
         self.dm = DeviceMapper(self.map)
         self.host = Mapper(self.map)
         # the map and each rule as the plain reference takes them
@@ -94,6 +94,14 @@ def even():
 @pytest.fixture(scope="module")
 def uneven():
     return Fixture(True)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """8 racks of 16 hosts, rack 0 of two: few enough takes collide (an
+    eighth of the first step's, two fifths of the second's) for both
+    steps of the lrc rule to run their later rounds on a tail."""
+    return Fixture(True, racks=8, hosts=16)
 
 
 def padded(row: list, width: int) -> list:
@@ -190,6 +198,57 @@ def test_whole_pool_pass_equals_host(request, which, rule, size, can_shift,
     else:
         # firstn has no such round, and a small batch runs the full loops
         assert st.retry_lanes == 0
+    # no tail without the Pallas lane kernels, whatever the lanes
+    assert st.indep_tail_lanes == 0
+
+
+@pytest.mark.parametrize("rule,size", [("lrc", 8), ("ec1", 6)])
+def test_whole_pool_pass_with_the_indep_tail_equals_host(
+        monkeypatch, wide, rule, size):
+    """At 16,384 lanes and with the Pallas lane kernels (interpret mode
+    here) every indep step runs its first round over all its takes and
+    its later rounds on the compacted takes that still had an undefined
+    slot; rows equal the host engine's position by position, holes
+    included (rack 0 cannot fill four positions; an OSD is down)."""
+    from ceph_tpu.ops.crush import pallas_draw
+    monkeypatch.setenv("CEPH_TPU_PALLAS_INTERPRET", "1")
+    fx, pg_num = wide, 16384
+    ruleno = RULE_IDS[rule]
+    plan = fx.dm._plan(ruleno, size)
+    tails = [fx.dm._tail_slots(ruleno, size, pg_num,
+                               fx.dm._tail_start(ruleno, size, i), i)
+             for i in range(len(plan.steps))]
+    assert all(tails), tails
+    widths = [pg_num * f // fx.dm.RC_ROW * kt
+              for f, kt in zip(plan.lane_factors, tails)]
+    assert all(n % pallas_draw.TL == 0 for n in widths), widths
+    exists = np.ones(fx.n, bool)
+    isup = np.ones(fx.n, bool)
+    isup[11] = False
+    st = fx.dm.map_pool_state(ruleno, size, pg_num, pg_num, pg_num - 1, 1,
+                              True, np.asarray(fx.w, np.int32), exists,
+                              isup, None, False)
+    assert all(fx.dm.fm.descent_in_pallas[n] for n in widths)
+    raw, up = np.array(st.raw), np.array(st.up)
+    pps = pps_seed_v(np.arange(pg_num), pg_num, pg_num - 1, 1, True)
+    holes = 0
+    for ps in range(0, pg_num, 29):
+        want = padded(fx.host.do_rule(ruleno, int(pps[ps]), size, fx.w),
+                      size)
+        assert list(raw[ps]) == want, ps
+        assert list(up[ps]) == [ITEM_NONE if o == 11 else o
+                                for o in want], ps
+        holes += want.count(ITEM_NONE)
+    assert holes > 0 or rule != "lrc"
+    # takes seated in a tail: at most every take that failed, and (two
+    # takes a lane in the second step) more than the lanes that did
+    assert st.tail_lanes == 0 and fx.dm.tail_overflows == 0
+    assert 0 < st.retry_lanes < st.lanes
+    if rule == "lrc":
+        assert st.retry_lanes < st.indep_tail_lanes < 3 * st.lanes
+    else:
+        assert 0 < st.indep_tail_lanes <= st.retry_lanes
+    assert 0 < st.resolve_lanes < st.retry_lanes
 
 
 def test_one_step_pass_counts_one_step(even):
@@ -197,7 +256,8 @@ def test_one_step_pass_counts_one_step(even):
         RULE_IDS["rep1"], 3, 256, 256, 255, 1, True,
         np.asarray(even.w, np.int32), np.ones(even.n, bool),
         np.ones(even.n, bool), None, True)
-    assert (st.steps, st.retry_lanes, st.tail_lanes) == (1, 0, 0)
+    assert (st.steps, st.retry_lanes, st.tail_lanes,
+            st.indep_tail_lanes) == (1, 0, 0, 0)
 
 
 OUTSIDE = {

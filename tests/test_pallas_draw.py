@@ -273,3 +273,26 @@ def test_rowexpand_puts_compacted_rows_back(density):
     assert (got[~hit] == old[~hit]).all()
     assert int(valid.sum()) == (n // row * kt if density == 0.2
                                 else int(hit.sum()))
+
+
+@pytest.mark.parametrize("density", [0.0, 0.087, 0.3])
+def test_rowgather_fetches_the_seated_lanes_words(density):
+    """rowgather beside rowcompact, at an indep tail's geometry: slot j
+    of a row group holds the words of the group's j-th hit lane
+    (negative bucket ids, ITEM_UNDEF and ITEM_NONE survive the 8-bit
+    limbs), a slot past the group's hits reads 0, and a group with more
+    hits than slots fetches its first 512."""
+    n, row, kt, words = 32768, dev.DeviceMapper.RC_ROW, 512, 9
+    rng = np.random.default_rng(int(density * 1000) + 17)
+    hit = rng.random(n) < density
+    vals = rng.integers(-2 ** 31, 2 ** 31, (n, words)).astype(np.int32)
+    vals[::5, 2], vals[::7, 3] = 0x7FFFFFFE, 0x7FFFFFFF
+    idx, valid, cnt = (np.asarray(a) for a in pd.make_rowcompact_kernel(
+        n, row, kt, n)(jnp.asarray(hit)))
+    got = np.asarray(pd.make_rowgather_kernel(n, row, kt, words)(
+        jnp.asarray(hit), jnp.asarray(vals)))
+    np.testing.assert_array_equal(
+        got, np.where(valid[:, None], vals[idx], 0))
+    assert (cnt > kt).all() == (density == 0.3)
+    assert int(valid.sum()) == (n // row * kt if density == 0.3
+                                else int(hit.sum()))

@@ -343,6 +343,9 @@ def test_traced_remap_counts_lanes_and_bytes(traced_remap):
     # firstn: no indep round to leave a slot undefined; the holes of the
     # up table (two hosts cannot seat three replicas) are counted
     assert counted["retry_lanes"] == 0
+    # nor an indep step's tail, and SPANS says what the count is
+    assert counted["indep_tail_lanes"] == 0
+    assert all(arg in SPANS["crush.lanes"][1] for arg in counted)
     assert counted["none_slots"] == int((pm.up == 0x7FFFFFFF).sum()) > 0
     (_a, _b, back), = by["crush.readback"]
     assert back["bytes"] == pm.up.nbytes + pm.up_primary.nbytes

@@ -134,6 +134,55 @@ def test_crush_descend_compiles_for_the_two_step_rule(
     _compiled_text(fn, one_chip, lanes, lanes, lanes, lanes)
 
 
+def test_lrc_pool_program_compiles_with_its_tails(one_chip, mosaic):
+    """The dense program of the benchmark's 4M-PG LRC pool, as
+    map_pool_state builds it: both steps of the rule with a tail of
+    their own, sized from the steps' geometry, every descent in Pallas
+    at the chunk's, the takes' and both tails' widths, and no more
+    code than the three dense rounds it replaces (68.5 MB and nine
+    descents when compiled here for this chip at PR 35; the tails'
+    rounds are a loop, so the program holds six)."""
+    import json
+    import os
+    from benchmark.drivers.crush_churn_rules import build_crush, make_rule
+    from ceph_tpu.ops.crush.device import DeviceMapper
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "crush-10kosd-lrc-4m.json")) as f:
+        cfg = json.load(f)
+    crush = build_crush(cfg)
+    ruleno = make_rule(cfg, crush)
+    dm = DeviceMapper(crush)
+    chunk, n_chunks, size = DeviceMapper.CHUNK, 4, 8
+    tails = tuple(
+        dm._tail_slots(ruleno, size, chunk,
+                       dm._tail_start(ruleno, size, i), i)
+        for i in range(2))
+    # two racks of 20 collide in 5% of the lanes, four hosts of 25 in
+    # 22% of the takes: 102 and 456 hits a row group and two deviations
+    assert tails == (128, 512)
+    pg_num = chunk * n_chunks
+    fn = dm._compiled_pool(ruleno, size, False, False, pg_num,
+                           pg_num - 1, 1, True, chunk, n_chunks, tails)
+    osds = 10000
+    args = [jax.ShapeDtypeStruct((osds,), d, sharding=one_chip)
+            for d in (jnp.int32, np.bool_, np.bool_, jnp.int32)]
+    compiled = fn.lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    for kernel, times in (("crush_straw2_descend", 6),
+                          ("crush_rowcompact", 2), ("crush_rowgather", 2),
+                          ("crush_rowexpand", 2), ("crush_post_up", 1)):
+        assert sum('/%s/pallas_call"' % kernel in line
+                   for line in calls) == times, kernel
+    widths = (chunk, 2 * chunk, chunk // 2048 * 128,
+              2 * chunk // 2048 * 512)
+    assert all(dm.fm.descent_in_pallas[n] for n in widths)
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    print("LRC pool program: %.1f MB of code" % (code / 1e6))
+    assert code < 68.5e6
+
+
 def test_crush_post_compiles_for_an_erasure_pool(one_chip, mosaic):
     """Eight positional slots, nothing shifts, one chunk of a pass."""
     from ceph_tpu.ops.crush import pallas_draw
@@ -147,7 +196,8 @@ NPG = 10 << 20
 
 
 @pytest.mark.parametrize("kernel", ["post", "hitscan", "rowcompact",
-                                    "rowcompact-tail", "rowexpand-tail"])
+                                    "rowcompact-tail", "rowgather-tail",
+                                    "rowexpand-tail"])
 def test_crush_lane_kernels_compile(one_chip, mosaic, kernel):
     from ceph_tpu.ops.crush import pallas_draw
     from ceph_tpu.ops.crush.device import DeviceMapper
@@ -169,6 +219,14 @@ def test_crush_lane_kernels_compile(one_chip, mosaic, kernel):
         fn = pallas_draw.make_rowcompact_kernel(
             chunk, DeviceMapper.RC_ROW, DeviceMapper.TAIL_KT, chunk)
         _compiled_text(fn, one_chip, ((chunk,), np.bool_))
+    elif kernel == "rowgather-tail":
+        # an indep step's widest tail on a second step's takes: four
+        # slots of out and of leaves, and the take
+        lanes, kt = 2 * DeviceMapper.CHUNK, DeviceMapper.INDEP_TAIL_KT_MAX
+        fn = pallas_draw.make_rowgather_kernel(
+            lanes, DeviceMapper.RC_ROW, kt, 9)
+        _compiled_text(fn, one_chip, ((lanes,), np.bool_),
+                       ((lanes, 9), jnp.int32))
     else:
         # three replicas and the flag, at the widest tail there is
         chunk, kt = DeviceMapper.CHUNK, DeviceMapper.TAIL_KT_MAX
