@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import matrices
+from ..trace.span import scope
 
 # ---------------------------------------------------------------------------
 # bit-plane helpers
@@ -406,7 +407,7 @@ def _fused_xor_pallas(bitmatrix: np.ndarray, tile_lanes: int):
                           (r * 8 + s + 1) * seg] = segs[s][r:r + 1, :]
 
     @jax.jit
-    @jax.named_scope("ec_encode_fused")
+    @scope("ec.encode")
     def run(data32: jax.Array) -> jax.Array:
         P = data32.shape[1]
         pad = (-P) % tile_lanes

@@ -61,6 +61,7 @@ from __future__ import annotations
 import collections
 import functools
 import math
+from contextlib import nullcontext
 
 import numpy as np
 
@@ -83,7 +84,7 @@ from ...models.crushmap import (
     TAKE,
     CrushMap,
 )
-from ...trace.span import mark, span
+from ...trace.span import mark, scope, span
 from ._ln_tables import LL_TBL, RH_LH_TBL
 
 S64_MAX = (1 << 63) - 1
@@ -715,6 +716,7 @@ def _get_pallas_descend(fm: FlatMap, depth_sizes: tuple,
     return cache[key]
 
 
+@scope("crush.descend")
 def _descend(fm: FlatMap, take_bid, x, r, want_type: int, pos,
              depth_sizes: tuple, resolve: bool,
              crow0: _ConstRow | None = None):
@@ -798,6 +800,7 @@ def small_fetch(table_i32, idx, n_limbs: int):
     return unpack_limbs32(pl, n_limbs)
 
 
+@scope("crush.is_out")
 def _is_out(dev_weights, item, x):
     """Reweight rejection (mapper.c:402-416).  Reweights are 16.16
     capped at 0x10000 (17 bits), so three limb planes suffice."""
@@ -1222,19 +1225,24 @@ def _choose_indep_vec(fm: FlatMap, take, xs, numrep: int,
         return jnp.where(res == ITEM_UNDEF, ITEM_NONE, res), flag
 
     n_rounds = min(_ATTEMPT_TRIES, tries)
-    out = leaves = _indep_start(L, slots, nslots)
-    out, leaves, flag = round_(jnp.int32(0), take_bid, xs, out, leaves,
-                               jnp.zeros((L,), bool))
-    retry = jnp.any(out == ITEM_UNDEF, axis=1)
+    with scope("crush.step"):
+        out = leaves = _indep_start(L, slots, nslots)
+    with scope("crush.first"):
+        out, leaves, flag = round_(jnp.int32(0), take_bid, xs, out,
+                                   leaves, jnp.zeros((L,), bool))
+    with scope("crush.step"):
+        retry = jnp.any(out == ITEM_UNDEF, axis=1)
     if tail is None or n_rounds < 2:
-        for ft in range(1, n_rounds):
-            out, leaves, flag = round_(jnp.int32(ft), take_bid, xs, out,
-                                       leaves, flag)
-        return rows_of(out, leaves, flag) + (retry, None)
+        with scope("crush.first"):
+            for ft in range(1, n_rounds):
+                out, leaves, flag = round_(jnp.int32(ft), take_bid, xs,
+                                           out, leaves, flag)
+        with scope("crush.step"):
+            return rows_of(out, leaves, flag) + (retry, None)
 
     from . import pallas_draw
-    with jax.named_scope("crush_indep_tail"):
-        row, kt = DeviceMapper.RC_ROW, tail.kt
+    row, kt = DeviceMapper.RC_ROW, tail.kt
+    with scope("crush.tail.move"):
         idx, _valid, cnt = pallas_draw.make_rowcompact_kernel(
             L, row, kt, L)(retry)
         # what a seated lane's later rounds start from: its take and, per
@@ -1253,12 +1261,14 @@ def _choose_indep_vec(fm: FlatMap, take, xs, numrep: int,
                   else state_t[:, -1])
         xs_t = tail.seeds(idx)
 
-        def later(ft, st):
-            return round_(ft.astype(jnp.int32), take_t, xs_t, *st)
+    def later(ft, st):
+        return round_(ft.astype(jnp.int32), take_t, xs_t, *st)
 
+    with scope("crush.tail.rounds"):
         out_t, leaves_t, flag_t = jax.lax.fori_loop(
             1, n_rounds, later,
             (out_t, leaves_t, jnp.zeros(idx.shape, bool)))
+    with scope("crush.tail.move"):
         res, _ = rows_of(out, leaves, flag)
         res_t, flag_t = rows_of(out_t, leaves_t, flag_t)
         # a seated lane takes its tail row and adds the tail's flag to its
@@ -1268,10 +1278,14 @@ def _choose_indep_vec(fm: FlatMap, take, xs, numrep: int,
             jnp.concatenate([res, retry[:, None].astype(jnp.int32)], axis=1),
             jnp.concatenate([res_t, flag_t[:, None].astype(jnp.int32)],
                             axis=1))
-    seated = jnp.minimum(cnt, kt)
-    return (rows[:, :-1], flag | (rows[:, -1] != 0), retry,
-            jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
-                       jnp.max(cnt)]))
+    with scope("crush.step"):
+        seated = jnp.minimum(cnt, kt)
+    with scope("crush.tail.move"):
+        rows, flag = rows[:, :-1], flag | (rows[:, -1] != 0)
+    with scope("crush.step"):
+        return (rows, flag, retry,
+                jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
+                           jnp.max(cnt)]))
 
 
 def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
@@ -1298,45 +1312,51 @@ def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
     retry, seats): seats as _choose_indep_vec's."""
     L, n_in = w.shape
     slots = min(st.numrep, result_max)
-    valid = (w < 0).T                                      # [n_in, L]
-    take = jnp.where(valid, -1 - w.T, 0).reshape(-1)
-    xs_t = jnp.tile(xs, n_in)
+    with scope("crush.step"):
+        valid = (w < 0).T                                  # [n_in, L]
+        take = jnp.where(valid, -1 - w.T, 0).reshape(-1)
+        xs_t = jnp.tile(xs, n_in)
     if st.firstn:
+        with scope("crush.step"):
+            limit = jnp.where(valid, slots, 0).reshape(-1)
         rows, placed, flag, unfinished = _choose_firstn_vec(
             fm, take, xs_t, st.numrep, result_max, st.want_type, st.leaf,
             dev_weights, st.tries, st.recurse, st.vary_r, st.stable,
-            st.outer_ds, st.inner_ds, resolve, full, None,
-            limit=jnp.where(valid, slots, 0).reshape(-1))
-        flag = flag | unfinished
-        retry, seats = jnp.zeros_like(flag), None
-        placed = placed.reshape(n_in, L)
+            st.outer_ds, st.inner_ds, resolve, full, None, limit=limit)
+        with scope("crush.step"):
+            flag = flag | unfinished
+            retry, seats = jnp.zeros_like(flag), None
+            placed = placed.reshape(n_in, L)
     else:
-        osize = jnp.zeros((L,), jnp.int32)
-        placed = []
-        for i in range(n_in):
-            placed.append(jnp.where(
-                valid[i], jnp.minimum(slots, result_max - osize), 0))
-            osize = osize + placed[i]
-        placed = jnp.stack(placed)
+        with scope("crush.step"):
+            osize = jnp.zeros((L,), jnp.int32)
+            placed = []
+            for i in range(n_in):
+                placed.append(jnp.where(
+                    valid[i], jnp.minimum(slots, result_max - osize), 0))
+                osize = osize + placed[i]
+            placed = jnp.stack(placed)
+            nslots = placed.reshape(-1)
         if tail is not None:
             pg_seeds = tail.seeds
             tail = tail._replace(seeds=lambda j: pg_seeds(j % L))
         rows, flag, retry, seats = _choose_indep_vec(
             fm, take, xs_t, st.numrep, result_max, st.want_type, st.leaf,
             dev_weights, st.tries, st.recurse, st.outer_ds, st.inner_ds,
-            resolve, full, None, nslots=placed.reshape(-1), tail=tail)
-    rows = rows.reshape(n_in, L, slots)
-    flag = jnp.any(flag.reshape(n_in, L) & valid, axis=0)
-    retry = jnp.any(retry.reshape(n_in, L) & valid, axis=0)
-    out = jnp.full((L, st.width), ITEM_NONE, jnp.int32)
-    cols = jnp.arange(st.width)[None, :]
-    osize = jnp.zeros((L,), jnp.int32)
-    for i in range(n_in):
-        n_i = jnp.minimum(placed[i], result_max - osize)
-        for k in range(slots):
-            put = (cols == (osize + k)[:, None]) & (k < n_i)[:, None]
-            out = jnp.where(put, rows[i, :, k:k + 1], out)
-        osize = osize + n_i
+            resolve, full, None, nslots=nslots, tail=tail)
+    with scope("crush.step"):
+        rows = rows.reshape(n_in, L, slots)
+        flag = jnp.any(flag.reshape(n_in, L) & valid, axis=0)
+        retry = jnp.any(retry.reshape(n_in, L) & valid, axis=0)
+        out = jnp.full((L, st.width), ITEM_NONE, jnp.int32)
+        cols = jnp.arange(st.width)[None, :]
+        osize = jnp.zeros((L,), jnp.int32)
+        for i in range(n_in):
+            n_i = jnp.minimum(placed[i], result_max - osize)
+            for k in range(slots):
+                put = (cols == (osize + k)[:, None]) & (k < n_i)[:, None]
+                out = jnp.where(put, rows[i, :, k:k + 1], out)
+            osize = osize + n_i
     return out, flag, retry, seats
 
 
@@ -1759,8 +1779,9 @@ class DeviceMapper:
                     resolve, full, rootc, first_only)
                 if first_only:
                     return res, flag, unfinished
-                flag = flag | unfinished
-                retry, seats = jnp.zeros_like(flag), [None]
+                with scope("crush.step"):
+                    flag = flag | unfinished
+                    retry, seats = jnp.zeros_like(flag), [None]
             else:
                 res, flag, retry, seat = _choose_indep_vec(
                     fm, plan.take_id, xs, head.numrep, result_max,
@@ -1772,13 +1793,15 @@ class DeviceMapper:
                 res, f, rt, seat = _chain_step(
                     fm, st, res, xs, result_max, dev_weights, resolve,
                     full, tl)
-                flag, retry = flag | f, retry | rt
+                with scope("crush.step"):
+                    flag, retry = flag | f, retry | rt
                 seats.append(seat)
             if tails is None:
                 return res, flag
-            return res, flag, jnp.concatenate(
-                [jnp.sum(retry, dtype=jnp.int32)[None]]
-                + [seat for seat in seats if seat is not None])
+            with scope("crush.step"):
+                return res, flag, jnp.concatenate(
+                    [jnp.sum(retry, dtype=jnp.int32)[None]]
+                    + [seat for seat in seats if seat is not None])
 
         return core
 
@@ -1970,37 +1993,53 @@ class DeviceMapper:
             rc = pallas_draw.make_rowcompact_kernel(n, self.RC_ROW,
                                                     kt_tail, n)
 
+        @scope("crush.seeds")
         def pps(ps):
             return _pps(ps, pgp_num, pgp_mask, pool_id, hashps)
 
         def descend(start, dev_weights):
             xs = pps(jnp.arange(n, dtype=jnp.uint32) + start)
             if not kt_tail:
-                raw, flag, counts = core(
-                    xs, dev_weights,
-                    lambda lane: pps(lane.astype(jnp.uint32) + start))
-                return xs, raw, flag, jnp.concatenate(
-                    [jnp.zeros((3,), jnp.int32), counts])
-            raw, flag, unfinished = first(xs, dev_weights)
-            idx, _valid, cnt = rc(unfinished)
-            raw_t, flag_t = core(pps(idx.astype(jnp.uint32) + start),
-                                 dev_weights)
+                # a firstn rule's rounds all run here at full width; an
+                # indep rule's steps scope their own rounds and tails
+                with scope("crush.first") if firstn else nullcontext():
+                    raw, flag, counts = core(
+                        xs, dev_weights,
+                        lambda lane: pps(lane.astype(jnp.uint32) + start))
+                with scope("crush.step"):
+                    return xs, raw, flag, jnp.concatenate(
+                        [jnp.zeros((3,), jnp.int32), counts])
+            with scope("crush.first"):
+                raw, flag, unfinished = first(xs, dev_weights)
+            with scope("crush.tail.move"):
+                idx, _valid, cnt = rc(unfinished)
+                xs_t = pps(idx.astype(jnp.uint32) + start)
+            with scope("crush.tail.rounds"):
+                raw_t, flag_t = core(xs_t, dev_weights)
             # a seated lane takes its replayed row and flag; one that
             # got no slot keeps its row, flagged for the resolve chain
             expand = pallas_draw.make_rowexpand_kernel(
                 n, self.RC_ROW, kt_tail, raw.shape[1] + 1)
-            rows = expand(
-                unfinished,
-                jnp.concatenate(
-                    [raw, (flag | unfinished)[:, None].astype(jnp.int32)],
-                    axis=1),
-                jnp.concatenate(
-                    [raw_t, flag_t[:, None].astype(jnp.int32)], axis=1))
-            seated = jnp.minimum(cnt, kt_tail)
-            return (xs, rows[:, :-1], rows[:, -1] != 0,
-                    jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
-                               jnp.max(cnt), jnp.int32(0)]))
+            with scope("crush.tail.move"):
+                rows = expand(
+                    unfinished,
+                    jnp.concatenate(
+                        [raw,
+                         (flag | unfinished)[:, None].astype(jnp.int32)],
+                        axis=1),
+                    jnp.concatenate(
+                        [raw_t, flag_t[:, None].astype(jnp.int32)],
+                        axis=1))
+            with scope("crush.step"):
+                seated = jnp.minimum(cnt, kt_tail)
+            with scope("crush.tail.move"):
+                rows, flag = rows[:, :-1], rows[:, -1] != 0
+            with scope("crush.step"):
+                return (xs, rows, flag,
+                        jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
+                                   jnp.max(cnt), jnp.int32(0)]))
 
+        @scope("crush.post")
         def post(raw, xs, exists_b, isup_b, aff):
             if not use_aff:
                 if (pallas_draw.pallas_enabled()
@@ -2015,25 +2054,25 @@ class DeviceMapper:
         @jax.jit
         def run(dev_weights, exists_b, isup_b, aff):
             def body(_, start):
-                with jax.named_scope("crush_descend"):
-                    xs, raw, flag, tail = descend(start, dev_weights)
-                with jax.named_scope("crush_post"):
-                    up, prim = post(raw, xs, exists_b, isup_b, aff)
+                xs, raw, flag, tail = descend(start, dev_weights)
+                up, prim = post(raw, xs, exists_b, isup_b, aff)
                 return 0, (raw, up, prim, flag, tail)
 
-            starts = (jnp.arange(n_chunks, dtype=jnp.uint32)
-                      * _u32(n))
+            with scope("crush.step"):
+                starts = (jnp.arange(n_chunks, dtype=jnp.uint32)
+                          * _u32(n))
             _, (raws, ups, prims, flags, counts) = jax.lax.scan(
                 body, 0, starts)
             S = ups.shape[2]
             # per chunk: seated, unseated, largest group; the retry
             # count; then the same three per indep step with a tail
-            return (raws.reshape(-1, S), ups.reshape(-1, S),
-                    prims.reshape(-1), flags.reshape(-1),
-                    jnp.stack([
-                        (jnp.max if i == 2 or (i > 3 and i % 3 == 0)
-                         else jnp.sum)(counts[:, i])
-                        for i in range(counts.shape[1])]))
+            with scope("crush.step"):
+                return (raws.reshape(-1, S), ups.reshape(-1, S),
+                        prims.reshape(-1), flags.reshape(-1),
+                        jnp.stack([
+                            (jnp.max if i == 2 or (i > 3 and i % 3 == 0)
+                             else jnp.sum)(counts[:, i])
+                            for i in range(counts.shape[1])]))
 
         return run
 
@@ -2060,17 +2099,21 @@ class DeviceMapper:
         rcore = self._compile(ruleno, result_max, True, True)
         acore = self._compile(ruleno, result_max, "all", True)
 
+        @scope("crush.seeds")
         def pps(idx):
             return _pps(idx, pgp_num, pgp_mask, pool_id, hashps)
 
         def settle(core_fn, raw_t, up, prim, lanes, w, ex, iu, af):
-            xs = pps(lanes)
-            rr, f = core_fn(xs, w)
-            u2, p2 = _post_process(rr, xs, ex, iu, af, can_shift,
-                                   use_aff)
-            raw_t = raw_t.at[lanes].set(rr.astype(jnp.int32))
-            up = up.at[lanes].set(u2.astype(jnp.int32))
-            prim = prim.at[lanes].set(p2.astype(jnp.int32))
+            with scope("crush.settle.draw"):
+                xs = pps(lanes)
+                rr, f = core_fn(xs, w)
+            with scope("crush.settle.post"):
+                u2, p2 = _post_process(rr, xs, ex, iu, af, can_shift,
+                                       use_aff)
+            with scope("crush.settle.scatter"):
+                raw_t = raw_t.at[lanes].set(rr.astype(jnp.int32))
+                up = up.at[lanes].set(u2.astype(jnp.int32))
+                prim = prim.at[lanes].set(p2.astype(jnp.int32))
             return raw_t, up, prim, f
 
         def chain(raw_t, up, prim, flag, nflag, to_lane, w, ex, iu,
@@ -2080,25 +2123,29 @@ class DeviceMapper:
             compact to index 0 whose resolved row is exact anyway, but
             their FLAGS must be masked (pads mirror position 0 — if it
             flags, every pad copy would flag with it)."""
-            pos = jnp.nonzero(flag, size=K1, fill_value=0)[0]
-            idx = to_lane(pos)
+            with scope("crush.resolve.compact"):
+                pos = jnp.nonzero(flag, size=K1, fill_value=0)[0]
+                idx = to_lane(pos)
             # stage A: exact draws through the bounded attempt
             # structure (covers the f32-uncertainty majority)
-            raw_t, up, prim, f2 = settle(acore_a, raw_t, up, prim,
-                                         idx, w, ex, iu, af)
-            f2 = f2 & (jnp.arange(K1, dtype=jnp.int32) < nflag)
-            n2 = jnp.sum(f2, dtype=jnp.int32)
+            with scope("crush.resolve.a"):
+                raw_t, up, prim, f2 = settle(acore_a, raw_t, up, prim,
+                                             idx, w, ex, iu, af)
+                f2 = f2 & (jnp.arange(K1, dtype=jnp.int32) < nflag)
+                n2 = jnp.sum(f2, dtype=jnp.int32)
             # stage B: stragglers (unfinished retries + dust) through
             # the full retry loops, on a compacted subset
-            lanesB = idx[jnp.nonzero(f2, size=K2, fill_value=0)[0]]
-            raw_t, up, prim, f3 = settle(rcore, raw_t, up, prim,
-                                         lanesB, w, ex, iu, af)
-            f3 = f3 & (jnp.arange(K2, dtype=jnp.int32) < n2)
-            n3 = jnp.sum(f3, dtype=jnp.int32)
+            with scope("crush.resolve.b"):
+                lanesB = idx[jnp.nonzero(f2, size=K2, fill_value=0)[0]]
+                raw_t, up, prim, f3 = settle(rcore, raw_t, up, prim,
+                                             lanesB, w, ex, iu, af)
+                f3 = f3 & (jnp.arange(K2, dtype=jnp.int32) < n2)
+                n3 = jnp.sum(f3, dtype=jnp.int32)
             # stage C: residual top-3-ambiguous dust, fully exact
-            lanesC = lanesB[jnp.nonzero(f3, size=K3, fill_value=0)[0]]
-            raw_t, up, prim, _ = settle(acore, raw_t, up, prim,
-                                        lanesC, w, ex, iu, af)
+            with scope("crush.resolve.c"):
+                lanesC = lanesB[jnp.nonzero(f3, size=K3, fill_value=0)[0]]
+                raw_t, up, prim, _ = settle(acore, raw_t, up, prim,
+                                            lanesC, w, ex, iu, af)
             return raw_t, up, prim, n2, n3
 
         return pps, settle, chain
@@ -2145,27 +2192,30 @@ class DeviceMapper:
                   npg, self.RC_ROW, kt, pg_num) if kt else None)
 
         @jax.jit
-        @jax.named_scope("crush_resolve")
         def run(raw_t, up, prim, flag, tail, w, ex, iu, af):
             if rc is not None:
-                idxp, validp, cnt = rc(flag)
-                nflag = jnp.sum(validp, dtype=jnp.int32)
-                rowmax = jnp.max(cnt)
+                with scope("crush.resolve.compact"):
+                    idxp, validp, cnt = rc(flag)
+                    nflag = jnp.sum(validp, dtype=jnp.int32)
+                    rowmax = jnp.max(cnt)
                 raw_t, up, prim, n2, n3 = chain(
                     raw_t, up, prim, validp, nflag,
                     lambda p: idxp[p], w, ex, iu, af)
             else:
-                flag2 = flag & (jnp.arange(npg, dtype=jnp.int32)
-                                < pg_num)
-                nflag = jnp.sum(flag2, dtype=jnp.int32)
-                rowmax = jnp.int32(0)
+                with scope("crush.resolve.compact"):
+                    flag2 = flag & (jnp.arange(npg, dtype=jnp.int32)
+                                    < pg_num)
+                    nflag = jnp.sum(flag2, dtype=jnp.int32)
+                    rowmax = jnp.int32(0)
                 raw_t, up, prim, n2, n3 = chain(
                     raw_t, up, prim, flag2, nflag, lambda p: p, w, ex,
                     iu, af)
-            none = jnp.sum((up == ITEM_NONE) & (jnp.arange(
-                npg, dtype=jnp.int32) < pg_num)[:, None], dtype=jnp.int32)
-            return raw_t, up, prim, jnp.concatenate(
-                [jnp.stack([nflag, n2, n3, rowmax]), tail, none[None]])
+            with scope("crush.resolve.counts"):
+                none = jnp.sum((up == ITEM_NONE) & (jnp.arange(
+                    npg, dtype=jnp.int32) < pg_num)[:, None],
+                    dtype=jnp.int32)
+                return raw_t, up, prim, jnp.concatenate(
+                    [jnp.stack([nflag, n2, n3, rowmax]), tail, none[None]])
 
         return run
 

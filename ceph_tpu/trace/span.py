@@ -20,12 +20,23 @@ the span covers).  A name that is not in it raises at the emit site, and
 tests/test_spans.py holds the table and the emit sites to each other.
 Never name a span ``bench.*``: the benchmark finds its window by that
 prefix.
+
+``scope(name)`` is the device's side of the same idea: JAX's name scope
+``rados.<name>`` around a stage of a jitted program, from the second
+registry ``SCOPES``.  It is metadata of the instructions traced inside
+it and nothing else (no operation is added: tests/test_scopes.py and the
+crush tests compare the lowered modules), so it is always on.  Scopes
+nest, and an instruction's path is the list of registered scopes around
+it, outermost first; the device trace carries it and
+benchmark/harness/device_scopes.py reads it.  No name scope is written
+under ceph_tpu/ but through it.
 """
 
 from __future__ import annotations
 
 import gc
 
+import jax
 from jax.profiler import TraceAnnotation
 
 PREFIX = "rados."
@@ -34,6 +45,7 @@ CLIENT = "client"
 HOST = "host path"
 BATCHER = "batcher and device runtime"
 MAPPING = "bulk mapping"
+KERNELS = "kernels"
 
 SPANS: dict[str, tuple[str, str]] = {
     # -- the served write path, by what the loop's one thread is doing ----
@@ -165,13 +177,60 @@ SPANS: dict[str, tuple[str, str]] = {
                      "PoolMapping"),
 }
 
+# stages of the jitted programs, by what a change to them has to price
+SCOPES: dict[str, tuple[str, str]] = {
+    "crush.seeds": (KERNELS, "pg number -> placement seed x (the dense "
+                    "pass's chunk, a tail's lanes, the resolve chain's)"),
+    "crush.first": (KERNELS, "the optimistic rounds that run over all of "
+                    "a chunk's lanes: firstn's first round of each "
+                    "replica (every round where the pass has no tail), "
+                    "an indep step's round 0 (and 1, 2 without a tail)"),
+    "crush.tail.move": (KERNELS, "lanes into and out of a compacted "
+                        "tail: rowcompact, rowgather, rowexpand, and the "
+                        "concatenations and slices around them"),
+    "crush.tail.rounds": (KERNELS, "the retry rounds at a tail's width"),
+    "crush.step": (KERNELS, "between rounds and steps: a later step's "
+                   "takes and windows, the working vector's assembly, "
+                   "the start vector, the per-chunk counts"),
+    "crush.descend": (KERNELS, "one descent bucket -> item of a type, "
+                      "the Pallas kernel or XLA's exact form, wherever "
+                      "it runs"),
+    "crush.is_out": (KERNELS, "the reweight test of a drawn device"),
+    "crush.post": (KERNELS, "raw rows -> up rows and primaries"),
+    "crush.resolve.compact": (KERNELS, "flagged lanes -> the resolve "
+                              "chain's K1 slots"),
+    "crush.resolve.a": (KERNELS, "stage A: the exact attempt structure "
+                        "over the K1 slots"),
+    "crush.resolve.b": (KERNELS, "stage B: what A left, through the "
+                        "full retry loops, K2 slots"),
+    "crush.resolve.c": (KERNELS, "stage C: top-3-ambiguous dust, the "
+                        "all-integer draw, K3 slots"),
+    "crush.settle.draw": (KERNELS, "inside a stage: seeds and the "
+                          "rule's draws for the stage's lanes"),
+    "crush.settle.post": (KERNELS, "inside a stage: raw -> up, primary "
+                          "for the stage's lanes"),
+    "crush.settle.scatter": (KERNELS, "inside a stage: the three "
+                             "tables' rows put back by XLA's scatter"),
+    "crush.resolve.counts": (KERNELS, "the up table's NONE slots "
+                             "counted, the pass's counters joined"),
+    "ec.encode": (KERNELS, "the fused encoder's program: pad, the "
+                  "Pallas kernel ec_encode_fused, slice"),
+}
+
 _FULL = {name: PREFIX + name for name in SPANS}
+_FULL_SCOPE = {name: PREFIX + name for name in SCOPES}
 
 
 def span(name: str, **args) -> TraceAnnotation:
     """``with span("osd.handle_op"): ...`` around a synchronous
     section."""
     return TraceAnnotation(_FULL[name], **args)
+
+
+def scope(name: str):
+    """``with scope("crush.first"): ...`` around a stage while a jitted
+    program is traced (also a decorator)."""
+    return jax.named_scope(_FULL_SCOPE[name])
 
 
 def mark(name: str, **args) -> None:

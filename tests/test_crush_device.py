@@ -436,6 +436,53 @@ class TestDenseTail:
         self._assert_rows(m, ruleno, 2, w, np.asarray(raw1),
                           [pg for pg in lanes if not flag[pg]])
 
+    def _tail_program(self, dm):
+        """The firstn pool program with a tail that the passes above
+        ran, and the shapes they gave it."""
+        import jax
+        import jax.numpy as jnp
+        n = self.HOSTS * self.PER_HOST
+        slots = dm._tail_slots(0, 2, self.PG_NUM, dm.TAIL_KT)
+        fn = dm._compiled_pool(0, 2, True, False, self.PG_NUM,
+                               self.PG_NUM - 1, 1, True, self.PG_NUM, 1,
+                               (slots,))
+        return fn, [jax.ShapeDtypeStruct((n,), dt) for dt in
+                    (jnp.int32, bool, bool, jnp.int32)]
+
+    def test_the_tail_program_carries_its_scopes(self):
+        """The compiled module the passes above ran (no second compile):
+        every stage of a firstn pass with a tail is named, a descent
+        stands under a first round or under the tail's rounds, and the
+        chunk loop's own instructions are all that has no scope."""
+        from tests.test_scopes import (POOL_SCOPES, scope_paths,
+                                       scoped_share)
+        fn, shapes = self._tail_program(self._mapper()[0])
+        assert fn._cache_size() >= 1, "run after the passes above"
+        paths = scope_paths(fn.lower(*shapes).compile().as_text())
+        assert {name for p in paths for name in p} == POOL_SCOPES
+        assert scoped_share(paths) >= 95.0, scoped_share(paths)
+        assert ("crush.first", "crush.descend") in paths
+        assert ("crush.tail.rounds", "crush.descend") in paths
+        assert ("crush.tail.move", "crush.seeds") in paths
+        assert all(p[0] in ("crush.first", "crush.tail.rounds")
+                   for p in paths if "crush.descend" in p), paths
+
+    def test_a_scope_adds_no_operation_to_the_tail_program(
+            self, monkeypatch):
+        """The same program lowered again from a mapper of its own with
+        `scope` a null context: the same StableHLO."""
+        from tests.test_scopes import assert_same_without_scopes
+        (dm, m), made = self._mapper(), {}
+        fn, shapes = self._tail_program(dm)
+
+        def build():
+            made["dm"] = DeviceMapper(m)
+            return self._tail_program(made["dm"])[0]
+
+        assert_same_without_scopes(monkeypatch, fn.lower(*shapes), shapes,
+                                   build)
+        assert made["dm"] is not dm
+
     @pytest.mark.parametrize("share", [0.0, 0.10, 0.13, 0.40])
     def test_indep_tail_parity_and_counters(self, share):
         """The indep twin: the step's first round over all lanes, its

@@ -202,6 +202,57 @@ def test_whole_pool_pass_equals_host(request, which, rule, size, can_shift,
     assert st.indep_tail_lanes == 0
 
 
+_TAIL_PASSES: dict = {}
+
+
+def tail_pass(fx, rule: str, size: int, pg_num: int = 16384) -> dict:
+    """One whole-pool pass of `rule` at 16,384 lanes with the Pallas
+    lane kernels (interpret mode: a minute of compile a program), once
+    for the module: its MapState, and the jitted pool and resolve
+    programs it ran last, each with the shapes it was given and the
+    arguments that built it, for the tests that read their text."""
+    if rule in _TAIL_PASSES:
+        return _TAIL_PASSES[rule]
+    import jax
+    n, ran = fx.n, {}
+    exists, isup = np.ones(n, bool), np.ones(n, bool)
+    isup[11] = False
+
+    def spied(name):
+        real = getattr(DeviceMapper, name)
+
+        def fetch(self, *key, **kw):
+            fn = real(self, *key, **kw)
+
+            def call(*args):
+                ran[name] = (fn, [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                                  for a in args], key)
+                return fn(*args)
+            return call
+        return fetch
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("CEPH_TPU_PALLAS_INTERPRET", "1")
+    for name in ("_compiled_pool", "_compiled_device_resolve"):
+        mp.setattr(DeviceMapper, name, spied(name))
+    try:
+        st = fx.dm.map_pool_state(
+            RULE_IDS[rule], size, pg_num, pg_num, pg_num - 1, 1, True,
+            np.asarray(fx.w, np.int32), exists, isup, None, False)
+    finally:
+        mp.undo()
+    _TAIL_PASSES[rule] = {"state": st, "pool": ran["_compiled_pool"],
+                          "resolve": ran["_compiled_device_resolve"]}
+    return _TAIL_PASSES[rule]
+
+
+def compiled_text(program) -> str:
+    """The compiled module of a jitted program that has run with these
+    shapes: the executable the call made, no second compile."""
+    fn, shapes, _key = program
+    return fn.lower(*shapes).compile().as_text()
+
+
 @pytest.mark.parametrize("rule,size", [("lrc", 8), ("ec1", 6)])
 def test_whole_pool_pass_with_the_indep_tail_equals_host(
         monkeypatch, wide, rule, size):
@@ -222,12 +273,7 @@ def test_whole_pool_pass_with_the_indep_tail_equals_host(
     widths = [pg_num * f // fx.dm.RC_ROW * kt
               for f, kt in zip(plan.lane_factors, tails)]
     assert all(n % pallas_draw.TL == 0 for n in widths), widths
-    exists = np.ones(fx.n, bool)
-    isup = np.ones(fx.n, bool)
-    isup[11] = False
-    st = fx.dm.map_pool_state(ruleno, size, pg_num, pg_num, pg_num - 1, 1,
-                              True, np.asarray(fx.w, np.int32), exists,
-                              isup, None, False)
+    st = tail_pass(fx, rule, size)["state"]
     assert all(fx.dm.fm.descent_in_pallas[n] for n in widths)
     raw, up = np.array(st.raw), np.array(st.up)
     pps = pps_seed_v(np.arange(pg_num), pg_num, pg_num - 1, 1, True)
@@ -249,6 +295,74 @@ def test_whole_pool_pass_with_the_indep_tail_equals_host(
     else:
         assert 0 < st.indep_tail_lanes <= st.retry_lanes
     assert 0 < st.resolve_lanes < st.retry_lanes
+
+
+# the scopes of ceph_tpu/trace/span.py's table the resolve program of a
+# two-step indep pool reaches
+RESOLVE_SCOPES = ("crush.resolve.compact", "crush.resolve.a",
+                  "crush.resolve.b", "crush.resolve.c",
+                  "crush.settle.draw", "crush.settle.post",
+                  "crush.settle.scatter", "crush.resolve.counts",
+                  "crush.seeds", "crush.step", "crush.descend",
+                  "crush.is_out")
+
+
+def test_the_two_step_pool_program_carries_its_scopes(wide):
+    """Every instruction the pool program traced stands under a
+    registered scope but the chunk loop's own, each stage of the table
+    is there, and a descent is found under a first round and under a
+    tail's rounds: the paths the device trace is split by."""
+    from tests.test_scopes import POOL_SCOPES, scope_paths, scoped_share
+    paths = scope_paths(compiled_text(tail_pass(wide, "lrc", 8)["pool"]))
+    seen = {name for p in paths for name in p}
+    assert seen == POOL_SCOPES, seen ^ POOL_SCOPES
+    assert scoped_share(paths) >= 95.0, scoped_share(paths)
+    assert ("crush.first", "crush.descend") in paths
+    assert ("crush.tail.rounds", "crush.descend") in paths
+    assert ("crush.tail.rounds", "crush.is_out") in paths
+    assert ("crush.tail.move", "crush.seeds") in paths
+    # no tail inside a tail, no round outside first or a tail
+    assert not any("crush.tail.move" in p and "crush.tail.rounds" in p
+                   for p in paths)
+    assert all(p[0] in ("crush.first", "crush.tail.rounds")
+               for p in paths if "crush.descend" in p), paths
+
+
+def test_the_resolve_program_carries_its_stages(wide):
+    """Each of the three stages holds a draw, a post-process and a
+    scatter; compaction and the counts stand beside them."""
+    from tests.test_scopes import scope_paths, scoped_share
+    paths = scope_paths(compiled_text(tail_pass(wide, "lrc", 8)["resolve"]))
+    seen = {name for p in paths for name in p}
+    # stage A's lanes (16,384 slots) run the attempt structure: its
+    # rounds are "first" rounds of that stage
+    assert seen == set(RESOLVE_SCOPES) | {"crush.first"}, seen
+    assert scoped_share(paths) >= 95.0, scoped_share(paths)
+    for stage in ("crush.resolve.a", "crush.resolve.b", "crush.resolve.c"):
+        for part in ("crush.settle.draw", "crush.settle.post",
+                     "crush.settle.scatter"):
+            assert any(p[:2] == (stage, part) for p in paths), (stage, part)
+        assert any(p[:2] == (stage, "crush.settle.draw")
+                   and p[-1] == "crush.descend" for p in paths), stage
+    assert ("crush.resolve.compact",) in paths
+    assert ("crush.resolve.counts",) in paths
+    # a stage never nests in another
+    assert not any(sum(n.startswith("crush.resolve.") for n in p) > 1
+                   for p in paths)
+
+
+def test_a_scope_adds_no_operation_to_the_resolve_program(
+        monkeypatch, wide):
+    """The resolve program lowered again with `scope` a null context:
+    the same StableHLO, so a scope is metadata of the instructions and
+    nothing else (tests/test_crush_device.py::TestDenseTail holds the
+    pool program with a tail to the same)."""
+    from tests.test_scopes import assert_same_without_scopes
+    fn, shapes, key = tail_pass(wide, "lrc", 8)["resolve"]
+    monkeypatch.setenv("CEPH_TPU_PALLAS_INTERPRET", "1")
+    assert_same_without_scopes(
+        monkeypatch, fn.lower(*shapes), shapes,
+        lambda: DeviceMapper(wide.map)._compiled_device_resolve(*key))
 
 
 def test_one_step_pass_counts_one_step(even):
