@@ -46,14 +46,20 @@ loops.
 
 Device scope (the modern "optimal" tunables profile): straw2 buckets at
 every level, choose_local_tries == choose_local_fallback_tries == 0,
-rules of shape TAKE -> one or more CHOOSE/CHOOSELEAF steps -> EMIT, the
-steps all firstn or all indep (root -> rack -> host: `choose indep 2
-type rack; chooseleaf indep 4 type host`).  A later step takes per lane
-from the step before it, as crush_do_rule chains its working vector
-(_chain_step).  Anything else -- a rule that mixes firstn and indep
-steps, a second TAKE/EMIT pair, a step below a chooseleaf, a non-straw2
-bucket, local retries -- raises ValueError and falls back to the host
-interpreter, which remains the general spec.
+rules of one or more blocks TAKE -> one or more CHOOSE/CHOOSELEAF steps
+-> EMIT, a block's steps all firstn or all indep (root -> rack -> host:
+`choose indep 2 type rack; chooseleaf indep 4 type host`; a stretch
+cluster's `take site1; chooseleaf firstn 2 type host; emit; take site2;
+chooseleaf firstn 2 type host; emit`).  A later step takes per lane from
+the step before it, as crush_do_rule chains its working vector
+(_chain_step); each block starts from its own TAKE, its draws' r from 0,
+and EMIT appends its working vector to the lane's result, at most
+result_max entries in all (_emit; blocks of one shape run as one choose
+over their lanes stacked).  Anything else -- a block that mixes firstn
+and indep steps, a TAKE after a choose step with no EMIT between, a step
+below a chooseleaf, a non-straw2 bucket, local retries -- raises
+ValueError and falls back to the host interpreter, which remains the
+general spec.
 """
 
 from __future__ import annotations
@@ -1340,8 +1346,11 @@ def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
     compacted tail, its seeds by PG lane; take-lane j is PG lane j mod L.
 
     Returns (rows [L, st.width] with NONE past a lane's osize, flag,
-    short, retry, seats): flag, short and seats as _choose_indep_vec's
-    (firstn: short is the lanes with a replica still unplaced)."""
+    short, retry, seats, osize): flag, short and seats as
+    _choose_indep_vec's (firstn: short is the lanes with a replica
+    still unplaced); osize [L] the entries of the working vector after
+    the step (an indep window's holes count, what was skipped does
+    not)."""
     L, n_in = w.shape
     slots = min(st.numrep, result_max)
     with scope("crush.step"):
@@ -1389,7 +1398,33 @@ def _chain_step(fm: FlatMap, st, w, xs, result_max: int, dev_weights,
                 put = (cols == (osize + k)[:, None]) & (k < n_i)[:, None]
                 out = jnp.where(put, rows[i, :, k:k + 1], out)
             osize = osize + n_i
-    return out, flag, short, retry, seats
+    return out, flag, short, retry, seats, osize
+
+
+def _emit(blocks, width: int):
+    """EMIT after EMIT, as crush_do_rule appends each block's working
+    vector to the result (mapper.c:1067-1077): blocks are (rows [L, w],
+    n [L] the entries of the lane's working vector, flags) in rule order;
+    a block's entries follow the entries the blocks before it emitted
+    (so a firstn block's rows follow however many the block before it
+    placed, an indep block's holes take their places), and a lane takes
+    at most width entries in all (width = min(result_max, the blocks'
+    w summed): EMIT's cap).  Returns (rows [L, width], the OR of each
+    flag over the blocks)."""
+    rows0 = blocks[0][0]
+    L = rows0.shape[0]
+    out = jnp.full((L, width), ITEM_NONE, jnp.int32)
+    cols = jnp.arange(width)[None, :]
+    osize = jnp.zeros((L,), jnp.int32)
+    for rows, n, _flags in blocks:
+        n = jnp.minimum(n, width - osize)
+        for k in range(rows.shape[1]):
+            put = (cols == (osize + k)[:, None]) & (k < n)[:, None]
+            out = jnp.where(put, rows[:, k:k + 1], out)
+        osize = osize + n
+    flags = [functools.reduce(jnp.logical_or, f)
+             for f in zip(*(b[2] for b in blocks))]
+    return out, flags
 
 
 # ---------------------------------------------------------------------------
@@ -1494,7 +1529,10 @@ class MapState:
     identical sequence.  Up/down/affinity changes only affect the
     post-CRUSH filter, which also reads the raw row.  Reweight
     INCREASES flip previously-hash-rejected lanes that are not
-    identifiable from the rows — those fall back to a full pass."""
+    identifiable from the rows — those fall back to a full pass, and so
+    does every change to a pool whose rule's blocks can place more than
+    result_max entries between them: an entry that EMIT cuts off is in
+    no row, and an indep block's other slots collide with it."""
 
     __slots__ = ("dm", "ruleno", "result_max", "pg_num", "pgp_num",
                  "pgp_mask", "pool_id", "hashps", "can_shift",
@@ -1502,14 +1540,14 @@ class MapState:
                  "ex_np", "iu_np", "af_np", "npg", "lanes",
                  "tail_lanes", "resolve_lanes", "steps", "retry_lanes",
                  "none_slots", "indep_tail_lanes", "fast_lanes",
-                 "fast_settled", "b_lanes")
+                 "fast_settled", "b_lanes", "blocks")
 
     def __init__(self, dm, ruleno, result_max, pg_num, pgp_num,
                  pgp_mask, pool_id, hashps, can_shift, use_aff, raw,
                  up_full, prim_full, w_np, ex_np, iu_np, af_np, npg,
                  lanes=0, tail_lanes=0, resolve_lanes=0, steps=0,
                  retry_lanes=0, none_slots=0, indep_tail_lanes=0,
-                 fast_lanes=0, fast_settled=0, b_lanes=0):
+                 fast_lanes=0, fast_settled=0, b_lanes=0, blocks=0):
         self.dm = dm
         self.ruleno = ruleno
         self.result_max = result_max
@@ -1534,11 +1572,12 @@ class MapState:
         self.lanes = lanes
         self.tail_lanes = tail_lanes
         self.resolve_lanes = resolve_lanes
-        # a whole-pool pass only: the rule's choose steps it ran on the
-        # device, the lanes an indep rule's first full-width round left
-        # with an undefined slot in some step, the take-lanes of its
-        # steps that sat in a tail for their later rounds, the up slots
-        # that ended ITEM_NONE
+        # a whole-pool pass only: the rule's TAKE/EMIT blocks and choose
+        # steps (over its blocks) it ran on the device, the lanes an
+        # indep rule's first full-width round left with an undefined
+        # slot in some step, the take-lanes of its steps that sat in a
+        # tail for their later rounds, the up slots that ended ITEM_NONE
+        self.blocks = blocks
         self.steps = steps
         self.retry_lanes = retry_lanes
         self.indep_tail_lanes = indep_tail_lanes
@@ -1576,7 +1615,9 @@ class MapState:
                 w_np, ex_np, iu_np, aff, self.can_shift)
 
         if (use_aff != self.use_aff
-                or w_np.shape != self.w_np.shape):
+                or w_np.shape != self.w_np.shape
+                or not self.dm._plan(self.ruleno, self.result_max
+                                     ).emits_all(self.result_max)):
             return full()
         changed = ((w_np != self.w_np) | (ex_np != self.ex_np)
                    | (iu_np != self.iu_np) | (af_np != self.af_np))
@@ -1656,10 +1697,28 @@ _Step = collections.namedtuple(
              "outer_ds inner_ds width collide")
 
 
-class _Plan(collections.namedtuple("_Plan", "take_id steps")):
-    """A rule in device scope: TAKE, its choose steps in order, EMIT."""
+class _Plan(collections.namedtuple("_Plan", "take_id steps more",
+                                    defaults=((),))):
+    """A rule in device scope: blocks of TAKE, choose steps in order,
+    EMIT.  take_id and steps are the first block's, more the blocks
+    after it, each a _Plan of one block."""
 
     __slots__ = ()
+
+    @property
+    def blocks(self) -> tuple:
+        return (self._replace(more=()),) + self.more
+
+    @property
+    def groups(self) -> tuple:
+        """The numbers of the blocks, by shape, in the order of each
+        shape's first block: blocks of the same steps (their takes
+        apart) run as one choose over their lanes stacked, a take per
+        lane, so that a program holds their descents once."""
+        shapes: dict = {}
+        for i, b in enumerate(self.blocks):
+            shapes.setdefault(b.steps, []).append(i)
+        return tuple(tuple(g) for g in shapes.values())
 
     @property
     def firstn(self) -> bool:
@@ -1671,10 +1730,30 @@ class _Plan(collections.namedtuple("_Plan", "take_id steps")):
         pass: the entries of the working vector it takes from."""
         return (1,) + tuple(st.width for st in self.steps[:-1])
 
+    @property
+    def n_steps(self) -> int:
+        return sum(len(b.steps) for b in self.blocks)
+
+    def width(self, result_max: int) -> int:
+        """Entries of a lane's result: what EMIT leaves of the blocks'
+        working vectors."""
+        return min(result_max, self.placed)
+
+    @property
+    def placed(self) -> int:
+        """The most entries the blocks' working vectors hold together."""
+        return sum(b.steps[-1].width for b in self.blocks)
+
+    def emits_all(self, result_max: int) -> bool:
+        """No entry a block places is ever cut off by EMIT's cap (each
+        step's width is cut to result_max already)."""
+        return self.placed <= result_max
+
 
 class DeviceMapper:
-    """Bulk do_rule on device for straw2 maps and rules of one TAKE, one
-    or more choose steps of one kind (firstn or indep) and one EMIT.
+    """Bulk do_rule on device for straw2 maps and rules of one or more
+    TAKE/EMIT blocks, each one TAKE, one or more choose steps of one
+    kind (firstn or indep) and one EMIT.
 
     do_rule_batch(ruleno, xs, result_max, dev_weights) mirrors
     CrushWrapper::do_rule over a whole batch of inputs; results carry
@@ -1690,7 +1769,7 @@ class DeviceMapper:
         self.map = crushmap
         self._cargs = (crushmap.choose_args.get(choose_args_name)
                        if choose_args_name else None)
-        # (ruleno, result_max, chunk lanes, choose step) -> slots per
+        # (ruleno, result_max, chunk lanes, block, step) -> slots per
         # row group a dense pass's tail was found to need; the passes
         # thrown away to find it
         self._tail_want: dict[tuple, int] = {}
@@ -1704,8 +1783,10 @@ class DeviceMapper:
 
     @functools.lru_cache(maxsize=None)
     def _plan(self, ruleno: int, result_max: int) -> "_Plan":
-        """The rule's choose steps, each with the tunables that govern
-        it; ValueError for a rule outside device scope."""
+        """The rule's blocks (TAKE, choose steps, EMIT), each step with
+        the tunables that govern it: a set_* step holds from where it
+        stands to the rule's end, across blocks, as in crush_do_rule.
+        ValueError for a rule outside device scope."""
         rule = self.fm.rules[ruleno]
         t = self.fm.tunables
         tries = t.choose_total_tries + 1     # historical off-by-one
@@ -1714,12 +1795,13 @@ class DeviceMapper:
         stable = t.chooseleaf_stable
         take_id = None
         steps: list[_Step] = []
-        emitted = False
+        blocks: list[_Plan] = []
         for op, arg1, arg2 in rule.steps:
             if op == TAKE:
                 if steps:
                     raise ValueError(
-                        "device mapper supports one TAKE/EMIT pair")
+                        "device mapper needs an EMIT between a choose "
+                        "step and the next TAKE")
                 take_id = arg1
             elif op == SET_CHOOSE_TRIES:
                 if arg1 > 0:
@@ -1735,10 +1817,7 @@ class DeviceMapper:
                     stable = arg1
             elif op in (CHOOSE_FIRSTN, CHOOSELEAF_FIRSTN,
                         CHOOSE_INDEP, CHOOSELEAF_INDEP):
-                if emitted:
-                    raise ValueError(
-                        "device mapper supports one TAKE/EMIT pair")
-                if take_id is None or take_id >= 0:
+                if take_id is None or take_id not in self.map.buckets:
                     raise ValueError("choose without a bucket take")
                 numrep = arg1
                 if numrep <= 0:
@@ -1758,8 +1837,8 @@ class DeviceMapper:
                     prev = steps[-1]
                     if prev.firstn != firstn:
                         raise ValueError(
-                            "device mapper supports steps of one kind, "
-                            "firstn or indep")
+                            "device mapper supports blocks of steps of "
+                            "one kind, firstn or indep")
                     if prev.leaf or prev.want_type == 0:
                         raise ValueError("choose step below devices")
                     starts = [b.id for b in self.map.buckets.values()
@@ -1779,20 +1858,28 @@ class DeviceMapper:
                     0.0 if firstn else self._collide_share(
                         starts, arg2, min(numrep, result_max))))
             elif op == EMIT:
-                emitted = bool(steps)
-        if not steps:
+                if steps:
+                    blocks.append(_Plan(take_id, tuple(steps)))
+                elif take_id is not None:
+                    # the host emits the bucket itself
+                    raise ValueError("EMIT of a TAKE with no choose step")
+                take_id, steps = None, []
+        if steps:
+            # the host emits nothing of a block without its EMIT
+            raise ValueError("choose steps with no EMIT after them")
+        if not blocks:
             raise ValueError("rule has no choose step")
-        if len(steps) > 1 and any(
+        if any(len(b.steps) > 1 for b in blocks) and any(
                 c < 0 and c not in self.map.buckets
                 for b in self.map.buckets.values() for c in b.items):
             # the host skips a working-vector entry that names no
             # bucket; the flat map cannot tell it from an empty one
             raise ValueError("bucket item names no bucket")
-        return _Plan(take_id, tuple(steps))
+        return blocks[0]._replace(more=tuple(blocks[1:]))
 
     def _compile(self, ruleno: int, result_max: int, resolve: bool,
                  full: bool = True, first_only: bool = False,
-                 tails: tuple | None = None):
+                 tails: tuple | None = None, group: int | None = None):
         """core(xs, dev_weights) -> (rows, flag): flag marks the lanes
         that a more exact pass has to recompute.  first_only (one firstn
         step, full=False): the first optimistic round of each replica
@@ -1803,23 +1890,65 @@ class DeviceMapper:
         the resolve chain), seeds as _Tail's by PG lane and counts
         int32: the lanes an indep step's first full-width round left
         with an undefined slot, then _choose_indep_vec's three seats of
-        each step that has a tail."""
-        plan = self._plan(ruleno, result_max)
-        fm = self.fm
-        head = plan.steps[0]
-        rootc = fm.const_row(plan.take_id, head.outer_ds[0])
-        assert not first_only or len(plan.steps) == 1
+        each step that has a tail.
 
-        def core(xs, dev_weights, seeds=None):
+        A rule of several TAKE/EMIT blocks runs each group of blocks of
+        one shape (_Plan.groups) as one choose over the group's blocks'
+        lanes stacked, a take per lane, and EMIT puts the blocks' rows
+        together (_emit); a flag is the OR over the blocks.  group (a
+        rule of several blocks, which a whole pool's dense pass runs
+        group by group): core(xs, dev_weights, seeds=None, take=None)
+        of that group's blocks alone, over their stacked lanes (block
+        after block, L lanes each; take [lanes], where given, is the
+        lanes' take: a tail's, stacked already), with the entries of
+        each lane's working vector last (None: its rows say it, see
+        _emit's caller)."""
+        plan = self._plan(ruleno, result_max)
+        if group is None and plan.more:
+            assert tails is None and not first_only
+            cores = [self._compile(ruleno, result_max, resolve, full,
+                                   group=g)
+                     for g in range(len(plan.groups))]
+
+            def rule(xs, dev_weights):
+                parts = [c(xs, dev_weights) for c in cores]
+                res, (flag,) = self._emit_groups(
+                    plan, result_max, xs.shape[0],
+                    [(rows, (flag,), n) for rows, flag, n in parts])
+                return res, flag
+
+            return rule
+        fm = self.fm
+        blocks = plan.groups[group or 0]
+        block = plan.blocks[blocks[0]]
+        head = block.steps[0]
+        takes = [-1 - plan.blocks[b].take_id for b in blocks]
+        rootc = (fm.const_row(block.take_id, head.outer_ds[0])
+                 if len(blocks) == 1 else None)
+        assert not first_only or len(block.steps) == 1
+
+        def core(xs, dev_weights, seeds=None, take=None):
+            crow = None
+            if take is None and len(blocks) == 1:
+                take, crow = block.take_id, rootc
+            elif take is None:
+                L = xs.shape[0]
+                with scope("crush.step"):
+                    take = jnp.concatenate(
+                        [jnp.full((L,), t, jnp.int32) for t in takes])
+                    xs = jnp.tile(xs, len(blocks))
+                if seeds is not None:
+                    pg_seeds = seeds
+                    seeds = lambda lane: pg_seeds(lane % L)   # noqa: E731
             tail = [_Tail(kt, seeds) if kt else None
-                    for kt in tails or (0,) * len(plan.steps)]
+                    for kt in tails or (0,) * len(block.steps)]
             if head.firstn:
                 res, _, flag, unfinished = _choose_firstn_vec(
-                    fm, plan.take_id, xs, head.numrep, result_max,
+                    fm, take, xs, head.numrep, result_max,
                     head.want_type, head.leaf, dev_weights,
                     head.tries, head.recurse, head.vary_r,
                     head.stable, head.outer_ds, head.inner_ds,
-                    resolve, full, rootc, first_only)
+                    resolve, full, crow, first_only)
                 if first_only:
                     return res, flag, unfinished
                 with scope("crush.step"):
@@ -1827,27 +1956,57 @@ class DeviceMapper:
                     retry, seats = jnp.zeros_like(flag), [None]
             else:
                 res, flag, short, retry, seat = _choose_indep_vec(
-                    fm, plan.take_id, xs, head.numrep, result_max,
+                    fm, take, xs, head.numrep, result_max,
                     head.want_type, head.leaf, dev_weights,
                     head.tries, head.recurse, head.outer_ds,
-                    head.inner_ds, resolve, full, rootc, tail=tail[0])
+                    head.inner_ds, resolve, full, crow, tail=tail[0])
                 seats = [seat]
-            for st, tl in zip(plan.steps[1:], tail[1:]):
-                res, f, sh, rt, seat = _chain_step(
+            count = None
+            for st, tl in zip(block.steps[1:], tail[1:]):
+                res, f, sh, rt, seat, count = _chain_step(
                     fm, st, res, xs, result_max, dev_weights, resolve,
                     full, tl)
                 with scope("crush.step"):
                     flag, short, retry = flag | f, short | sh, retry | rt
                 seats.append(seat)
+            counted = (count,) if group is not None else ()
             if tails is None:
                 with scope("crush.step"):
-                    return res, flag | short
+                    return (res, flag | short) + counted
             with scope("crush.step"):
-                return res, _kind(flag, short), jnp.concatenate(
+                return (res, _kind(flag, short), jnp.concatenate(
                     [jnp.sum(retry, dtype=jnp.int32)[None]]
                     + [seat for seat in seats if seat is not None])
+                    ) + counted
 
         return core
+
+    @staticmethod
+    def _emit_groups(plan: "_Plan", result_max: int, L: int, parts):
+        """The rows of a rule of several blocks from its groups' (parts,
+        in plan.groups' order: rows [B * L, w] of the group's B blocks
+        stacked, flags: a tuple of [B * L], n: [B * L] entries of each
+        lane's working vector, or None), un-stacked into blocks and
+        put together by EMIT in rule order (_emit): rows [L, width] and
+        each flag ORed over the blocks.  A block's entries are its n, or
+        where n is None its rows' (firstn: compact, every entry placed)
+        or its width (indep of one step: holes are entries)."""
+        with scope("crush.emit"):
+            per = {}
+            for g, (rows, flags, n) in zip(plan.groups, parts):
+                for j, b in enumerate(g):
+                    lanes = slice(j * L, (j + 1) * L)
+                    r = rows[lanes]
+                    if n is not None:
+                        nb = n[lanes]
+                    elif plan.blocks[b].firstn:
+                        nb = jnp.sum(r != ITEM_NONE, axis=1,
+                                     dtype=jnp.int32)
+                    else:
+                        nb = jnp.full((L,), r.shape[1], jnp.int32)
+                    per[b] = (r, nb, tuple(f[lanes] for f in flags))
+            return _emit([per[b] for b in range(len(plan.blocks))],
+                         plan.width(result_max))
 
     def _depth_sizes(self, start_bucket_ids: list[int],
                      want_type: int) -> tuple:
@@ -1946,13 +2105,14 @@ class DeviceMapper:
     TAIL_KT_MAX = 512
     INDEP_TAIL_KT_MAX = 1024
 
-    def _tail_start(self, ruleno: int, result_max: int, step: int) -> int:
-        """Slots per row group a step's tail starts with, before any
-        pass has been counted: firstn TAIL_KT; an indep step the hits
-        its geometry lets expect in a row group (_collide_share) and two
-        standard deviations (a group that runs over leaves a few lanes
-        to the resolve chain, flagged)."""
-        plan = self._plan(ruleno, result_max)
+    def _tail_start(self, ruleno: int, result_max: int, step: int,
+                    block: int = 0) -> int:
+        """Slots per row group the tail of a block's step starts with,
+        before any pass has been counted: firstn TAIL_KT; an indep step
+        the hits its geometry lets expect in a row group
+        (_collide_share) and two standard deviations (a group that runs
+        over leaves a few lanes to the resolve chain, flagged)."""
+        plan = self._plan(ruleno, result_max).blocks[block]
         if plan.firstn:
             return self.TAIL_KT
         p = plan.steps[step].collide
@@ -1960,20 +2120,23 @@ class DeviceMapper:
         return math.ceil(mu + 2.0 * math.sqrt(mu * (1.0 - p))) or 1
 
     def _tail_slots(self, ruleno: int, result_max: int, C: int,
-                    want: int, step: int = 0) -> int:
+                    want: int, step: int = 0, block: int = 0) -> int:
         """Slots per row group (>= want) for the tail of choose step
-        `step` in a dense pass whose chunks are C PG lanes wide, or 0:
-        no tail, every optimistic round of the step dense.  A firstn
-        tail is the whole rule's, and needs a rule of one step; an
-        indep step has its own, on the step's takes (C lanes times the
-        entries of the working vector before it).  Either needs rounds
-        to save, the attempt structure (lanes >= _ATTEMPT_MIN_L),
-        rowcompact's alignment, and a width that keeps its descents in
-        Pallas."""
+        `step` of a block (the first of its group) in a dense pass
+        whose chunks are C PG lanes wide, or 0: no tail, every
+        optimistic round of the step dense.  A firstn tail is the whole
+        block's, and needs a block of one step; an indep step has its
+        own, on the step's takes (C lanes times the group's blocks
+        times the entries of the working vector before it).  Either
+        needs rounds to save, the attempt structure (lanes >=
+        _ATTEMPT_MIN_L), rowcompact's alignment, and a width that keeps
+        its descents in Pallas."""
         from . import pallas_draw
-        plan = self._plan(ruleno, result_max)
+        rule = self._plan(ruleno, result_max)
+        plan = rule.blocks[block]
+        stacked = next(len(g) for g in rule.groups if block in g)
         st = plan.steps[step]
-        lanes = C * plan.lane_factors[step]
+        lanes = C * stacked * plan.lane_factors[step]
         if plan.firstn:
             rounds = (_firstn_attempts(st.tries, st.leaf, st.recurse)
                       if len(plan.steps) == 1 else 1)
@@ -1988,6 +2151,31 @@ class DeviceMapper:
         unit = max(128, pallas_draw.TL // math.gcd(nr, pallas_draw.TL))
         kt = unit * -(-want // unit)
         return kt if kt <= most else 0
+
+    def _tail_keys(self, ruleno: int, result_max: int) -> list:
+        """(block, step) of every tail a dense pass may have, in the
+        order of its `tails`: a group's first block's steps, group by
+        group (the one block's steps, for a rule of one)."""
+        plan = self._plan(ruleno, result_max)
+        return [(g[0], i) for g in plan.groups
+                for i in range(len(plan.blocks[g[0]].steps))]
+
+    def _tail_seats(self, ruleno: int, result_max: int, tails: tuple):
+        """Where a dense pass counts its tails: (the key of the tail
+        whose seats come first, the keys of those after the retry count
+        in order), keys as _tail_keys'.  The first is a firstn block's
+        (the first group's that has one; None if none has), the rest
+        the other firstn blocks' and the indep steps', each that has a
+        tail."""
+        plan, lead, rest = self._plan(ruleno, result_max), None, []
+        for key, kt in zip(self._tail_keys(ruleno, result_max), tails):
+            if not kt:
+                continue
+            if plan.blocks[key[0]].firstn and lead is None:
+                lead = key
+            else:
+                rest.append(key)
+        return lead, rest
 
     # -- whole-pool mapping with device-side pps -------------------------
 
@@ -2005,85 +2193,153 @@ class DeviceMapper:
         why a lane goes to the chain (an uncertain draw, or short of
         rounds), which decides the chain's stage for it.
 
-        tails: per choose step the slots of its tail (_tail_slots), 0
-        or nothing for every round over all n lanes.  A firstn rule's
-        (one step, kt_tail slots): only the first optimistic round of
-        each replica runs over all n lanes of a chunk (numrep descents,
-        twice that for chooseleaf).  The lanes it leaves unplaced are
-        compacted into kt_tail slots per row group (rowcompact),
-        replayed from scratch through the whole attempt structure at
-        that width (n / RC_ROW * kt_tail lanes) and their rows and
-        kinds put back (rowexpand).  An indep rule's are inside its
-        steps (_choose_indep_vec).  Either way a row group with more
+        tails: per choose step of _tail_keys (the rule's steps, block
+        by block of a group's first) the slots of its tail
+        (_tail_slots), 0 or nothing for every round over all n lanes.
+        A firstn block's (one step, kt_tail slots): only the first
+        optimistic round of each replica runs over all n lanes of a
+        chunk (numrep descents, twice that for chooseleaf).  The lanes
+        it leaves unplaced are compacted into kt_tail slots per row
+        group (rowcompact), replayed from scratch through the whole
+        attempt structure at that width (n / RC_ROW * kt_tail lanes)
+        and their rows and kinds put back (rowexpand).  An indep
+        block's are inside its steps (_choose_indep_vec).  A rule of
+        several blocks runs group after group (_Plan.groups), each over
+        its blocks' n lanes stacked, and EMIT puts the rows together
+        (_emit_groups); a lane's kind is uncertain if a block's is,
+        else short if a block's is.  Either way a row group with more
         unplaced lanes than slots leaves the rest short, for the
         resolve chain; the pass's counts (lanes seated, lanes left
         unseated, largest group) tell the host when that is worth a
         wider tail.
 
         The pass's counts: a firstn tail's three, the lanes an indep
-        rule's first full-width round left with an undefined slot (0
-        for firstn), three for each indep step that has a tail, then
-        the lanes it hands the chain uncertain and short (pads past
-        pg_num included)."""
+        block's first full-width round left with an undefined slot (0
+        for firstn; a lane once per block), three for each other tail
+        (_tail_seats), then the lanes it hands the chain uncertain and
+        short (pads past pg_num included)."""
         self._note_compile("pool", (ruleno, result_max, can_shift,
                                     use_aff, pgp_num, pgp_mask,
                                     pool_id, hashps, n, n_chunks,
                                     tails))
         from . import pallas_draw
-        firstn = self._plan(ruleno, result_max).firstn
-        kt_tail = tails[0] if firstn and tails else 0
-        core = self._compile(ruleno, result_max, False, full=False,
-                             tails=(0,) if kt_tail else tuple(tails))
-        if kt_tail:
-            first = self._compile(ruleno, result_max, False, full=False,
-                                  first_only=True)
-            rc = pallas_draw.make_rowcompact_kernel(n, self.RC_ROW,
-                                                    kt_tail, n)
+        plan = self._plan(ruleno, result_max)
+        keys = self._tail_keys(ruleno, result_max)
+        kts = dict(zip(keys, tails or (0,) * len(keys)))
 
         @scope("crush.seeds")
         def pps(ps):
             return _pps(ps, pgp_num, pgp_mask, pool_id, hashps)
 
+        def group_pass(g: int):
+            """descend_g(xs, start, dev_weights) -> (rows, kind, counts,
+            n) of a group's blocks, over their lanes stacked (every lane
+            of a rule of one block); counts in the pass's order (a
+            firstn tail's three, the retry count, an indep step's three
+            per tail), n as _compile's group core's."""
+            blocks = plan.groups[g]
+            block = plan.blocks[blocks[0]]
+            firstn, B = block.firstn, len(blocks)
+            group = g if plan.more else None
+            steps = tuple(kts[(blocks[0], i)]
+                          for i in range(len(block.steps)))
+            kt_tail = steps[0] if firstn else 0
+            core = self._compile(ruleno, result_max, False, full=False,
+                                 tails=(0,) if kt_tail else steps,
+                                 group=group)
+            if not kt_tail:
+                def dense(xs, start, dev_weights):
+                    # a firstn block's rounds all run here at full
+                    # width; an indep block's steps scope their own
+                    # rounds and tails
+                    with scope("crush.first") if firstn else nullcontext():
+                        raw, kind, counts, *n = core(
+                            xs, dev_weights,
+                            lambda lane: pps(lane.astype(jnp.uint32)
+                                             + start))
+                    with scope("crush.step"):
+                        return (raw, kind, jnp.concatenate(
+                            [jnp.zeros((3,), jnp.int32), counts]),
+                            n[0] if n else None)
+                return dense
+            first = self._compile(ruleno, result_max, False, full=False,
+                                  first_only=True, group=group)
+            rc = pallas_draw.make_rowcompact_kernel(B * n, self.RC_ROW,
+                                                    kt_tail, B * n)
+            takes = [-1 - plan.blocks[b].take_id for b in blocks]
+
+            def tailed(xs, start, dev_weights):
+                with scope("crush.first"):
+                    raw, flag, unfinished = first(xs, dev_weights)
+                with scope("crush.tail.move"):
+                    idx, _valid, cnt = rc(unfinished)
+                    if B == 1:
+                        xs_t, take_t = pps(idx.astype(jnp.uint32)
+                                           + start), None
+                    else:
+                        # lane j of the stack is PG j mod n of block
+                        # j // n, whose take it starts from
+                        xs_t = pps((idx % n).astype(jnp.uint32) + start)
+                        take_t = jnp.full(idx.shape, takes[0], jnp.int32)
+                        for j in range(1, B):
+                            take_t = jnp.where(idx >= j * n, takes[j],
+                                               take_t)
+                with scope("crush.tail.rounds"):
+                    raw_t, kind_t, *_ = core(xs_t, dev_weights,
+                                             take=take_t)
+                # a seated lane takes its replayed row and kind; one
+                # that got no slot keeps its row, short, for the
+                # resolve chain
+                expand = pallas_draw.make_rowexpand_kernel(
+                    B * n, self.RC_ROW, kt_tail, raw.shape[1] + 1)
+                with scope("crush.tail.move"):
+                    rows = expand(
+                        unfinished,
+                        jnp.concatenate(
+                            [raw, _kind(flag, unfinished)[:, None].astype(
+                                jnp.int32)], axis=1),
+                        jnp.concatenate(
+                            [raw_t, kind_t[:, None].astype(jnp.int32)],
+                            axis=1))
+                with scope("crush.step"):
+                    seated = jnp.minimum(cnt, kt_tail)
+                with scope("crush.tail.move"):
+                    rows, kind = rows[:, :-1], rows[:, -1].astype(jnp.int8)
+                with scope("crush.step"):
+                    return (rows, kind,
+                            jnp.stack([jnp.sum(seated),
+                                       jnp.sum(cnt - seated),
+                                       jnp.max(cnt), jnp.int32(0)]), None)
+            return tailed
+
+        passes = [group_pass(g) for g in range(len(plan.groups))]
+        # the groups whose firstn tail's seats the pass counts, the
+        # first of them ahead of the retry count (_tail_seats)
+        firstn_tailed = [i for i, g in enumerate(plan.groups)
+                         if plan.blocks[g[0]].firstn and kts[(g[0], 0)]]
+
         def descend(start, dev_weights):
             xs = pps(jnp.arange(n, dtype=jnp.uint32) + start)
-            if not kt_tail:
-                # a firstn rule's rounds all run here at full width; an
-                # indep rule's steps scope their own rounds and tails
-                with scope("crush.first") if firstn else nullcontext():
-                    raw, kind, counts = core(
-                        xs, dev_weights,
-                        lambda lane: pps(lane.astype(jnp.uint32) + start))
-                with scope("crush.step"):
-                    return xs, raw, kind, jnp.concatenate(
-                        [jnp.zeros((3,), jnp.int32), counts])
-            with scope("crush.first"):
-                raw, flag, unfinished = first(xs, dev_weights)
-            with scope("crush.tail.move"):
-                idx, _valid, cnt = rc(unfinished)
-                xs_t = pps(idx.astype(jnp.uint32) + start)
-            with scope("crush.tail.rounds"):
-                raw_t, kind_t, _ = core(xs_t, dev_weights)
-            # a seated lane takes its replayed row and kind; one that
-            # got no slot keeps its row, short, for the resolve chain
-            expand = pallas_draw.make_rowexpand_kernel(
-                n, self.RC_ROW, kt_tail, raw.shape[1] + 1)
-            with scope("crush.tail.move"):
-                rows = expand(
-                    unfinished,
-                    jnp.concatenate(
-                        [raw, _kind(flag, unfinished)[:, None].astype(
-                            jnp.int32)], axis=1),
-                    jnp.concatenate(
-                        [raw_t, kind_t[:, None].astype(jnp.int32)],
-                        axis=1))
+            parts = [p(xs, start, dev_weights) for p in passes]
+            if not plan.more:
+                return (xs,) + parts[0][:3]
+            raw, (unc, short) = self._emit_groups(
+                plan, result_max, n,
+                [(rows, (kind == _UNCERTAIN, kind == _SHORT), cnt)
+                 for rows, kind, _, cnt in parts])
+            with scope("crush.emit"):
+                kind = _kind(unc, short)
             with scope("crush.step"):
-                seated = jnp.minimum(cnt, kt_tail)
-            with scope("crush.tail.move"):
-                rows, kind = rows[:, :-1], rows[:, -1].astype(jnp.int8)
-            with scope("crush.step"):
-                return (xs, rows, kind,
-                        jnp.stack([jnp.sum(seated), jnp.sum(cnt - seated),
-                                   jnp.max(cnt), jnp.int32(0)]))
+                counts = [c for _, _, c, _ in parts]
+                lead = (counts[firstn_tailed[0]][:3] if firstn_tailed
+                        else jnp.zeros((3,), jnp.int32))
+                rest = []
+                for i, c in enumerate(counts):
+                    if i in firstn_tailed[1:]:
+                        rest.append(c[:3])
+                    rest.append(c[4:])
+                return xs, raw, kind, jnp.concatenate(
+                    [lead, sum(c[3] for c in counts)[None]] + rest)
 
         @scope("crush.post")
         def post(raw, xs, exists_b, isup_b, aff):
@@ -2368,26 +2624,32 @@ class DeviceMapper:
                                                   (K1, K2, K3, 0, 0))
         kt = self.RC_KT if self._rc_ok(npg) else 0
         plan = self._plan(ruleno, result_max)
+        keys = self._tail_keys(ruleno, result_max)
         dense = None
         while True:
             if dense is None:
                 tails = tuple(
                     self._tail_slots(
                         ruleno, result_max, C, self._tail_want.get(
-                            (ruleno, result_max, C, i),
-                            self._tail_start(ruleno, result_max, i)), i)
-                    for i in range(len(plan.steps)))
+                            (ruleno, result_max, C, b, i),
+                            self._tail_start(ruleno, result_max, i, b)),
+                        i, b)
+                    for b, i in keys)
                 fn = self._compiled_pool(
                     ruleno, result_max, bool(can_shift), use_aff,
                     int(pgp_num), int(pgp_num_mask), int(pool_id),
                     bool(hashpspool), C, n_chunks, tails)
                 in_pallas = self.fm.descent_in_pallas
-                widths = [C * f for f in plan.lane_factors]
+                # the lanes of every choose the pass runs: a group's
+                # blocks stacked, then its tails'
+                widths = [C * len(g) * f for g in plan.groups
+                          for f in plan.blocks[g[0]].lane_factors]
                 widths += [n // self.RC_ROW * t
                            for n, t in zip(widths, tails) if t]
                 with span("crush.launch", lanes=npg, pallas_lanes=(
                         npg if all(in_pallas.get(n) for n in widths)
-                        else 0), steps=len(plan.steps)):
+                        else 0), steps=plan.n_steps,
+                        blocks=len(plan.blocks)):
                     dense = fn(w, ex, iu, af)
                 if not known:
                     K1, KF, KA = self._chain_start(K1, dense[4],
@@ -2400,16 +2662,18 @@ class DeviceMapper:
                 raw2, up2, prim2, counts = res(*dense, w, ex, iu, af)
             with span("crush.wait"):
                 (nflag, n2, ndust, rowmax, n_fast, fast_settled, n_a,
-                 tail_lanes, unseated, tail_max, retry_lanes, *indep,
-                 _uncertain, _short, none_slots) = (
-                    int(v) for v in np.asarray(counts))
-            # per tail (step, lanes seated, left unseated, largest row
-            # group): a firstn rule's one, or an indep rule's steps'
-            tailed = [i for i, t in enumerate(tails) if t]
-            seats = ([(0, tail_lanes, unseated, tail_max)] if plan.firstn
-                     else [(i, *indep[3 * k:3 * k + 3])
-                           for k, i in enumerate(tailed)])
-            over = [(i, most) for i, seated, left, most in seats
+                 *lead_seats, retry_lanes) = (
+                    int(v) for v in np.asarray(counts)[:11])
+                rest_seats, none_slots = (
+                    [int(v) for v in np.asarray(counts)[11:-3]],
+                    int(np.asarray(counts)[-1]))
+            # per tail ((block, step), lanes seated, left unseated,
+            # largest row group): a firstn block's, an indep step's
+            lead, rest = self._tail_seats(ruleno, result_max, tails)
+            seats = ([(lead, *lead_seats)] if lead else []) + [
+                (key, *rest_seats[3 * k:3 * k + 3])
+                for k, key in enumerate(rest)]
+            over = [(key, most) for key, seated, left, most in seats
                     if left * 16 > seated]
             if over:
                 # row groups with more unplaced lanes than the tail has
@@ -2421,8 +2685,8 @@ class DeviceMapper:
                 # have (until the crush map, and with it this mapper,
                 # is replaced)
                 self.tail_overflows += 1
-                for i, most in over:
-                    self._tail_want[(ruleno, result_max, C, i)] = (
+                for (b, i), most in over:
+                    self._tail_want[(ruleno, result_max, C, b, i)] = (
                         most + most // 4)
                 dense = None
                 continue
@@ -2448,20 +2712,27 @@ class DeviceMapper:
             K3 = max(K3, min(_pow2(ndust), K1))
             known = True
         self._chain_want[chain_key] = (K1, K2, K3, KF, KA)
-        indep_tail_lanes = (0 if plan.firstn
-                            else sum(seated for _, seated, _, _ in seats))
+        # take-lanes seated in a tail, summed over blocks and steps: a
+        # firstn block's count a lane once per block, an indep step's
+        # once per take, so either share can pass the lanes
+        tail_lanes = sum(seated for (b, _), seated, _, _ in seats
+                         if plan.blocks[b].firstn)
+        indep_tail_lanes = sum(seated for (b, _), seated, _, _ in seats
+                               if not plan.blocks[b].firstn)
         mark("crush.lanes", lanes=npg, tail_lanes=tail_lanes,
              resolve_lanes=nflag, retry_lanes=retry_lanes,
              indep_tail_lanes=indep_tail_lanes, none_slots=none_slots,
-             fast_lanes=n_fast, fast_settled=fast_settled, b_lanes=n2)
+             fast_lanes=n_fast, fast_settled=fast_settled, b_lanes=n2,
+             blocks=len(plan.blocks))
         return MapState(
             self, ruleno, result_max, pg_num, pgp_num, pgp_num_mask,
             pool_id, bool(hashpspool), bool(can_shift), use_aff,
             raw2, up2, prim2, w_np, ex_np, iu_np, af_np, npg,
             lanes=npg, tail_lanes=tail_lanes, resolve_lanes=nflag,
-            steps=len(plan.steps), retry_lanes=retry_lanes,
+            steps=plan.n_steps, retry_lanes=retry_lanes,
             none_slots=none_slots, indep_tail_lanes=indep_tail_lanes,
-            fast_lanes=n_fast, fast_settled=fast_settled, b_lanes=n2)
+            fast_lanes=n_fast, fast_settled=fast_settled, b_lanes=n2,
+            blocks=len(plan.blocks))
 
     @staticmethod
     def _chain_start(K1: int, counts, widest: int) -> tuple:

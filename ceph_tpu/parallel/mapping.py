@@ -11,8 +11,9 @@ sparse exception tables (pg_upmap*, pg_temp, primary_temp) are applied
 by recomputing only the excepted PGs through the host scalar pipeline.
 
 Falls back to the scalar pipeline per-PG when the crush map is outside
-the device scope (non-straw2 buckets, local retries, a rule that mixes
-firstn and indep steps or has more than one TAKE/EMIT pair).
+the device scope (non-straw2 buckets, local retries, a rule block that
+mixes firstn and indep steps, a TAKE after a choose step with no EMIT
+between).
 
 Device dispatches route through the shared device runtime
 (ceph_tpu.device.runtime) onto one mesh chip — the caller's affinity
@@ -75,8 +76,10 @@ class OSDMapMapping:
         self.device_pools = 0      # pools mapped on device this build
         self.scalar_pools = 0      # pools that fell back to host
         # pool id -> choose steps of its rule that the device pass ran
-        # (MapState.steps); a pool the host mapped has no entry
+        # (MapState.steps, over the rule's blocks) and its TAKE/EMIT
+        # blocks (MapState.blocks); a pool the host mapped has no entry
         self.rule_steps: dict[int, int] = {}
+        self.rule_blocks: dict[int, int] = {}
         with span("crush.build", pools=len(osdmap.pools)):
             self._build(osdmap, device_mapper, runtime, chip)
 
@@ -154,6 +157,7 @@ class OSDMapMapping:
             bool(pool.flags & FLAG_HASHPSPOOL), osdmap.osd_weight,
             exists, isup, aff, can_shift=pool.can_shift_osds())
         self.rule_steps[pool.id] = state.steps
+        self.rule_blocks[pool.id] = state.blocks
         return dm.read_tables(state)
 
     # -- scalar fallback ---------------------------------------------------

@@ -157,7 +157,8 @@ SPANS: dict[str, tuple[str, str]] = {
                      "before the device ends); on the pool program lanes, "
                      "and pallas_lanes whose descent was built in Pallas "
                      "(0 on the call that first traces it), steps: the "
-                     "rule's choose steps that ran on the device"),
+                     "rule's choose steps that ran on the device, over "
+                     "its blocks, blocks: its TAKE/EMIT blocks"),
     "crush.lanes": (MAPPING, "mark: one whole-pool pass counted, after "
                     "its one blocking read: lanes of the dense pass, "
                     "tail_lanes of them left unplaced by the first "
@@ -173,7 +174,9 @@ SPANS: dict[str, tuple[str, str]] = {
                     "pass left short (every draw proven, its rounds "
                     "run out: stage F's), fast_settled of them stage F "
                     "settled in the fast form, b_lanes those that "
-                    "reached stage B"),
+                    "reached stage B; blocks: the rule's TAKE/EMIT "
+                    "blocks (tail_lanes and indep_tail_lanes count a "
+                    "lane once per block that seated it)"),
     "crush.wait": (MAPPING, "the first blocking read: the host waits, "
                    "the device works"),
     "crush.readback": (MAPPING, "up/acting tables device -> host; bytes"),
@@ -201,6 +204,10 @@ SCOPES: dict[str, tuple[str, str]] = {
                       "it runs"),
     "crush.is_out": (KERNELS, "the reweight test of a drawn device"),
     "crush.post": (KERNELS, "raw rows -> up rows and primaries"),
+    "crush.emit": (KERNELS, "a rule of several TAKE/EMIT blocks: the "
+                   "blocks' stacked lanes apart, each block's rows "
+                   "shifted behind the entries of the blocks before it, "
+                   "EMIT's cap, the kinds joined"),
     "crush.resolve.compact": (KERNELS, "flagged lanes -> the resolve "
                               "chain's K1 slots"),
     "crush.resolve.fast": (KERNELS, "stage F: the short lanes through "

@@ -434,7 +434,7 @@ class TestDenseTail:
         assert dm.tail_overflows == overflows + 1
         assert st.tail_lanes == 0
         assert dm._tail_slots(ruleno, 2, self.PG_NUM, dm._tail_want[
-            (ruleno, 2, self.PG_NUM, 0)]) == 0
+            (ruleno, 2, self.PG_NUM, 0, 0)]) == 0
         # and the overflowing program itself flagged what it could not
         # seat, rather than cut it off: every lane it leaves unflagged
         # is exact
@@ -620,9 +620,10 @@ class TestDenseTail:
         # want is past what a tail may have, its rounds are dense again
         assert dm.tail_overflows == overflows + 1
         assert st.indep_tail_lanes == 0 and st.retry_lanes > 0
-        assert dm._tail_want[(2, 2, self.PG_NUM, 0)] > dm.INDEP_TAIL_KT_MAX
+        assert dm._tail_want[(2, 2, self.PG_NUM, 0, 0)] > \
+            dm.INDEP_TAIL_KT_MAX
         assert dm._tail_slots(2, 2, self.PG_NUM, dm._tail_want[
-            (2, 2, self.PG_NUM, 0)]) == 0
+            (2, 2, self.PG_NUM, 0, 0)]) == 0
         # and the program that ran over flagged what it could not seat:
         # every lane it leaves unflagged is exact
         import jax.numpy as jnp

@@ -419,9 +419,8 @@ def test_one_step_pass_counts_one_step(even):
             st.indep_tail_lanes) == (1, 0, 0, 0)
 
 
+# (rules of several TAKE/EMIT blocks: tests/test_crush_blocks_device.py)
 OUTSIDE = {
-    "two takes": [(TAKE, -1, 0), (CHOOSE_FIRSTN, 1, 2), (EMIT, 0, 0),
-                  (TAKE, -1, 0), (CHOOSELEAF_FIRSTN, 1, 1), (EMIT, 0, 0)],
     "firstn then indep": [(TAKE, -1, 0), (CHOOSE_FIRSTN, 2, 2),
                           (CHOOSELEAF_INDEP, 2, 1), (EMIT, 0, 0)],
     "indep then firstn": [(TAKE, -1, 0), (CHOOSE_INDEP, 2, 2),
@@ -431,6 +430,11 @@ OUTSIDE = {
     "take after a choose": [(TAKE, -1, 0), (CHOOSE_INDEP, 2, 2),
                             (TAKE, -2, 0), (CHOOSE_INDEP, 2, 0),
                             (EMIT, 0, 0)],
+    # the host emits nothing of a block with no EMIT, and a bare TAKE's
+    # bucket itself
+    "no emit": [(TAKE, -1, 0), (CHOOSELEAF_FIRSTN, 1, 1)],
+    "a bare take emitted": [(TAKE, -2, 0), (EMIT, 0, 0), (TAKE, -1, 0),
+                            (CHOOSELEAF_FIRSTN, 1, 1), (EMIT, 0, 0)],
 }
 
 
@@ -454,7 +458,7 @@ def test_a_pool_outside_the_scope_is_counted_in_scalar_pools():
                                      Incremental, OSDMap, PGPool, pg_t)
     from ceph_tpu.parallel.mapping import OSDMapMapping
     crush, n = build(False)
-    crush.add_rule(OUTSIDE["two takes"], id=50)
+    crush.add_rule(OUTSIDE["firstn then indep"], id=50)
     m = OSDMap()
     inc = Incremental(epoch=1)
     inc.new_max_osd = n
@@ -472,7 +476,7 @@ def test_a_pool_outside_the_scope_is_counted_in_scalar_pools():
     m.apply_incremental(inc)
     mp = OSDMapMapping(m)
     assert (mp.device_pools, mp.scalar_pools) == (1, 1)
-    assert mp.rule_steps == {2: 2}
+    assert mp.rule_steps == {2: 2} and mp.rule_blocks == {2: 1}
     for pool in (1, 2):
         for ps in range(16):
             assert mp.get(pg_t(pool, ps)) == m.pg_to_up_acting_osds(

@@ -78,6 +78,42 @@ def test_roundtrip_preserves_mappings():
         assert a.do_rule(0, x, 3, weights) == b.do_rule(0, x, 3, weights)
 
 
+STRETCH_RULE = ["step take site1", "step chooseleaf firstn 2 type host",
+                "step emit", "step take site2",
+                "step chooseleaf firstn 2 type host", "step emit"]
+
+
+def test_the_stretch_rule_round_trips():
+    """upstream's stretch_rule (doc/rados/operations/stretch-mode.rst),
+    two TAKE/EMIT blocks on a map of two datacenters: decompile writes
+    its six lines back, and compiling them again gives the same steps
+    and the same mappings."""
+    sites = "".join(
+        "datacenter %s {\n id %d\n alg straw2\n hash 0\n item host%d\n}\n"
+        % (name, -4 - i, i) for i, name in enumerate(("site1", "site2")))
+    text = MAP_TEXT.replace("type 2 root", "type 8 datacenter\ntype 11 root")
+    text = text.replace(
+        "root default {", sites + "root default {").replace(
+        "    item host0\n    item host1", "    item site1\n    item site2")
+    text = text.replace(
+        "    step take default\n    step chooseleaf firstn 0 type host\n"
+        "    step emit",
+        "\n".join("    " + line for line in STRETCH_RULE))
+    m = compile(text)
+    out = decompile(m)
+    rule = out[out.index("rule replicated_rule {"):].splitlines()
+    assert [line.strip() for line in rule[3:9]] == STRETCH_RULE
+    m2 = compile(out)
+    assert m2.rules[0].steps == m.rules[0].steps
+    assert len(m.rules[0].steps) == 6
+    weights = [0x10000] * 6
+    a, b = Mapper(m), Mapper(m2)
+    for x in range(256):
+        got = a.do_rule(0, x, 4, weights)
+        assert got == b.do_rule(0, x, 4, weights)
+        assert got[0] in (0, 1, 2) and got[1] in (3, 4, 5)
+
+
 def test_tester_statistics():
     m = compile(MAP_TEXT)
     t = CrushTester(m)

@@ -334,11 +334,13 @@ def test_traced_remap_counts_lanes_and_bytes(traced_remap):
     lanes = [s for _a, _b, s in by["crush.launch"] if "lanes" in s]
     # 256 PGs is no multiple of the Pallas tile: the XLA descent, counted
     assert [(s["lanes"], s["pallas_lanes"]) for s in lanes] == [(256, 0)]
-    # a rule of one choose step, all of it on the device
-    assert [s["steps"] for s in lanes] == [1]
+    # a rule of one choose step in one TAKE/EMIT block, all of it on
+    # the device
+    assert [(s["steps"], s["blocks"]) for s in lanes] == [(1, 1)]
     # one pass counted: too small a pool for a tail, a few lanes flagged
     (_a, _b, counted), = by["crush.lanes"]
     assert counted["lanes"] == 256 and counted["tail_lanes"] == 0
+    assert counted["blocks"] == 1
     assert 0 <= counted["resolve_lanes"] < 256
     # firstn: no indep round to leave a slot undefined; the holes of the
     # up table (two hosts cannot seat three replicas) are counted

@@ -235,3 +235,48 @@ def test_crush_lane_kernels_compile(one_chip, mosaic, kernel):
         _compiled_text(fn, one_chip, ((chunk,), np.bool_),
                        ((chunk, 4), jnp.int32),
                        ((chunk // DeviceMapper.RC_ROW * kt, 4), jnp.int32))
+
+
+def test_stretch_pool_program_compiles_with_its_tail(one_chip, mosaic):
+    """The dense program of the benchmark's 10M-PG stretch pool, as
+    map_pool_state builds it: the rule's two TAKE/EMIT blocks stacked
+    into one choose over two chunks' lanes, its first round and its
+    tail's rounds in Pallas, the post kernel at the pool's four slots,
+    and no more code than the sibling's one-block program."""
+    import json
+    import os
+    from benchmark.drivers.crush_churn_stretch import crush_text
+    from ceph_tpu.models.crushcompiler import compile as crush_compile
+    from ceph_tpu.ops.crush.device import DeviceMapper
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "crush-stretch-2dc-10m.json")) as f:
+        cfg = json.load(f)
+    crush = crush_compile(crush_text(cfg))
+    (ruleno,) = crush.rules
+    dm = DeviceMapper(crush)
+    chunk, n_chunks, size, pg_num = DeviceMapper.CHUNK, 10, 4, 10_000_000
+    assert dm._plan(ruleno, size).groups == ((0, 1),)
+    tails = (dm._tail_slots(ruleno, size, chunk,
+                            dm._tail_start(ruleno, size, 0)),)
+    assert tails == (DeviceMapper.TAIL_KT,)
+    fn = dm._compiled_pool(ruleno, size, True, False, pg_num,
+                           (1 << 24) - 1, 1, True, chunk, n_chunks, tails)
+    args = [jax.ShapeDtypeStruct((1000,), d, sharding=one_chip)
+            for d in (jnp.int32, np.bool_, np.bool_, jnp.int32)]
+    compiled = fn.lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    counts = {kernel: sum('/%s/pallas_call"' % kernel in line
+                          for line in calls)
+              for kernel in ("crush_straw2_descend", "crush_rowcompact",
+                             "crush_rowexpand", "crush_post_up")}
+    # the first round's two descents (host, then a leaf under it) and
+    # the tail's two, each once for both blocks
+    assert counts == {"crush_straw2_descend": 4, "crush_rowcompact": 1,
+                      "crush_rowexpand": 1, "crush_post_up": 1}, counts
+    assert all(dm.fm.descent_in_pallas[n]
+               for n in (2 * chunk, 2 * chunk // 2048 * tails[0]))
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    print("stretch pool program: %.1f MB of code" % (code / 1e6))
+    assert code < 30e6      # 25.2 MB when this test was written
